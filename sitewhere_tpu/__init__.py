@@ -17,7 +17,8 @@ Architecture (TPU-first, not a Java port):
                 rules (CEP) → outbound, plus command delivery.
 - ``models``    Model zoo: LSTM anomaly detector, Transformer/DeepAR
                 forecaster, ViT-B/16 frame classifier (pure-JAX pytrees).
-- ``ops``       JAX/Pallas kernels for the hot scoring path.
+- ``ops``       jitted JAX ops (jnp / lax / shard_map) for the hot scoring
+                path: window rings, on-device IDCT, ring attention.
 - ``parallel``  Mesh management, tenant→mesh-axis router, dp/tp/sp sharding
                 helpers built on jax.sharding + shard_map.
 - ``services``  L5: device/event/asset/state/schedule/batch/user/tenant

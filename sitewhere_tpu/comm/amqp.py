@@ -151,10 +151,13 @@ class AmqpBroker(LifecycleComponent):
     async def on_stop(self) -> None:
         if self._server is not None:
             self._server.close()
+            # connections first: since Python 3.12.1 wait_closed() waits
+            # for every accepted connection to drop, so stopping with a
+            # client still attached would never return
+            for t in list(self._conns):
+                await cancel_and_wait(t)
             await self._server.wait_closed()
             self._server = None
-        for t in list(self._conns):
-            await cancel_and_wait(t)
 
     def _queue(self, name: str) -> _Queue:
         q = self.queues.get(name)

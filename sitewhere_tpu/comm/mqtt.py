@@ -147,10 +147,13 @@ class MqttBroker(LifecycleComponent):
     async def on_stop(self) -> None:
         if self._server is not None:
             self._server.close()
+            # connections first: since Python 3.12.1 wait_closed() waits
+            # for every accepted connection to drop, so stopping with a
+            # client still attached would never return
+            for task in list(self._conns):
+                await cancel_and_wait(task)
             await self._server.wait_closed()
             self._server = None
-        for task in list(self._conns):
-            await cancel_and_wait(task)
 
     async def _serve(self, reader, writer) -> None:
         task = asyncio.current_task()

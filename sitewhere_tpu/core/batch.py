@@ -759,7 +759,7 @@ def _batch_from_wire(data: bytes) -> "MeasurementBatch":
         trace_ctx=meta.get("trace_ctx"),
         deadline_ms=meta.get("deadline_ms"),
         # the wire's chunk structure IS the group index — the consumer
-        # never pays the object-string sort (PERF_NOTES.md round 5)
+        # never pays the object-string sort (round 5)
         tok_index=None if tok_u is None else (tok_u, cols["tok_inverse"]),
         name_index=None if name_u is None else (name_u, cols["name_inverse"]),
     )

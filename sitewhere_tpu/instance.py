@@ -767,7 +767,7 @@ class SiteWhereInstance(LifecycleComponent):
 
     async def _autosave_loop(self) -> None:
         """Periodic live checkpoint: bounds the loss window of a HARD kill
-        (no polite stop) to one interval (VERDICT r2 item 7)."""
+        (no polite stop) to one interval."""
         interval = self.config.checkpoint_interval_s
         while True:
             await asyncio.sleep(interval)

@@ -407,9 +407,8 @@ def sketch_edges(
 #
 # Why analytic instead of XLA's cost analysis: XLA's ``cost_analysis()``
 # counts a ``lax.scan`` BODY once, not per trip — for the window-scan
-# models here that under-reports FLOPs by ~(window-1)×, which is exactly
-# the discrepancy between BENCH_r05's 0.043% "MFU" and the chip's real
-# utilization (see docs/PERFORMANCE.md "MFU accounting").
+# models here that under-reports FLOPs by ~(window-1)× (see
+# docs/PERFORMANCE.md "MFU accounting").
 
 def dense_flops(in_dim: int, out_dim: int) -> float:
     """Matmul FLOPs for one row through a dense layer (2 per MAC)."""

@@ -18,9 +18,8 @@ from dataclasses import dataclass
 from typing import Tuple
 
 import jax
-
-from sitewhere_tpu.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 
 from sitewhere_tpu.models.common import (
     Params,
@@ -144,12 +143,14 @@ def backbone_sharded(
             f"context {t} must divide across {n} '{axis_name}' shards"
         )
 
-    fn = shard_map(
+    # jitted: an eager shard_map call is interpreted op by op (minutes on
+    # the 8-virtual-device CPU rig under jax 0.9); one compile is seconds
+    fn = jax.jit(shard_map(
         partial(_backbone_local, cfg=cfg, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(), P(None, axis_name)),
         out_specs=P(None, axis_name, None),
-    )
+    ))
     return fn(params, normed)
 
 

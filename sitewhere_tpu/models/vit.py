@@ -16,9 +16,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
-
-from sitewhere_tpu.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 
 from sitewhere_tpu.models.common import (
     Params,
@@ -195,10 +194,12 @@ def apply_tp(
         x = layernorm(rest_p["ln_f"], x)
         return dense(rest_p["head"], x[:, 0], dtype).astype(jnp.float32)
 
-    fn = shard_map(
+    # jitted: an eager shard_map call is interpreted op by op (minutes on
+    # the 8-virtual-device CPU rig under jax 0.9); one compile is seconds
+    fn = jax.jit(shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name), P(), P()),
         out_specs=P(),
-    )
+    ))
     return fn(blocks_stacked, rest, images)

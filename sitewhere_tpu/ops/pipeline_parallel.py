@@ -19,10 +19,8 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-
-from sitewhere_tpu.compat import shard_map
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -89,11 +87,13 @@ def pipeline_apply(
         params = jax.tree_util.tree_map(lambda a: a[0], params_local)
         return pipeline_apply_local(params, xm_in, stage_fn, axis_name)
 
-    fn = shard_map(
+    # jitted: an eager shard_map call is interpreted op by op (minutes on
+    # the 8-virtual-device CPU rig under jax 0.9); one compile is seconds
+    fn = jax.jit(shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),
-    )
+    ))
     out = fn(stage_params_stacked, xm)
     return out.reshape(b, *out.shape[2:])

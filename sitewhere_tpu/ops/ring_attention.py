@@ -21,10 +21,8 @@ import math
 from functools import partial
 
 import jax
-
-from sitewhere_tpu.compat import shard_map
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -98,12 +96,14 @@ def ring_attention(q, k, v, mesh, axis_name: str, causal: bool = True):
     ``axis_name`` of ``mesh`` and run the ring. q/k/v: [B, T, H, D]
     global arrays (T divisible by the axis size)."""
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    # jitted: an eager shard_map call is interpreted op by op (minutes on
+    # the 8-virtual-device CPU rig under jax 0.9); one compile is seconds
+    fn = jax.jit(shard_map(
         partial(ring_attention_local, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-    )
+    ))
     return fn(q, k, v)
 
 

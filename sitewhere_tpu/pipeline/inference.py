@@ -4687,6 +4687,12 @@ class TpuInferenceService(MultitenantService):
             scorer = self.scorers.get(key)
             if scorer is not None:
                 scorer.prewarm(sorted(sizes))
+                # every bucket's step is compiled now: its first real
+                # flush must not report a (false) compile either — same
+                # rule as the train lane below
+                self._seen_shapes.update(
+                    (key[0], key[1], b) for b in sizes
+                )
                 if key in lane_keys and getattr(
                     scorer, "train_lane", False
                 ):

@@ -10,7 +10,7 @@ site — they simply never complete, complete late, or complete wrong:
   result array never becomes ready and its host materialization blocks
   forever (a wedged device queue / XLA deadlock).
 - ``hang_transfer``   — device compute finishes (``is_ready`` True) but
-  the d2h copy never crosses the link (stuck DMA / dead tunnel).
+  the d2h copy never crosses the link (stuck DMA).
 - ``fail_after_delay``— the result errors out, but only after
   ``delay_s`` of looking in-flight (late XLA runtime error).
 - ``corrupt_result``  — the transfer lands, full of NaN garbage

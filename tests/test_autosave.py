@@ -1,7 +1,7 @@
 """Automatic checkpointing: periodic autosave + checkpoint-on-stop, and
 the headline guarantee — a HARD-KILLED process (SIGKILL, no polite stop)
 restarts from the autosave with a loss window bounded by one interval and
-exactly-once persistence intact (VERDICT r2 item 7)."""
+exactly-once persistence intact."""
 
 import asyncio
 import json

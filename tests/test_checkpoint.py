@@ -1,6 +1,6 @@
 """Checkpoint/resume: param round trips, bus snapshots, and the headline
 guarantee — an instance killed mid-stream restarts with NO event lost and
-NO event persisted twice (SURVEY.md §5 checkpoint; VERDICT r1 item 4)."""
+NO event persisted twice (SURVEY.md §5 checkpoint)."""
 
 import asyncio
 

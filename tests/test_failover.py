@@ -1,6 +1,6 @@
 """Auto-failover chaos tests: a fault-injected scorer recovers scoring
-on a DIFFERENT mesh shard without losing events (VERDICT r2 item 6;
-SURVEY.md §5 "tenant-engine failover to a different mesh shard")."""
+on a DIFFERENT mesh shard without losing events (SURVEY.md §5
+"tenant-engine failover to a different mesh shard")."""
 
 import asyncio
 

@@ -1,6 +1,6 @@
 """Streaming-media → ViT pipeline: chunks → frame decode → micro-batched
-classification → events on the bus (VERDICT r2 item 5: the service must
-FLOW, not just store chunks)."""
+classification → events on the bus (the service must FLOW, not just
+store chunks)."""
 
 import asyncio
 import io

@@ -62,14 +62,13 @@ def test_local_memory_is_block_sized():
     mesh = _mesh(8)
     from functools import partial
 
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from sitewhere_tpu.compat import shard_map
-
     spec = P(None, "seq", None, None)
-    shard_map(probe, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)(
-        q, k, v
-    )
+    jax.jit(
+        shard_map(probe, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
+    )(q, k, v)
     assert seen["shape"][1] == 64 // 8
 
 

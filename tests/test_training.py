@@ -1,7 +1,7 @@
 """Live training in the running pipeline: per-tenant models adapt on
 their resident window state, and the CEP UDF evaluates with the tenant's
-LIVE params (VERDICT r2 item 4: train_resident must not be dead code and
-ModelUdf must not score with a fresh init forever)."""
+LIVE params (train_resident must not be dead code and ModelUdf must not
+score with a fresh init forever)."""
 
 import asyncio
 import math
