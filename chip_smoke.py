@@ -367,6 +367,18 @@ class EventsRun:
                         for i in range(self.size.tenants)),
             60.0, f"an outbound alert per tenant (got {self.alerts})")
 
+    async def wait_delivered(self) -> None:
+        """``scored_total`` counts at the publish on scored-events;
+        persist and outbound follow it. Wait for the LAST stage before
+        the accounting reads what it delivered."""
+        per_tenant = self.published // self.size.tenants
+        await _wait_for(
+            lambda: all(
+                self.inst.tenants[self.tenant(i)].outbound.connectors[0]
+                .batch_rows >= per_tenant
+                for i in range(self.size.tenants)),
+            60.0, "the outbound connectors to deliver every row")
+
     def check_accounting(self) -> dict:
         """published == scored == stored, all finite, nothing degraded."""
         from sitewhere_tpu.core.batch import MeasurementBatch
@@ -446,6 +458,7 @@ async def phase_events(size: EventsSize, seed: int, platform: str,
         traffic_s = await run.publish(events_per_sec)
         numerics = run.check_numerics()
         await run.check_rule_leg()
+        await run.wait_delivered()
         out = run.check_accounting()
         return {"phase": "events", "ok": True, **out, **numerics,
                 "offered_ev_s": events_per_sec,
@@ -640,6 +653,7 @@ async def phase_chips4(size: EventsSize, seed: int, platform: str) -> dict:
         try:
             await run.start()
             await run.publish(None)  # lockstep: timing-independent scores
+            await run.wait_delivered()
             cols = {run.tenant(i): run.store_columns(run.tenant(i))
                     for i in range(size.tenants)}
             acct = run.check_accounting()

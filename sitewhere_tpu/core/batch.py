@@ -108,6 +108,15 @@ class MeasurementBatch:
     # registry, and device-state
     tok_index: Optional[tuple] = None
     name_index: Optional[tuple] = None
+    # time.perf_counter() stamps of this process (0.0 = not stamped;
+    # never on the wire — another process's clock means nothing here):
+    # broker delivery to the event source, lane enqueue, publish on
+    # scored-events. ``pipeline.intake`` / ``tpu_inference.lane_wait`` /
+    # ``pipeline.egress`` are the intervals between them and their
+    # neighbours (docs/OBSERVABILITY.md "Latency attribution").
+    t_intake: float = 0.0
+    t_lane: float = 0.0
+    t_scored: float = 0.0
 
     def token_index(self) -> tuple:
         if self.tok_index is None:
@@ -320,6 +329,9 @@ class MeasurementBatch:
             trace=dict(self.trace),
             trace_ctx=self.trace_ctx,
             deadline_ms=self.deadline_ms,
+            t_intake=self.t_intake,
+            t_lane=self.t_lane,
+            t_scored=self.t_scored,
         )
 
     def to_events(self) -> List[DeviceMeasurement]:

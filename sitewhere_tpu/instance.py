@@ -181,6 +181,9 @@ class SiteWhereInstance(LifecycleComponent):
 
         self.flightrec = FlightRecorder()
         self.tracer.flightrec = self.flightrec  # SLO-breach snapshots
+        from sitewhere_tpu.runtime.loopledger import GcAccount
+
+        self._gc_account = GcAccount(self.metrics)
         # latency attribution (runtime.latency): the engine every tail
         # decision feeds — per-(tenant, priority) stage ledgers, p99
         # decomposition, SLO burn rates. Shared by the tracer (feed),
@@ -255,9 +258,9 @@ class SiteWhereInstance(LifecycleComponent):
                 _Path(cfg.data_dir) / "replay" if cfg.checkpointing else None
             ),
         )
-        # profile hooks: annotate scoring dispatches inside the jax
-        # profiler trace when the instance is capturing one
-        self.inference.profile_annotations = bool(cfg.profile_dir)
+        # the ledger splits each batch's inference span on the record of
+        # the flush that scored it, found by the span's flush_id
+        self.latency.flushes = self.inference.flush_records
         self.add_child(self.inference)
         self.tenants: Dict[str, TenantRuntime] = {}
         self.coap: object = None
@@ -688,6 +691,11 @@ class SiteWhereInstance(LifecycleComponent):
                 # process-global (an already-active trace raises); losing
                 # the trace must not keep the instance from booting
                 self._record_error("profiler-start", exc)
+        # the event-loop ledger and the collector's account: always on,
+        # installed BEFORE any child starts so every task of the
+        # instance is created through the ledger's task factory
+        self.metrics.loop_ledger.install(asyncio.get_running_loop())
+        self._gc_account.install()
         self.bus.subscribe(self.bus.naming.tenant_model_updates(), "instance")
         self._updates_task = asyncio.create_task(
             self._updates_loop(), name=f"{self.name}-tenant-updates"
@@ -736,6 +744,9 @@ class SiteWhereInstance(LifecycleComponent):
         while True:
             await asyncio.sleep(self.history.resolution_s)
             try:
+                # the tick is the tracing's own cost: the loop ledger's
+                # ``observe`` stage, not ``other``
+                t0 = self.metrics.loop_ledger.clock()
                 # decay idle families' MFU gauges BEFORE sampling so the
                 # ring never records a stale "last busy" value forever
                 self._refresh_mfu()
@@ -746,6 +757,7 @@ class SiteWhereInstance(LifecycleComponent):
                 self.history.sample()
                 if self.watchdog is not None:
                     self.watchdog.evaluate()
+                self.metrics.loop_ledger.observe_from(t0)
             except Exception as exc:  # noqa: BLE001 - a sampling fault
                 # must not kill the blackbox; next tick retries
                 self._record_error("history-tick", exc)
@@ -812,6 +824,8 @@ class SiteWhereInstance(LifecycleComponent):
         await cancel_and_wait(getattr(self, "_history_task", None))
         self._history_task = None
         await self.replay.stop()
+        self._gc_account.uninstall()
+        self.metrics.loop_ledger.uninstall()
         if self._profiling:
             import jax
 

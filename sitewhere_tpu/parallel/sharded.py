@@ -408,6 +408,9 @@ class ShardedScorer:
 
     def _gather_fn(self) -> Callable:
         if getattr(self, "_gather", None) is None:
+            # "sw/gather" names the program's operations in a profile
+            # whatever XLA calls the module (today ``jit_gather``)
+            @jax.named_scope("sw/gather")
             def gather(scores, counts, size):
                 # scores [T, D*B] wire dtype, counts i32[T, D]; the valid
                 # rows are front-contiguous per (slot, data-shard) lane,
@@ -519,6 +522,9 @@ class ShardedScorer:
             )
             return hist.reshape(t, nbins + 1)[:, :nbins]
 
+        # "sw/step" names the step's operations in a profile whatever
+        # XLA calls the module (today ``jit_local_step``)
+        @jax.named_scope("sw/step")
         def local_step(params, state, active, ids, vals, validity):
             # local shapes: params [T_loc, ...], state [T_loc, S_loc, W],
             # ids/vals [T_loc, B_loc]; validity is bool[T_loc, B_loc]

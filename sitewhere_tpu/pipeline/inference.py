@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import asyncio
 import time
+from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -96,30 +97,13 @@ from sitewhere_tpu.runtime.lifecycle import (
     SupervisedTask,
     cancel_and_wait,
 )
+from sitewhere_tpu.runtime.loopledger import spanned, sw
 from sitewhere_tpu.runtime.metrics import (
     D2H_OVERLAP_EPS_S as _D2H_OVERLAP_EPS_S,
     MetricsRegistry,
     RollingQuantile,
 )
 from sitewhere_tpu.runtime.tenant import MultitenantService, TenantEngine
-
-
-def _profiler_annotation(enabled: bool, family: str):
-    """A ``jax.profiler.TraceAnnotation`` around the scoring dispatch when
-    the instance is capturing a profile (InstanceConfig.profile_dir), so
-    per-family device time is attributable inside the trace; a cheap
-    nullcontext otherwise — and on any profiler fault (the profiler is
-    process-global and can be owned elsewhere)."""
-    import contextlib
-
-    if not enabled:
-        return contextlib.nullcontext()
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(f"tpu_scoring/{family}")
-    except Exception:  # noqa: BLE001 - never let profiling break scoring
-        return contextlib.nullcontext()
 
 
 class StreamRegistry:
@@ -349,7 +333,15 @@ class _StagingSet:
 
 
 class _PendingFlush:
-    """One dispatched flush awaiting its device→host score transfer.
+    """One dispatched flush awaiting its device→host score transfer —
+    and the span record of that flush: ``flush_id`` names it (each batch
+    it completes stamps the id on its inference span), ``rec`` is the
+    record dict the flight recorder's ring and ``flush_records`` share,
+    which carries the batch ``seqs`` and the contiguous
+    ``time.perf_counter()`` stamps ``t_oldest → t_asked → t_got →
+    t_assembled → t_staged → t_dispatched → t_landed → t_resolved`` (the
+    first six written by ``_flush_slice``, the last two by
+    ``_resolve_flush``).
 
     ``scores`` is either the device-gathered row vector (``gathered``
     True — slice ``[:moved]`` is the picks, already in pack order) or
@@ -366,7 +358,7 @@ class _PendingFlush:
         "t_dispatch", "nbytes", "plane_nbytes", "host_future", "t_wait",
         "poisoned", "flops", "rec", "sketch", "shadow", "slot_override",
         "resolved", "lane", "deadline", "retried", "retry_rows",
-        "retry_from", "owns_permit",
+        "retry_from", "owns_permit", "flush_id",
     )
 
     def __init__(
@@ -374,8 +366,10 @@ class _PendingFlush:
         nbytes: int, plane_nbytes: int, poisoned: bool = False,
         flops: float = 0.0, rec: Optional[dict] = None,
         sketch=None, shadow=None, sl: int = 0, lane: str = "serve",
+        flush_id: int = -1, t_dispatch: Optional[float] = None,
     ) -> None:
         self.family = family
+        self.flush_id = flush_id
         # the mesh slice that ran this flush: reap queues, overlap
         # probes, and device-labeled attribution are all keyed
         # (family, slice) on a multi-chip mesh
@@ -387,7 +381,11 @@ class _PendingFlush:
         self.taken = taken
         self.moved = moved
         self.gathered = gathered
-        self.t_dispatch = time.perf_counter()
+        # when the dispatch call returned (perf_counter): the start of
+        # the in-flight interval and of the supervisor's deadline
+        self.t_dispatch = (
+            time.perf_counter() if t_dispatch is None else t_dispatch
+        )
         self.nbytes = nbytes
         self.plane_nbytes = plane_nbytes
         self.host_future = None
@@ -887,11 +885,10 @@ class TpuInferenceService(MultitenantService):
 
         self.fair = DeficitRoundRobin(quantum=fair_quantum)
         self._gates: Dict[str, object] = {}
-        # tracing + scoring profile hooks: per-tenant inference spans, a
-        # compile-count per (family, bucket) shape (the first flush at a
-        # shape IS the XLA compile — a mid-traffic recompile is the p99
-        # cliff SURVEY §7 warns about), and optional jax.profiler
-        # annotations so device time shows up in profile_dir traces
+        # tracing + scoring profile hooks: per-tenant inference spans and
+        # a compile-count per (family, bucket) shape (the first flush at
+        # a shape IS the XLA compile — a mid-traffic recompile is the p99
+        # cliff SURVEY §7 warns about)
         self.tracer = tracer
         # flight recorder (runtime.flightrec): always-on per-flush
         # blackbox records + dump-on-incident (breaker trip) snapshots;
@@ -916,8 +913,16 @@ class TpuInferenceService(MultitenantService):
         self._mfu_dev: Dict[Tuple[str, int], object] = {}
         self._stage_timers: Dict[str, object] = {}
         self._seen_shapes: set = set()
-        self._last_flush: Dict[str, dict] = {}
-        self.profile_annotations = False
+        # the flush records of the newest flushes by flush_id — the same
+        # dicts the flight recorder's ring holds. The latency ledger
+        # splits a batch's inference span on ITS OWN flush through the
+        # flush_id the span carries (runtime.latency.stage_vector).
+        self.flush_records: "OrderedDict[int, dict]" = OrderedDict()
+        self._next_flush_id = 0
+        # per-(family, slice) perf_counter() of the newest landing: the
+        # device queue is FIFO, so a flush's non-overlapping service
+        # time runs from the later of its own dispatch and this
+        self._last_landed: Dict[Tuple[str, int], float] = {}
         self.slots_per_shard = slots_per_shard
         self.poll_batch = poll_batch  # bus items (batches) per poll
         self.router = TenantRouter(self.mm.n_tenant_shards, slots_per_shard)
@@ -1423,6 +1428,7 @@ class TpuInferenceService(MultitenantService):
             self._deliver_pool = None
 
     # -- ingestion → lanes (columnar) ------------------------------------
+    @spanned("lanes")
     async def _enqueue_batch(
         self,
         engine: TpuInferenceEngine,
@@ -1435,6 +1441,14 @@ class TpuInferenceService(MultitenantService):
         only a strided sample of rows is scored, the rest resolve
         unscored right away (they still persist — degraded, never lost)
         so the TPU budget shrinks without breaking accounting."""
+        t_lane = time.perf_counter()
+        if batch.t_intake:
+            # broker delivery → lane enqueue: receiver queue, decode,
+            # inbound and their bus waits, per message
+            self.metrics.histogram("pipeline.intake", unit="s").record(
+                t_lane - batch.t_intake
+            )
+        batch.t_lane = t_lane
         family = engine.config.model
         sl = engine.placement.shard
         # setdefault: a GHOST (paged-out) tenant's slice may not have
@@ -1538,13 +1552,16 @@ class TpuInferenceService(MultitenantService):
         scores: Optional[np.ndarray],
         publish_nowait: bool = False,
         family: str = "",
+        flush_id: Optional[int] = None,
     ) -> int:
         """Columnar score write-back: scatter ``scores`` (or NaN for an
         unscored resolution) into their batches' score columns one
         contiguous run at a time, then publish every batch that became
         complete — in seq (= enqueue) order, so a tenant's batches leave
         in order even when a flush carried several. Returns the number
-        of batches published.
+        of batches published. ``flush_id`` names the flush that scored
+        the rows: each batch it completes carries it on its inference
+        span.
 
         Rows arrive grouped: lanes pop FIFO and flushes pack lanes in
         sorted order, so equal-seq runs are contiguous and their row
@@ -1600,18 +1617,27 @@ class TpuInferenceService(MultitenantService):
             # await-free, so no batch state moved under us)
             done[:k].sort()
             seq_list = done[:k].tolist()
-            for i, s in enumerate(seq_list):
-                try:
-                    await self._publish_batch(int(s), nowait=publish_nowait)
-                except BaseException:
-                    # cancelled (teardown) or a publish fault mid-loop:
-                    # the remaining completed batches are already out of
-                    # the registry's reach of any later resolve — flush
-                    # them nowait or they strand in _batches and their
-                    # events are lost
-                    for s2 in seq_list[i + 1:]:
-                        await self._publish_batch(int(s2), nowait=True)
-                    raise
+            t_pub = time.perf_counter()
+            with sw("publish"):
+                for i, s in enumerate(seq_list):
+                    try:
+                        await self._publish_batch(
+                            int(s), nowait=publish_nowait, flush_id=flush_id
+                        )
+                    except BaseException:
+                        # cancelled (teardown) or a publish fault
+                        # mid-loop: the remaining completed batches are
+                        # already out of the registry's reach of any
+                        # later resolve — flush them nowait or they
+                        # strand in _batches and their events are lost
+                        for s2 in seq_list[i + 1:]:
+                            await self._publish_batch(int(s2), nowait=True)
+                        raise
+            # the publish loop alone — a child of ``resolve``, which ends
+            # where this does
+            self.metrics.histogram("tpu_inference.publish", unit="s").record(
+                time.perf_counter() - t_pub
+            )
         return k
 
     def _gate(self, tenant: str):
@@ -1638,13 +1664,17 @@ class TpuInferenceService(MultitenantService):
             )
         return t
 
-    async def _publish_batch(self, seq: int, nowait: bool = False) -> None:
+    async def _publish_batch(
+        self, seq: int, nowait: bool = False,
+        flush_id: Optional[int] = None,
+    ) -> None:
         batch, _ = self._batches.pop(seq)
         # a retried batch that made it out scored is no longer suspect
         self._retried_seqs.discard(seq)
         # inference span: start = lane enqueue, queue wait = bus time since
-        # the inbound stage published; annotations carry the family's last
-        # flush profile (dispatch time, whether it compiled a new shape)
+        # the inbound stage published; it carries the id of the flush that
+        # completed the batch — the latency ledger splits the span on that
+        # flush's own record (``flush_records``), never on a neighbour's
         t_now = time.time() * 1000.0
         enq = batch.trace.get("inference_enqueue", t_now)
         prev = max(
@@ -1653,11 +1683,14 @@ class TpuInferenceService(MultitenantService):
         )
         engine = self.engines.get(batch.tenant)
         family = engine.config.model if engine is not None else ""
+        ann = {"family": family}
+        if flush_id is not None:
+            ann["flush_id"] = flush_id
         self._stage_timer(batch.tenant).observe(
             batch, enq, t_now, n_events=batch.n,
-            queue_wait_ms=max(0.0, enq - prev),
-            **self._last_flush.get(family, {}),
+            queue_wait_ms=max(0.0, enq - prev), **ann,
         )
+        batch.t_scored = time.perf_counter()
         batch.mark("scored")
         topic = self.bus.naming.scored_events(batch.tenant)
         if nowait:
@@ -1782,16 +1815,20 @@ class TpuInferenceService(MultitenantService):
         # a cancellation while waiting here must not strand popped rows
         # (everything from the pop to the reap enqueue below is
         # await-free).
-        t_acq = time.perf_counter()
+        flush_id = self._next_flush_id
+        self._next_flush_id += 1
+        t_asked = time.perf_counter()
         sem = self._inflight_sem((family, sl))
         if sem.locked():
             # all of THIS slice's completion slots busy: the flush
             # backpressures here, where depth is the deliver_inflight
             # gauge (check_queues) — other slices' budgets are untouched
             self.metrics.counter("tpu_inference.deliver_backpressure").inc()
-        await sem.acquire()
+        with sw("permit_wait", flush_id=flush_id):
+            await sem.acquire()
+        t_got = time.perf_counter()
         self.metrics.histogram("tpu_inference.acquire_wait", unit="s").record(
-            time.perf_counter() - t_acq
+            t_got - t_asked
         )
         # pick the bucket AFTER the (possibly long) acquire wait: rows that
         # accumulated while every slot was busy should ride out in ONE
@@ -1805,53 +1842,55 @@ class TpuInferenceService(MultitenantService):
         # Assembly is slice copies lane-ring → REUSABLE staging buffers:
         # no fresh flush arrays, no list accumulators, no np.asarray over
         # Python lists (tools/check_hotpath.py enforces this stays true).
-        t_asm = time.perf_counter()
-        st = self._staging_set(family, sl, scorer, b_lane)
-        ids, vals, counts = st.ids, st.vals, st.counts
-        counts[:] = 0
-        take_total = 0
-        for lane in lanes.values():
-            take_total += min(lane.count, b_lane)
-        slots_cat = np.empty((take_total,), np.int32)
-        cols_cat = np.empty((take_total,), np.int32)
-        seqs_cat = np.empty((take_total,), np.int64)
-        rows_cat = np.empty((take_total,), np.int32)
-        moved = 0
-        used_slots: set = set()
-        # SORTED lane order: the device-side gather compacts valid rows
-        # in (slot, data-shard, lane-position) order, so the host-side
-        # seqs/rows bookkeeping must pack in exactly that order for
-        # gathered[:moved] to line up with seqs_cat/rows_cat
-        for (slot, dshard), lane in sorted(lanes.items()):
-            k = min(lane.count, b_lane)
-            if k == 0:
-                continue
-            base = dshard * b_lane
-            lane.pop_into(k, ids[slot], vals[slot], base, seqs_cat, rows_cat, moved)
-            slots_cat[moved : moved + k] = slot
-            cols_cat[moved : moved + k] = st.arange[base : base + k]
-            counts[slot, dshard] = k
-            used_slots.add(slot)
-            moved += k
-        depth_left = 0
-        for lane in lanes.values():
-            depth_left += lane.count
-        self.metrics.gauge("tpu_inference_lane_rows", family=family).set(
-            depth_left
-        )
-        if depth_left:
-            self._first_pending_ts[(family, sl)] = time.monotonic()
-        else:
-            self._first_pending_ts.pop((family, sl), None)
-        if moved == 0:
-            sem.release()
-            if breaker is not None:
-                breaker.release_trial()  # allowed, but no call was made
-            return 0
-        assembly_s = time.perf_counter() - t_asm
-        self.metrics.histogram("tpu_inference.flush_assembly", unit="s").record(
-            assembly_s
-        )
+        with sw("flush_assembly", flush_id=flush_id):
+            t_asm = time.perf_counter()
+            st = self._staging_set(family, sl, scorer, b_lane)
+            ids, vals, counts = st.ids, st.vals, st.counts
+            counts[:] = 0
+            take_total = 0
+            for lane in lanes.values():
+                take_total += min(lane.count, b_lane)
+            slots_cat = np.empty((take_total,), np.int32)
+            cols_cat = np.empty((take_total,), np.int32)
+            seqs_cat = np.empty((take_total,), np.int64)
+            rows_cat = np.empty((take_total,), np.int32)
+            moved = 0
+            used_slots: set = set()
+            # SORTED lane order: the device-side gather compacts valid rows
+            # in (slot, data-shard, lane-position) order, so the host-side
+            # seqs/rows bookkeeping must pack in exactly that order for
+            # gathered[:moved] to line up with seqs_cat/rows_cat
+            for (slot, dshard), lane in sorted(lanes.items()):
+                k = min(lane.count, b_lane)
+                if k == 0:
+                    continue
+                base = dshard * b_lane
+                lane.pop_into(k, ids[slot], vals[slot], base, seqs_cat, rows_cat, moved)
+                slots_cat[moved : moved + k] = slot
+                cols_cat[moved : moved + k] = st.arange[base : base + k]
+                counts[slot, dshard] = k
+                used_slots.add(slot)
+                moved += k
+            depth_left = 0
+            for lane in lanes.values():
+                depth_left += lane.count
+            self.metrics.gauge("tpu_inference_lane_rows", family=family).set(
+                depth_left
+            )
+            if depth_left:
+                self._first_pending_ts[(family, sl)] = time.monotonic()
+            else:
+                self._first_pending_ts.pop((family, sl), None)
+            if moved == 0:
+                sem.release()
+                if breaker is not None:
+                    breaker.release_trial()  # allowed, but no call was made
+                return 0
+            t_assembled = time.perf_counter()
+            assembly_s = t_assembled - t_asm
+            self.metrics.histogram(
+                "tpu_inference.flush_assembly", unit="s"
+            ).record(assembly_s)
 
         taken = (slots_cat, cols_cat, seqs_cat, rows_cat)
         shape_key = (family, sl, b_lane)
@@ -1875,11 +1914,13 @@ class TpuInferenceService(MultitenantService):
             t_stage = time.perf_counter()
             stage = getattr(scorer, "stage_inputs", None)
             if stage is not None:
-                staged = stage(ids, vals, counts)
+                with sw("h2d_stage", flush_id=flush_id):
+                    staged = stage(ids, vals, counts)
                 st.staged = staged
             else:  # monkeypatched/minimal scorers (tests)
                 staged = (ids, vals, counts)
-            h2d_stage_s = time.perf_counter() - t_stage
+            t_staged = time.perf_counter()
+            h2d_stage_s = t_staged - t_stage
             self.metrics.histogram("tpu_inference.h2d_stage", unit="s").record(
                 h2d_stage_s
             )
@@ -1931,9 +1972,11 @@ class TpuInferenceService(MultitenantService):
                 # kernel crash on this batch's data
                 self.faultplan.maybe_raise(family, sl, "serve")
             t_disp = time.perf_counter()
-            with _profiler_annotation(self.profile_annotations, family):
+            with sw("dispatch", flush_id=flush_id):
                 scores_dev = scorer.step_counts(*staged)  # async dispatch
-            dispatch_s = time.perf_counter() - t_disp
+            t_dispatched = time.perf_counter()
+            ts_dispatched_ms = time.time() * 1000.0
+            dispatch_s = t_dispatched - t_disp
             self.metrics.histogram("tpu_inference.dispatch", unit="s").record(
                 dispatch_s
             )
@@ -1961,19 +2004,6 @@ class TpuInferenceService(MultitenantService):
                     "tpu_inference_compiles", family=family,
                     bucket=str(b_lane),
                 ).inc()
-            self._last_flush[family] = {
-                "family": family,
-                "dispatch_s": round(dispatch_s, 6),
-                "compiled": compiling,
-                "bucket": b_lane,
-                # latency-attribution profile: runtime.latency splits the
-                # inference span into its flush sub-stages on these keys
-                # (device/d2h/resolve halves land when the reaper
-                # resolves — see _resolve_flush)
-                "flush_assembly_s": round(assembly_s, 6),
-                "flush_h2d_s": round(h2d_stage_s, 6),
-                "flush_dispatch_s": round(dispatch_s, 6),
-            }
             if self.mm.n_devices > 1:
                 # per-device throughput attribution: which chip scored
                 # these rows (slice balance / skew ride on this)
@@ -1983,30 +2013,57 @@ class TpuInferenceService(MultitenantService):
                 ).inc(moved)
             self.metrics.counter("tpu_inference.flushes").inc()
             self.metrics.counter("tpu_inference.flush_rows").inc(moved)
-            if self.flightrec is not None:
-                # the blackbox record for this flush — completed in place
-                # (d2h/resolve/device timings) when the reaper resolves it
-                rec = self.flightrec.record(
-                    "flush", family,
-                    lane="serve",
-                    rows=moved, bucket=b_lane,
-                    assembly_s=round(assembly_s, 6),
-                    h2d_stage_s=round(h2d_stage_s, 6),
-                    dispatch_s=round(dispatch_s, 6),
-                    h2d_overlapped=bool(overlapped),
-                    compiled=compiling,
-                    # kernel variant attribution: which fused-step shape
-                    # produced this flush's timings (incident snapshots
-                    # must name the variant, not just the family)
-                    k_steps=getattr(scorer, "k_steps", 1),
-                    param_dtype=getattr(scorer, "param_dtype", "f32"),
-                    # multi-chip attribution: WHICH slice/chip ran this
-                    # flush — incident snapshots must name the device
-                    mesh_slice=sl,
-                    device_label=scorer.device_label,
-                    trace_id=self._flush_trace_id(seqs_cat),
-                    status="inflight",
-                )
+            # flushes already in flight on this slice as this one joins
+            # the device queue (÷ .flushes = the mean depth it waits
+            # behind)
+            self.metrics.counter("tpu_inference.inflight_depth_sum").inc(
+                len(self._reap.get((family, sl), ()))
+            )
+            # lane wait, per carried batch: its enqueue → the flush asked
+            # for its permit
+            seq_list = np.unique(seqs_cat).tolist()
+            lane_wait = self.metrics.histogram(
+                "tpu_inference.lane_wait", unit="s"
+            )
+            t_oldest = t_asked
+            for s_ in seq_list:
+                entry = self._batches.get(s_)
+                t_lane = entry[0].t_lane if entry is not None else 0.0
+                if t_lane:
+                    lane_wait.record(max(0.0, t_asked - t_lane))
+                    if t_lane < t_oldest:
+                        t_oldest = t_lane
+            # the flush record — completed in place (landed / resolved /
+            # d2h / service timings) when the reaper resolves the flush.
+            # It lives in the flight recorder's ring (the blackbox) and,
+            # by id, in ``flush_records`` (the latency ledger's lookup).
+            rec = self._flush_record(
+                family,
+                ts_ms=ts_dispatched_ms,
+                flush_id=flush_id,
+                seqs=seq_list,
+                t_oldest=t_oldest, t_asked=t_asked, t_got=t_got,
+                t_assembled=t_assembled, t_staged=t_staged,
+                t_dispatched=t_dispatched,
+                lane="serve",
+                rows=moved, bucket=b_lane,
+                assembly_s=round(assembly_s, 6),
+                h2d_stage_s=round(h2d_stage_s, 6),
+                dispatch_s=round(dispatch_s, 6),
+                h2d_overlapped=bool(overlapped),
+                compiled=compiling,
+                # kernel variant attribution: which fused-step shape
+                # produced this flush's timings (incident snapshots
+                # must name the variant, not just the family)
+                k_steps=getattr(scorer, "k_steps", 1),
+                param_dtype=getattr(scorer, "param_dtype", "f32"),
+                # multi-chip attribution: WHICH slice/chip ran this
+                # flush — incident snapshots must name the device
+                mesh_slice=sl,
+                device_label=getattr(scorer, "device_label", "device:?"),
+                trace_id=self._flush_trace_id(seqs_cat),
+                status="inflight",
+            )
             # device-side gather: compact ONLY the flushed rows out of
             # the [T, D*B] score plane before anything crosses d2h —
             # transfer volume becomes rows-proportional (wire dtype),
@@ -2160,6 +2217,7 @@ class TpuInferenceService(MultitenantService):
             int(getattr(scores_dev, "nbytes", 0)), plane_nbytes,
             flops=float(flops_fn(b_lane)) if flops_fn is not None else 0.0,
             rec=rec, sketch=sketch_dev, shadow=shadow_dev, sl=sl,
+            flush_id=flush_id, t_dispatch=t_dispatched,
         )
         pf.slot_override = slot_override
         # flush supervision: the completion deadline the reaper races
@@ -2182,6 +2240,22 @@ class TpuInferenceService(MultitenantService):
             )
         self._reap_enqueue(pf)
         return moved
+
+    FLUSH_RECORDS = 512  # newest flush records kept for the ledger
+
+    def _flush_record(self, family: str, **fields) -> dict:
+        """One flush's record: appended to the flight recorder's ring
+        (when the service has one) and indexed by ``flush_id``."""
+        t0 = self.metrics.loop_ledger.clock()
+        if self.flightrec is not None:
+            rec = self.flightrec.record("flush", family, **fields)
+        else:
+            rec = fields
+        self.flush_records[fields["flush_id"]] = rec
+        if len(self.flush_records) > self.FLUSH_RECORDS:
+            self.flush_records.popitem(last=False)
+        self.metrics.loop_ledger.observe_from(t0)
+        return rec
 
     @staticmethod
     def _copy_retry_rows(
@@ -4022,11 +4096,12 @@ class TpuInferenceService(MultitenantService):
                 # one future per in-flight FAMILY (a handful), not per row
                 futs.append(h.ensure_host_future(loop, self._deliver_pool))  # hotpath: ok
             try:
-                await asyncio.wait(  # supervised: ok(flush-deadline timer races in futs)
-                    [*futs, waiter]
-                    + ([timer] if timer is not None else []),
-                    return_when=asyncio.FIRST_COMPLETED,
-                )
+                with sw("reap_wait", flush_id=heads[0].flush_id):
+                    await asyncio.wait(  # supervised: ok(flush-deadline timer races in futs)
+                        [*futs, waiter]
+                        + ([timer] if timer is not None else []),
+                        return_when=asyncio.FIRST_COMPLETED,
+                    )
             finally:
                 waiter.cancel()
                 if timer is not None:
@@ -4044,7 +4119,9 @@ class TpuInferenceService(MultitenantService):
         its own family, and only until ``max_inflight`` backpressures
         the scoring loop as a whole."""
         task = asyncio.get_running_loop().create_task(
-            self._resolve_flush(pf)
+            self._resolve_flush(pf),
+            # the loop ledger charges a task by its name's prefix
+            name=f"tpu-inference-resolve[{pf.family}/{pf.sl}]",
         )
         self._resolving[pf.key] = task
 
@@ -4126,6 +4203,9 @@ class TpuInferenceService(MultitenantService):
                     timeout=budget,
                 )
                 now = time.perf_counter()
+                # a train step holds the slice's device queue like a
+                # serve flush: the next flush's service time starts here
+                self._last_landed[pf.key] = now
                 self.last_train_losses[pf.key] = losses_np
                 device_s = max(0.0, now - pf.t_dispatch)
                 # train steps feed the same deadline history as serve
@@ -4156,13 +4236,21 @@ class TpuInferenceService(MultitenantService):
                 await self._resolve_rows(seqs, rows, None, family=pf.family)
                 return
             t0 = time.perf_counter()
-            scores_np, sketch_np, shadow_np = await asyncio.wait_for(
-                pf.ensure_host_future(
-                    asyncio.get_running_loop(), self._deliver_pool
-                ),
-                timeout=budget,
-            )
+            with sw("reap_wait", flush_id=pf.flush_id):
+                scores_np, sketch_np, shadow_np = await asyncio.wait_for(
+                    pf.ensure_host_future(
+                        asyncio.get_running_loop(), self._deliver_pool
+                    ),
+                    timeout=budget,
+                )
+            # the transfer has landed: the in-flight interval ends and
+            # ``resolve`` begins, on one stamp
             now = time.perf_counter()
+            service_s = max(
+                0.0,
+                now - max(pf.t_dispatch, self._last_landed.get(pf.key, 0.0)),
+            )
+            self._last_landed[pf.key] = now
             # cumulative wait: from the FIRST time the reaper waited on
             # this flush (race rounds included), not just the last await
             waited_s = now - pf.t_wait if pf.t_wait is not None else now - t0
@@ -4177,71 +4265,85 @@ class TpuInferenceService(MultitenantService):
                 # it rode under later compute (raced-on heads never count,
                 # however fast their future resolved afterwards)
                 self.metrics.counter("tpu_inference.d2h_overlapped").inc()
-            t1 = time.perf_counter()
-            # wire dtype (bf16/f16) widens back to f32 at the batch edge
-            if pf.gathered:
-                picks = scores_np[: pf.moved].astype(np.float32, copy=False)
-            else:
-                picks = scores_np[_slots, _cols].astype(np.float32, copy=False)
-            # score-quality accounting: per-flush NaN census + the
-            # device sketch folded into the tenant drift windows, all
-            # vectorized (runtime.scorehealth; nan attribution rides the
-            # pack-order slots — one bincount, never a per-row loop)
-            nan_mask = np.isnan(picks)
-            nan_rows = int(nan_mask.sum())
-            if nan_rows:
-                self.metrics.counter(
-                    "tpu_scores_nan_total", family=pf.family
-                ).inc(nan_rows)
-            if sketch_np is not None:
-                nan_by_slot = None
+            with sw("resolve", flush_id=pf.flush_id):
+                t1 = time.perf_counter()
+                # wire dtype (bf16/f16) widens back to f32 at the batch edge
+                if pf.gathered:
+                    picks = scores_np[: pf.moved].astype(np.float32, copy=False)
+                else:
+                    picks = scores_np[_slots, _cols].astype(np.float32, copy=False)
+                # score-quality accounting: per-flush NaN census + the
+                # device sketch folded into the tenant drift windows, all
+                # vectorized (runtime.scorehealth; nan attribution rides the
+                # pack-order slots — one bincount, never a per-row loop)
+                nan_mask = np.isnan(picks)
+                nan_rows = int(nan_mask.sum())
                 if nan_rows:
-                    # picks align with the pack-order slots on BOTH the
-                    # gathered and full-plane fallback paths; only the
-                    # single-slot slice zeroed them (override carries it)
-                    if pf.slot_override is not None:
-                        nan_by_slot = np.zeros(
-                            (sketch_np.shape[0],), np.int64
-                        )
-                        nan_by_slot[pf.slot_override] = nan_rows
-                    else:
-                        nan_by_slot = np.bincount(
-                            _slots[nan_mask], minlength=sketch_np.shape[0]
-                        )
-                self.scorehealth.ingest_sketch(
-                    pf.family, sketch_np.sum(axis=1), nan_by_slot,
-                    mesh_slice=pf.sl,
+                    self.metrics.counter(
+                        "tpu_scores_nan_total", family=pf.family
+                    ).inc(nan_rows)
+                if sketch_np is not None:
+                    nan_by_slot = None
+                    if nan_rows:
+                        # picks align with the pack-order slots on BOTH the
+                        # gathered and full-plane fallback paths; only the
+                        # single-slot slice zeroed them (override carries it)
+                        if pf.slot_override is not None:
+                            nan_by_slot = np.zeros(
+                                (sketch_np.shape[0],), np.int64
+                            )
+                            nan_by_slot[pf.slot_override] = nan_rows
+                        else:
+                            nan_by_slot = np.bincount(
+                                _slots[nan_mask], minlength=sketch_np.shape[0]
+                            )
+                    self.scorehealth.ingest_sketch(
+                        pf.family, sketch_np.sum(axis=1), nan_by_slot,
+                        mesh_slice=pf.sl,
+                    )
+                if shadow_np is not None:
+                    self._canary_compare(pf, picks, shadow_np)
+                # cancellation past this point observes only INSIDE
+                # _resolve_rows' publish loop (the scatter is await-free), so
+                # scores are written and counts decremented exactly once —
+                # the cancel path below must not resolve a second time
+                scattered = True
+                await self._resolve_rows(
+                    seqs, rows, picks, flush_id=pf.flush_id
                 )
-            if shadow_np is not None:
-                self._canary_compare(pf, picks, shadow_np)
-            # cancellation past this point observes only INSIDE
-            # _resolve_rows' publish loop (the scatter is await-free), so
-            # scores are written and counts decremented exactly once —
-            # the cancel path below must not resolve a second time
-            scattered = True
-            await self._resolve_rows(seqs, rows, picks)
-            resolve_s = time.perf_counter() - t1
+                t_resolved = time.perf_counter()
+                resolve_s = t_resolved - t1
             self.metrics.histogram("tpu_inference.resolve", unit="s").record(
                 resolve_s
             )
             self.metrics.counter("tpu_inference.reaped").inc()
             self.metrics.counter("tpu_inference.d2h_bytes").inc(pf.nbytes)
-            # device-time / MFU attribution: the dispatch was outstanding
-            # from issue until its transfer landed — that window times
-            # this flush's executed FLOPs (padded plane; see
-            # ShardedScorer.flops_per_flush)
+            # the in-flight interval: dispatch returned → transfer landed.
+            # With ``max_inflight`` flushes queued on the device these
+            # windows OVERLAP — it is what an event waits, and what the
+            # flush supervisor's deadline bounds (the next flush's
+            # deadline tracks this (family, slice)'s observed p99)
             device_s = max(0.0, now - pf.t_dispatch)
-            # ...and the flush supervisor's deadline history: the next
-            # flush's deadline tracks this (family, slice)'s observed
-            # dispatch→landed p99
+            self.metrics.histogram("tpu_inference.inflight", unit="s").record(
+                device_s
+            )
             self._note_device_s(pf.key, device_s)
+            # the service time (taken at the landing, above): the slice's
+            # device queue is FIFO, so this flush had the device from the
+            # later of its own dispatch and the previous landing. These
+            # windows never overlap — they are what device-time and MFU
+            # attribution divide by (this flush's executed FLOPs: padded
+            # plane, see ShardedScorer.flops_per_flush)
+            self.metrics.histogram("tpu_inference.service", unit="s").record(
+                service_s
+            )
             if pf.flops:
-                self._mfu_account(pf.family).record(pf.flops, device_s)
+                self._mfu_account(pf.family).record(pf.flops, service_s)
                 if self.mm.n_devices > 1:
                     # per-chip utilization beside the family aggregate:
                     # each slice's flushes feed ITS device's account
                     self._mfu_device_account(pf.family, pf.sl).record(
-                        pf.flops, device_s
+                        pf.flops, service_s
                     )
             d2h_labels = {"family": pf.family}
             if self.mm.n_devices > 1:
@@ -4252,21 +4354,15 @@ class TpuInferenceService(MultitenantService):
             self.metrics.counter(
                 "tpu_inference_d2h_bytes_total", **d2h_labels
             ).inc(pf.nbytes)
-            # complete the family's latency-attribution profile: the
-            # inference span annotates with the LAST RESOLVED flush's
-            # full sub-stage split (a per-batch approximation; the
-            # ledger scales it so it never exceeds the span)
-            prof = self._last_flush.get(pf.family)
-            if prof is not None:
-                prof["flush_device_s"] = round(device_s, 6)
-                prof["flush_d2h_wait_s"] = round(waited_s, 6)
-                prof["flush_resolve_s"] = round(resolve_s, 6)
             if pf.rec is not None:
-                # complete the blackbox record in place (see flightrec)
+                # complete the flush record in place (see flightrec)
                 pf.rec["d2h_wait_s"] = round(waited_s, 6)
                 pf.rec["d2h_overlapped"] = d2h_overlapped
                 pf.rec["resolve_s"] = round(resolve_s, 6)
                 pf.rec["device_s"] = round(device_s, 6)
+                pf.rec["service_s"] = round(service_s, 6)
+                pf.rec["t_landed"] = now
+                pf.rec["t_resolved"] = t_resolved
                 pf.rec["status"] = "ok"
                 # score-quality fields: incident snapshots can now see
                 # WHAT the flush scored, not just how long it took

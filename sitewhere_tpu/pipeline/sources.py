@@ -32,6 +32,7 @@ from sitewhere_tpu.pipeline.decoders import (
 from sitewhere_tpu.runtime.bus import EventBus, RetryingConsumer
 from sitewhere_tpu.runtime.config import FaultTolerancePolicy
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent, cancel_and_wait
+from sitewhere_tpu.runtime.loopledger import sw
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 from sitewhere_tpu.runtime.overload import (
     PRIORITY_NAMES,
@@ -75,6 +76,9 @@ class InboundReceiver(LifecycleComponent):
             self.shed_hook(priority, n)
 
     async def submit(self, payload: bytes, **context: Any) -> None:
+        # broker delivery, on the flush record's clock: where the
+        # ``pipeline.intake`` interval of the payload's batch starts
+        context["_recv_pc"] = time.perf_counter()
         if self.stamp_recv_ts:
             context["_recv_t"] = time.time() * 1000.0
         await self.queue.put(
@@ -86,6 +90,7 @@ class InboundReceiver(LifecycleComponent):
         watermark sheds the OLDEST queued payload of the lowest present
         class (newest data wins under burst — counted, never raised
         into the receiver loop)."""
+        context["_recv_pc"] = time.perf_counter()
         if self.stamp_recv_ts:
             context["_recv_t"] = time.time() * 1000.0
         self.queue.put_nowait((payload, context), classify_priority(context))
@@ -384,128 +389,131 @@ class EventSource(LifecycleComponent):
                 )
 
             item = await q.get()
-            now = now_ms()
-            first_context = item[1]  # decode-span baggage + queue wait
-            while True:
-                payload, context = item
-                n_payloads += 1
-                try:
-                    if decode_any is not None:
-                        kind, out = decode_any(payload, context)
+            with sw("intake"):
+                now = now_ms()
+                first_context = item[1]  # decode-span baggage + queue wait
+                while True:
+                    payload, context = item
+                    n_payloads += 1
+                    try:
+                        if decode_any is not None:
+                            kind, out = decode_any(payload, context)
+                        else:
+                            kind, out = "requests", self.decoder.decode(payload, context)
+                    except Exception as exc:  # noqa: BLE001 - any bad payload (incl.
+                        # UnicodeDecodeError from garbled bytes) must not kill the pump
+                        await report_failed(payload, context, exc)
+                        kind, out = "requests", []
+                    if kind == "columns":
+                        toks, names, vals, ets = out
+                        c_toks.extend(toks)
+                        c_names.extend(names)
+                        c_vals.extend(vals)
+                        c_ets.extend(ets)
+                        n_events += len(vals)
+                    elif kind == "columns_np":
+                        np_chunks.extend(out)
+                        n_events += sum(len(c[2]) for c in out)
                     else:
-                        kind, out = "requests", self.decoder.decode(payload, context)
-                except Exception as exc:  # noqa: BLE001 - any bad payload (incl.
-                    # UnicodeDecodeError from garbled bytes) must not kill the pump
-                    await report_failed(payload, context, exc)
-                    kind, out = "requests", []
-                if kind == "columns":
-                    toks, names, vals, ets = out
-                    c_toks.extend(toks)
-                    c_names.extend(names)
-                    c_vals.extend(vals)
-                    c_ets.extend(ets)
-                    n_events += len(vals)
-                elif kind == "columns_np":
-                    np_chunks.extend(out)
-                    n_events += sum(len(c[2]) for c in out)
-                else:
-                    n_events += len(out)
-                    await self._route_requests(
-                        out, measurements, decoded_topic, duped, decoded_ctr, now
-                    )
-                if n_events >= self.EVENT_CAP or n_payloads >= self.DRAIN:
-                    break
-                try:
-                    item = q.get_nowait()
-                except asyncio.QueueEmpty:
-                    break
-            received.inc(n_payloads)
-            out_batches = []
-            # batch construction must not kill the pump on one malformed
-            # row (e.g. a string value the decoder didn't vet) — drop the
-            # offending group to the failed topic instead
-            if np_chunks:
-                try:
-                    out_batches.append(MeasurementBatch.from_column_chunks(
-                        self.tenant, np_chunks, received_ms=float(now),
-                    ))
-                except Exception as exc:  # noqa: BLE001
-                    await report_failed(b"<bulk chunk batch>", {}, exc)
-            if c_vals:
-                try:
-                    out_batches.append(MeasurementBatch.from_columns(
-                        self.tenant, c_toks, c_names, c_vals, c_ets,
-                        received_ms=float(now),
-                    ))
-                except Exception as exc:  # noqa: BLE001
-                    await report_failed(b"<columnar batch>", {}, exc)
-            if measurements:
-                try:
-                    out_batches.append(
-                        MeasurementBatch.from_requests(self.tenant, measurements)
-                    )
-                except Exception:  # noqa: BLE001 - salvage: re-try row by
-                    # row so one bad request doesn't drop its whole group
-                    good = []
-                    for req in measurements:
-                        try:
-                            float(req.get("value", 0.0))
-                            float(req.get("event_ts", now))
-                            good.append(req)
-                        except (TypeError, ValueError) as exc:
-                            await report_failed(
-                                json.dumps(req, default=str).encode(), {}, exc
-                            )
-                    if good:
-                        out_batches.append(
-                            MeasurementBatch.from_requests(self.tenant, good)
+                        n_events += len(out)
+                        await self._route_requests(
+                            out, measurements, decoded_topic, duped, decoded_ctr, now
                         )
-            t_done = time.time() * 1000.0
-            src_topic = str(first_context.get("topic", self.source_id))
-            recv_t = first_context.get("_recv_t")
-            queue_wait = max(0.0, float(now) - recv_t) if recv_t else 0.0
-            traced = self.tracer is not None and self.tracer.enabled_for(
-                self.tenant
-            )
-            # admission deadline: accepted work gets `admission + budget`
-            # from the tenant's OverloadPolicy — anchored at the receiver
-            # enqueue stamp when present so receiver-queue wait spends
-            # budget too; every downstream stage consults the remainder
-            # (runtime.overload.DeadlineGate)
-            budget = (
-                self.overload.deadline_ms(self.tenant)
-                if self.overload is not None
-                else None
-            )
-            deadline_base = float(recv_t) if recv_t else float(now)
-            for mb in out_batches:
-                if budget is not None:
-                    mb.deadline_ms = deadline_base + budget
-                if traced:
-                    # mint at the edge; the context rides the batch through
-                    # every stage (and over the netbus wire, pickled)
-                    dev = (
-                        str(mb.device_tokens[0])
-                        if mb.device_tokens is not None and mb.n
-                        else ""
-                    )
-                    mb.trace_ctx = self.tracer.mint(
-                        self.tenant, device=dev, source_topic=src_topic,
-                        # the admission class rides the context so the
-                        # latency ledger cohorts by (tenant, priority)
-                        priority=PRIORITY_NAMES[
-                            classify_priority(first_context)
-                        ],
-                    )
-                # span recorded BEFORE the publish so the downstream
-                # stage's span parents under this one deterministically
-                self.stage_timer.observe(
-                    mb, float(now), t_done, n_events=mb.n,
-                    queue_wait_ms=queue_wait,
+                    if n_events >= self.EVENT_CAP or n_payloads >= self.DRAIN:
+                        break
+                    try:
+                        item = q.get_nowait()
+                    except asyncio.QueueEmpty:
+                        break
+                received.inc(n_payloads)
+                out_batches = []
+                # batch construction must not kill the pump on one malformed
+                # row (e.g. a string value the decoder didn't vet) — drop the
+                # offending group to the failed topic instead
+                if np_chunks:
+                    try:
+                        out_batches.append(MeasurementBatch.from_column_chunks(
+                            self.tenant, np_chunks, received_ms=float(now),
+                        ))
+                    except Exception as exc:  # noqa: BLE001
+                        await report_failed(b"<bulk chunk batch>", {}, exc)
+                if c_vals:
+                    try:
+                        out_batches.append(MeasurementBatch.from_columns(
+                            self.tenant, c_toks, c_names, c_vals, c_ets,
+                            received_ms=float(now),
+                        ))
+                    except Exception as exc:  # noqa: BLE001
+                        await report_failed(b"<columnar batch>", {}, exc)
+                if measurements:
+                    try:
+                        out_batches.append(
+                            MeasurementBatch.from_requests(self.tenant, measurements)
+                        )
+                    except Exception:  # noqa: BLE001 - salvage: re-try row by
+                        # row so one bad request doesn't drop its whole group
+                        good = []
+                        for req in measurements:
+                            try:
+                                float(req.get("value", 0.0))
+                                float(req.get("event_ts", now))
+                                good.append(req)
+                            except (TypeError, ValueError) as exc:
+                                await report_failed(
+                                    json.dumps(req, default=str).encode(), {}, exc
+                                )
+                        if good:
+                            out_batches.append(
+                                MeasurementBatch.from_requests(self.tenant, good)
+                            )
+                t_done = time.time() * 1000.0
+                src_topic = str(first_context.get("topic", self.source_id))
+                recv_t = first_context.get("_recv_t")
+                queue_wait = max(0.0, float(now) - recv_t) if recv_t else 0.0
+                traced = self.tracer is not None and self.tracer.enabled_for(
+                    self.tenant
                 )
-                mb.mark("decoded")
-                await self.retry.publish(decoded_topic, mb)
-                decoded_ctr.inc(mb.n)
+                # admission deadline: accepted work gets `admission + budget`
+                # from the tenant's OverloadPolicy — anchored at the receiver
+                # enqueue stamp when present so receiver-queue wait spends
+                # budget too; every downstream stage consults the remainder
+                # (runtime.overload.DeadlineGate)
+                budget = (
+                    self.overload.deadline_ms(self.tenant)
+                    if self.overload is not None
+                    else None
+                )
+                deadline_base = float(recv_t) if recv_t else float(now)
+                t_intake = first_context.get("_recv_pc", 0.0)
+                for mb in out_batches:
+                    mb.t_intake = t_intake
+                    if budget is not None:
+                        mb.deadline_ms = deadline_base + budget
+                    if traced:
+                        # mint at the edge; the context rides the batch through
+                        # every stage (and over the netbus wire, pickled)
+                        dev = (
+                            str(mb.device_tokens[0])
+                            if mb.device_tokens is not None and mb.n
+                            else ""
+                        )
+                        mb.trace_ctx = self.tracer.mint(
+                            self.tenant, device=dev, source_topic=src_topic,
+                            # the admission class rides the context so the
+                            # latency ledger cohorts by (tenant, priority)
+                            priority=PRIORITY_NAMES[
+                                classify_priority(first_context)
+                            ],
+                        )
+                    # span recorded BEFORE the publish so the downstream
+                    # stage's span parents under this one deterministically
+                    self.stage_timer.observe(
+                        mb, float(now), t_done, n_events=mb.n,
+                        queue_wait_ms=queue_wait,
+                    )
+                    mb.mark("decoded")
+                    await self.retry.publish(decoded_topic, mb)
+                    decoded_ctr.inc(mb.n)
 
     async def _route_requests(
         self, reqs, measurements, decoded_topic, duped, decoded_ctr, now

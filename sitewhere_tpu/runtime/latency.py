@@ -16,16 +16,25 @@ the engine (``ingest_trace``). Each trace flattens into an additive
 per-stage vector (``stage_vector``): the spans' queue-wait/service
 split maps onto the canonical stage axis
 
-    ingest → decode → inbound → lane_wait → flush_assembly → dispatch
-    → d2h_wait → resolve → persistence → rules → outbound
+    ingest → decode → inbound → lane_wait → permit_wait → flush_assembly
+    → h2d_stage → dispatch → inflight → resolve → persistence → rules
+    → outbound
 
-where the inference span's service time is split into its lane-wait /
-flush-assembly / dispatch / d2h-wait / resolve sub-stages using the
-flush profile annotations the inference service stamps on the span
-(the family's most recently RESOLVED flush — a per-batch approximation
-scaled to never exceed the span it decomposes). ``rules`` runs on the
-persisted-events fork concurrently with outbound, so it is recorded in
-the waterfall but excluded from the additive critical path.
+where the inference span's service time (lane enqueue → published on
+scored-events) is cut at the boundaries of the batch's OWN flush: the
+span carries the ``flush_id`` of the flush that completed the batch,
+and the flush record (``TpuInferenceService.flush_records``) holds that
+flush's contiguous ``time.perf_counter()`` stamps — permit asked, permit
+got, assembled, h2d staged, dispatch returned, transfer landed. The
+cuts are contiguous, so the seven sub-stages sum to the span exactly;
+nothing is scaled. ``lane_wait`` (enqueue → permit asked) and
+``resolve`` (landed → this batch published) are the batch's own; the
+five between are its flush's. ``inflight`` is where an event waits
+behind the flushes queued on the device before its own
+(``tpu_inference.d2h_wait`` lies inside it and stays a histogram only).
+``rules`` runs on the persisted-events fork concurrently with outbound,
+so it is recorded in the waterfall but excluded from the additive
+critical path.
 
 Decomposition is additive **by construction**: the per-(tenant,
 priority) ledger keeps a bounded window of whole vectors, picks the
@@ -51,6 +60,7 @@ can assert attribution costs <2% of step time.
 from __future__ import annotations
 
 import time
+from array import array
 from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -58,27 +68,41 @@ from sitewhere_tpu.runtime.metrics import MetricsRegistry, RollingQuantile
 
 # the canonical stage axis — waterfall row order and the additive path
 STAGES = (
-    "ingest", "decode", "inbound", "lane_wait", "flush_assembly",
-    "dispatch", "d2h_wait", "resolve", "persistence", "rules", "outbound",
+    "ingest", "decode", "inbound", "lane_wait", "permit_wait",
+    "flush_assembly", "h2d_stage", "dispatch", "inflight", "resolve",
+    "persistence", "rules", "outbound",
 )
 
 # rules consumes the persisted-events fork CONCURRENTLY with outbound:
 # it shows in the waterfall but never in the additive e2e path
 PATH_STAGES = tuple(s for s in STAGES if s != "rules")
 
-# inference-span sub-stages derived from the flush profile annotations
-# (seconds keys as stamped by TpuInferenceService on the span)
-_FLUSH_SUBS = (
-    ("flush_assembly", ("flush_assembly_s", "flush_h2d_s")),
-    ("dispatch", ("flush_device_s",)),
-    ("d2h_wait", ("flush_d2h_wait_s",)),
-    ("resolve", ("flush_resolve_s",)),
+# where a stage's [wait, service] pair sits in a ledger entry (slot 0 is
+# the trace total)
+_ENTRY_AT = {s: 1 + 2 * i for i, s in enumerate(STAGES)}
+_ENTRY_ZEROS = bytes(8 * (1 + 2 * len(STAGES)))
+
+# the inference span's contiguous cuts: (stage it ends, the flush
+# record's perf_counter stamp that ends it). ``lane_wait`` runs from the
+# span's start to the first stamp; ``resolve`` from the last to its end.
+_FLUSH_CUTS = (
+    ("lane_wait", "t_asked"),
+    ("permit_wait", "t_got"),
+    ("flush_assembly", "t_assembled"),
+    ("h2d_stage", "t_staged"),
+    ("dispatch", "t_dispatched"),
+    ("inflight", "t_landed"),
 )
 
 
-def stage_vector(tr: Any) -> Tuple[Dict[str, List[float]], float]:
+def stage_vector(
+    tr: Any, flushes: Optional[Any] = None
+) -> Tuple[Dict[str, List[float]], float]:
     """Flatten one TraceRecord into the additive per-stage vector:
     ``{stage: [queue_wait_ms, service_ms]}`` plus the trace total.
+    ``flushes`` maps a ``flush_id`` to its flush record (``.get``); an
+    inference span whose flush is not there (no id, evicted, resolved
+    unscored) stays whole under ``lane_wait``.
     Multiple spans of one LINEAR stage (sequential sub-batches) sum;
     fork stages (rules/outbound — one sibling span per connector,
     concurrent) keep their slowest sibling, since summing overlapped
@@ -103,27 +127,23 @@ def stage_vector(tr: Any) -> Tuple[Dict[str, List[float]], float]:
             acc("ingest", 0.0, wait)
             acc("decode", 0.0, service)
         elif st == "inference":
-            # split the inference span on its flush profile; whatever the
-            # profile does not claim stays lane_wait (rows sitting in the
-            # lane ring awaiting flush assembly)
-            ann = s.annotations
-            subs: List[Tuple[str, float]] = []
-            claimed = 0.0
-            for name, keys in _FLUSH_SUBS:
-                ms = sum(float(ann.get(k, 0.0) or 0.0) for k in keys) * 1e3
-                if ms > 0.0:
-                    subs.append((name, ms))  # hotpath: ok (≤4 sub-stages per span, bounded by _FLUSH_SUBS — not a per-row collector)
-                    claimed += ms
-            if claimed > service and claimed > 0.0:
-                # the profile is the LAST resolved flush, not this batch's
-                # own — scale so sub-stages never exceed the span they
-                # decompose (keeps the vector additive)
-                scale = service / claimed
-                subs = [(n, ms * scale) for n, ms in subs]
-                claimed = service
-            acc("lane_wait", wait, max(0.0, service - claimed))
-            for name, ms in subs:
-                acc(name, 0.0, ms)
+            rec = None
+            if flushes is not None:
+                rec = flushes.get(s.annotations.get("flush_id"))
+            if rec is None or "t_landed" not in rec:
+                acc("lane_wait", wait, service)
+                continue
+            # the record's stamps are perf_counter seconds; ``ts_ms`` is
+            # the wall clock at ``t_dispatched`` — the one anchor that
+            # puts them on the span's clock. Each cut is clamped into
+            # the span, so the pieces sum to it whatever the clocks did.
+            anchor = rec["ts_ms"] - rec["t_dispatched"] * 1e3
+            at = s.start_ms
+            for stage, stamp in _FLUSH_CUTS:
+                cut = min(max(anchor + rec[stamp] * 1e3, at), s.end_ms)
+                acc(stage, wait if stage == "lane_wait" else 0.0, cut - at)
+                at = cut
+            acc("resolve", 0.0, s.end_ms - at)
         elif st in ("inbound", "persistence"):
             acc(st, wait, service)
         elif st in ("rules", "outbound"):
@@ -185,15 +205,23 @@ class StageLedger:
     def __init__(self, tenant: str, priority: str) -> None:
         self.tenant = tenant
         self.priority = priority
-        # (total_ms, {stage: [wait_ms, service_ms]})
+        # one array('d') a trace: total_ms, then (wait_ms, service_ms) per
+        # stage of STAGES. A window of dicts of lists was some fifteen
+        # containers a trace for the collector to walk in every full
+        # collection — times WINDOW, times every tenant; an array is none
         self.entries: deque = deque(maxlen=self.WINDOW)
         self.stage_q: Dict[str, RollingQuantile] = {}
         self.e2e_q = RollingQuantile(window=256)
 
     def add(self, vec: Dict[str, List[float]], total_ms: float) -> None:
-        self.entries.append((total_ms, vec))
+        entry = array("d", _ENTRY_ZEROS)
+        entry[0] = total_ms
+        self.entries.append(entry)
         self.e2e_q.add(total_ms)
         for stage, (wait, service) in vec.items():
+            at = _ENTRY_AT[stage]
+            entry[at] = wait
+            entry[at + 1] = service
             q = self.stage_q.get(stage)
             if q is None:
                 q = self.stage_q[stage] = RollingQuantile(window=256)
@@ -219,8 +247,9 @@ class StageLedger:
         stages: List[Dict[str, Any]] = []
         attributed = 0.0
         for stage in STAGES:
-            wait = sum(e[1].get(stage, (0.0, 0.0))[0] for e in cohort) / m
-            service = sum(e[1].get(stage, (0.0, 0.0))[1] for e in cohort) / m
+            at = _ENTRY_AT[stage]
+            wait = sum(e[at] for e in cohort) / m
+            service = sum(e[at + 1] for e in cohort) / m
             tot = wait + service
             if stage in PATH_STAGES:
                 attributed += tot
@@ -255,10 +284,10 @@ class StageLedger:
         return best["stage"] if best and best["total_ms"] > 0 else ""
 
 
-def dominant_stage_of(tr: Any) -> str:
+def dominant_stage_of(tr: Any, flushes: Optional[Any] = None) -> str:
     """One retained trace's dominant stage (critical-path extractor unit):
     the on-path stage with the largest wait+service in ITS OWN vector."""
-    vec, _total = stage_vector(tr)
+    vec, _total = stage_vector(tr, flushes)
     best, best_ms = "", 0.0
     for stage in PATH_STAGES:
         cell = vec.get(stage)
@@ -292,6 +321,10 @@ class LatencyEngine:
         # tracing bridge, set by the instance (read-only here): the
         # critical-path extractor walks tracer.store's retained ring
         self.tracer = None
+        # flush_id → flush record, set by the instance to the scoring
+        # service's ``flush_records``: what ``stage_vector`` cuts each
+        # inference span by
+        self.flushes = None
         # self-timing: the bench's attribution-overhead key reads these
         self.ingest_calls = 0
         self.ingest_secs = 0.0
@@ -330,7 +363,7 @@ class LatencyEngine:
                     self._ledgers.popitem(last=False)
                 led = self._ledgers[key] = StageLedger(tr.tenant, priority)
             self._ledgers.move_to_end(key)
-            vec, total = stage_vector(tr)
+            vec, total = stage_vector(tr, self.flushes)
             led.add(vec, total)
             self._slo_ms[tr.tenant] = float(slo_ms)
             if priority != "replay":
@@ -431,7 +464,7 @@ class LatencyEngine:
                 slo is not None and tr.duration_ms >= slo
             ):
                 continue
-            stage = dominant_stage_of(tr) or "unattributed"
+            stage = dominant_stage_of(tr, self.flushes) or "unattributed"
             groups.setdefault((tr.tenant, stage), []).append(tr)
         out: List[Dict[str, Any]] = []
         for (t, stage), trs in groups.items():
@@ -495,8 +528,7 @@ class LatencyEngine:
         merged = StageLedger("", "")
         cohorts: List[Dict[str, Any]] = []
         for (tenant, priority), led in self._ledgers.items():
-            for total, vec in led.entries:
-                merged.entries.append((total, vec))
+            merged.entries.extend(led.entries)
             d = led.decompose()
             cohorts.append({
                 "tenant": tenant,

@@ -25,6 +25,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from sitewhere_tpu.runtime.loopledger import LoopLedger
+
 
 # a device→host materialization that returns faster than this never
 # waited on the transfer — the boundary for the d2h_overlapped counters.
@@ -434,6 +436,10 @@ class MetricsRegistry:
         self._kinds: Dict[str, str] = {}  # labeled family → prometheus kind
         self._help: Dict[str, str] = {}
         self._reg_lock = threading.Lock()
+        # the event-loop ledger (loop_busy_seconds_total{stage}): lives
+        # with the registry because every stage already holds one; it
+        # registers nothing until the instance installs it on its loop
+        self.loop_ledger = LoopLedger(self)
 
     def describe(self, name: str, help_text: str) -> None:
         """Attach a ``# HELP`` string to a metric family."""

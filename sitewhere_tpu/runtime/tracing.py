@@ -336,7 +336,7 @@ class Tracer:
             queue_wait_ms=max(0.0, queue_wait_ms),
             n_events=n_events,
             error=error,
-            annotations=dict(annotations) if annotations else {},
+            annotations=annotations,
         )
         tr.add_span(span)
         if advance:
@@ -454,7 +454,7 @@ class StageTimer:
 
     __slots__ = (
         "tracer", "tenant", "stage", "service_h", "wait_h", "events_c",
-        "_fr_tick",
+        "_fr_tick", "_ledger",
     )
 
     # flight-recorder stride: one per-stage blackbox record every Nth
@@ -475,6 +475,7 @@ class StageTimer:
         # primed so the FIRST batch records (evidence exists from the
         # start), then every FLIGHTREC_STRIDE-th
         self._fr_tick = self.FLIGHTREC_STRIDE - 1
+        self._ledger = metrics.loop_ledger
         metrics.describe(
             "pipeline_stage_seconds",
             "per-stage service time (handler run) per tenant",
@@ -507,6 +508,12 @@ class StageTimer:
         queue_wait_ms: Optional[float] = None,
         **annotations: Any,
     ) -> None:
+        # what this recorder — and the span, tail decision, ledger feed
+        # and blackbox record under it — costs the event loop is charged
+        # to the loop ledger's ``observe`` stage, not to the stage that
+        # called it (nothing is read while no step is being timed)
+        ledger = self._ledger
+        t_observe = ledger.clock() if ledger.current is not None else 0.0
         if queue_wait_ms is None:
             queue_wait_ms = queue_wait_from(item, start_ms)
         self.service_h.record(max(0.0, end_ms - start_ms) / 1000.0)
@@ -543,6 +550,8 @@ class StageTimer:
                         rec["error"] = error
                     if hot and not error:
                         rec["forced"] = "tail"
+        if t_observe:
+            ledger.observe_from(t_observe)
 
 
 def queue_wait_from(item: Any, start_ms: float) -> float:

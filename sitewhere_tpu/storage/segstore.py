@@ -58,6 +58,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from sitewhere_tpu.core.batch import make_event_ids
+from sitewhere_tpu.runtime.loopledger import spanned
 
 SEG_MAGIC = b"SWS"
 SEG_VERSION = 1
@@ -687,6 +688,7 @@ class SegmentColumns:
         )
         return list(vocab_map), merged_inv
 
+    @spanned("seal")
     def _seal(self) -> None:
         """Seal the tail (pending chunks + live rows) into one Segment:
         encode the wire layout once, compute the zone map, write + fsync

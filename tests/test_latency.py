@@ -82,10 +82,11 @@ def _feed_trace(
     persistence_svc: float = 1.5,
 ):
     """One full-pipeline trace with controlled timings: decode qw 2 /
-    svc 3, inbound 1/2, inference 4/20 split by a flush profile claiming
-    12 ms (assembly 3, dispatch 4, d2h 3, resolve 2 → lane_wait keeps
-    the remaining 8), persistence 0.5/<svc>, a rules fork span 0.2/1,
-    and TWO concurrent outbound siblings (0.3/2 and 0.1/5)."""
+    svc 3, inbound 1/2, inference 4/20 carrying ``flush_id`` 7 (cut by
+    ``_flush_record`` into lane_wait 4, permit_wait 1, assembly 1, h2d
+    0.5, dispatch 0.5, inflight 10, resolve 3), persistence 0.5/<svc>, a
+    rules fork span 0.2/1, and TWO concurrent outbound siblings (0.3/2
+    and 0.1/5)."""
     ctx = tracer.mint(tenant, priority=priority)
     b = now_ms()
     tracer.record_span(ctx, "decode", b + 2, b + 5, queue_wait_ms=2.0,
@@ -93,8 +94,7 @@ def _feed_trace(
     tracer.record_span(ctx, "inbound", b + 6, b + 8, queue_wait_ms=1.0)
     tracer.record_span(
         ctx, "inference", b + 12, b + 32, queue_wait_ms=4.0,
-        flush_assembly_s=0.002, flush_h2d_s=0.001, flush_device_s=0.004,
-        flush_d2h_wait_s=0.003, flush_resolve_s=0.002,
+        family="lstm_ad", flush_id=7,
     )
     end_p = b + 33.5 + persistence_svc
     tracer.record_span(ctx, "persistence", b + 33.5, end_p,
@@ -107,6 +107,25 @@ def _feed_trace(
     return ctx
 
 
+def _flush_record(span_start_ms: float, offsets_ms, flush_id: int = 7):
+    """A flush record as ``TpuInferenceService._flush_record`` leaves it:
+    perf_counter stamps (here: seconds on an arbitrary origin) and
+    ``ts_ms``, the wall clock at ``t_dispatched``. ``offsets_ms`` are the
+    six stamps' offsets from the span's start."""
+    names = ("t_asked", "t_got", "t_assembled", "t_staged", "t_dispatched",
+             "t_landed")
+    origin = 5000.0  # perf_counter seconds; only differences matter
+    rec = {"flush_id": flush_id}
+    for name, off in zip(names, offsets_ms):
+        rec[name] = origin + off / 1e3
+    rec["ts_ms"] = span_start_ms + offsets_ms[4]
+    return rec
+
+
+_FLUSH_STAGES = ("lane_wait", "permit_wait", "flush_assembly", "h2d_stage",
+                 "dispatch", "inflight", "resolve")
+
+
 # ------------------------------------------- (a) stage-vector flattening
 def test_stage_vector_axis_mapping_and_fork_max():
     reg = MetricsRegistry()
@@ -114,17 +133,24 @@ def test_stage_vector_axis_mapping_and_fork_max():
                                                slo_ms=60_000))
     ctx = _feed_trace(tracer)
     tr = tracer.store.peek(ctx.trace_id)
-    vec, total = stage_vector(tr)
+    inf = next(s for s in tr.spans if s.stage == "inference")
+    flushes = {7: _flush_record(inf.start_ms, (4, 5, 6, 6.5, 7, 17))}
+    vec, total = stage_vector(tr, flushes)
     # decode queue wait IS the ingest stage (receive → decode start)
     assert vec["ingest"] == [0.0, pytest.approx(2.0)]
     assert vec["decode"] == [0.0, pytest.approx(3.0)]
     assert vec["inbound"] == [pytest.approx(1.0), pytest.approx(2.0)]
-    # inference span split on the flush profile; unclaimed → lane_wait
-    assert vec["lane_wait"] == [pytest.approx(4.0), pytest.approx(8.0)]
-    assert vec["flush_assembly"][1] == pytest.approx(3.0)
-    assert vec["dispatch"][1] == pytest.approx(4.0)
-    assert vec["d2h_wait"][1] == pytest.approx(3.0)
-    assert vec["resolve"][1] == pytest.approx(2.0)
+    # inference span cut at the boundaries of ITS OWN flush's record
+    assert vec["lane_wait"] == [pytest.approx(4.0), pytest.approx(4.0)]
+    assert vec["permit_wait"][1] == pytest.approx(1.0)
+    assert vec["flush_assembly"][1] == pytest.approx(1.0)
+    assert vec["h2d_stage"][1] == pytest.approx(0.5)
+    assert vec["dispatch"][1] == pytest.approx(0.5)
+    assert vec["inflight"][1] == pytest.approx(10.0)
+    assert vec["resolve"][1] == pytest.approx(3.0)
+    assert "d2h_wait" not in vec  # a histogram, no longer on the axis
+    # contiguous cuts: the seven pieces ARE the span, nothing scaled
+    assert sum(vec[s][1] for s in _FLUSH_STAGES) == pytest.approx(20.0)
     assert vec["persistence"] == [pytest.approx(0.5), pytest.approx(1.5)]
     # fork stages keep the SLOWEST sibling, never the overlapped sum
     assert vec["outbound"] == [pytest.approx(0.1), pytest.approx(5.0)]
@@ -133,32 +159,43 @@ def test_stage_vector_axis_mapping_and_fork_max():
     # additivity: the on-path stages never claim more than the trace total
     on_path = sum(sum(vec[s]) for s in PATH_STAGES if s in vec)
     assert on_path <= total + 0.01
-    assert dominant_stage_of(tr) == "lane_wait"
+    assert dominant_stage_of(tr, flushes) == "inflight"
+    # without its flush's record the span stays whole under lane_wait
+    vec0, _ = stage_vector(tr)
+    assert vec0["lane_wait"] == [pytest.approx(4.0), pytest.approx(20.0)]
 
 
-def test_stage_vector_scales_stale_flush_profile():
-    """The flush profile is the family's LAST resolved flush, not this
-    batch's own — when it claims more than the span it decomposes, the
-    sub-stages scale down so the vector stays additive."""
+def test_stage_vector_never_scales_and_sums_to_the_span():
+    """The inference span is cut, not apportioned: whatever the flush
+    record claims — stamps before the span began (a batch that waited
+    out an earlier flush), or after it ended — every cut is clamped
+    into the span, so the stages sum to it exactly and nothing is ever
+    scaled (the old ledger scaled a neighbour flush's 12 ms profile
+    down into a 5 ms span)."""
     reg = MetricsRegistry()
     tracer = Tracer(reg, default=TracingConfig(sample_rate=1.0,
                                                slo_ms=60_000))
     ctx = tracer.mint("t1")
     b = now_ms()
-    # 5 ms span carrying a 12 ms profile → scale 5/12, lane_wait svc 0
-    tracer.record_span(
-        ctx, "inference", b, b + 5, queue_wait_ms=1.0,
-        flush_assembly_s=0.002, flush_h2d_s=0.001, flush_device_s=0.004,
-        flush_d2h_wait_s=0.003, flush_resolve_s=0.002,
-    )
-    vec, _total = stage_vector(tracer.store.peek(ctx.trace_id))
-    subs = sum(
-        vec[s][1] for s in ("flush_assembly", "dispatch", "d2h_wait",
-                            "resolve")
-    )
-    assert subs == pytest.approx(5.0, abs=1e-6)
+    tracer.record_span(ctx, "inference", b, b + 5, queue_wait_ms=1.0,
+                       flush_id=3)
+    tr = tracer.store.peek(ctx.trace_id)
+    # a 12 ms record around a 5 ms span: asked 2 ms BEFORE the span's
+    # start, landed 4 ms AFTER its end
+    rec = _flush_record(tr.spans[0].start_ms, (-2, 1, 2, 2.5, 3, 9),
+                        flush_id=3)
+    vec, _total = stage_vector(tr, {3: rec})
+    assert sum(vec[s][1] for s in _FLUSH_STAGES) == pytest.approx(5.0,
+                                                                  abs=1e-6)
     assert vec["lane_wait"] == [pytest.approx(1.0), pytest.approx(0.0)]
-    assert vec["dispatch"][1] == pytest.approx(4.0 * 5.0 / 12.0)
+    assert vec["permit_wait"][1] == pytest.approx(1.0)   # 0 → 1, clamped
+    assert vec["dispatch"][1] == pytest.approx(0.5)      # unscaled
+    assert vec["inflight"][1] == pytest.approx(2.0)      # 3 → 5, clamped
+    assert vec["resolve"][1] == pytest.approx(0.0)
+    # an unresolved flush (no landing stamp yet) claims nothing
+    del rec["t_landed"]
+    vec, _total = stage_vector(tr, {3: rec})
+    assert vec["lane_wait"] == [pytest.approx(1.0), pytest.approx(5.0)]
 
 
 # ----------------------------------------- (b) additive p99 decomposition
@@ -200,6 +237,31 @@ def test_ledger_decompose_is_additive_and_names_dominant_stage():
         thin.add({"decode": [0.0, 1.0]}, 1.0)
     assert thin.decompose() is None
     assert thin.dominant_stage() == ""
+
+
+def test_ledger_window_costs_the_collector_one_object_a_trace():
+    """A ledger keeps WINDOW vectors a (tenant, priority), every tenant
+    its own: kept as the dicts of lists ``stage_vector`` returns they
+    were some fifteen containers a trace in every full collection. The
+    window keeps one flat array a trace, and the decomposition reads the
+    same numbers back from it."""
+    import gc
+
+    vec = {s: [float(i), float(2 * i)] for i, s in enumerate(STAGES)}
+    led = StageLedger("t1", "measurement")
+    gc.collect()
+    before = len(gc.get_objects())
+    for _ in range(64):
+        led.add({s: list(cell) for s, cell in vec.items()}, 1000.0)
+    gc.collect()
+    # the stage quantile windows are made at the first add; after it an
+    # entry is one object, so 64 entries stay far below one dict and 13
+    # lists each
+    assert len(gc.get_objects()) - before < 64 * 2 + 8 * len(STAGES)
+    by = {s["stage"]: s for s in led.decompose()["stages"]}
+    for i, s in enumerate(STAGES):
+        assert by[s]["queue_wait_ms"] == pytest.approx(float(i))
+        assert by[s]["service_ms"] == pytest.approx(float(2 * i))
 
 
 # --------------------------------------------- (c) burn-rate accounting
@@ -628,6 +690,14 @@ async def test_rest_latency_reports_reconcile_with_measured_p99():
             assert on_path + fleet["residual_ms"] == pytest.approx(
                 fleet["cohort_mean_ms"], abs=0.05
             )
+            # the contiguous axis: the inference span is cut on the
+            # batch's own flush record, so the device queue shows as a
+            # stage of its own and next to nothing is left unnamed
+            by = {s["stage"]: s for s in fleet["stages"]}
+            assert by["inflight"]["total_ms"] > 0.0
+            assert by["resolve"]["total_ms"] > 0.0
+            assert "d2h_wait" not in by
+            assert fleet["residual_ms"] <= 0.10 * fleet["cohort_mean_ms"]
             # the headline acceptance: decomposition ↔ measured p99
             assert abs(fleet["cohort_mean_ms"] - fleet["e2e_p99_ms"]) <= (
                 0.15 * fleet["e2e_p99_ms"] + 0.05
