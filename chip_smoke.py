@@ -618,8 +618,7 @@ def _train_step_check(size: EventsSize, seed: int, devices) -> dict:
     for sc in (sharded, single):
         sc.init_optimizer(optax.adam(1e-3))
     hlo = sharded._train.lower(
-        sharded.params, sharded._opt_state, sharded.state.values,
-        sharded.state.pos, sharded.state.count,
+        sharded.params, sharded._opt_state, sharded.state,
         sharded.active & sharded.train_mask, sharded.slot_lr,
     ).compile().as_text()
     coll = count_collectives(hlo)
@@ -682,7 +681,8 @@ async def phase_chips4(size: EventsSize, seed: int, platform: str) -> dict:
             lbl = svc.mm.slice_device_label(sl)
             scorer = svc.scorers[("lstm_ad", sl)]
             home = {f"{d.platform}:{d.id}"
-                    for d in scorer.state.values.devices()}
+                    for x in jax.tree_util.tree_leaves(scorer.state)
+                    for d in x.devices()}
             check(home == {lbl} == {f"{platform}:{devices[sl].id}"},
                   f"slice {sl} state lives on {home}, label {lbl}")
             rows = m.counter(

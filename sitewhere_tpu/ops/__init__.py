@@ -9,6 +9,7 @@
 from sitewhere_tpu.ops.windows import (
     WindowState,
     init_window_state,
+    ring_values,
     update_windows,
     gather_windows,
     update_and_gather,
@@ -17,6 +18,7 @@ from sitewhere_tpu.ops.windows import (
 __all__ = [
     "WindowState",
     "init_window_state",
+    "ring_values",
     "update_windows",
     "gather_windows",
     "update_and_gather",

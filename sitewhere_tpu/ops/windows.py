@@ -9,9 +9,18 @@ only the new micro-batch crosses host→device each step.
 
 Design constraints (why it looks the way it does):
 
-- **Static shapes.** State is ``[S, W]`` for a fixed stream capacity ``S``
-  and window ``W``; micro-batches are padded to bucketed sizes. XLA compiles
-  each bucket once.
+- **Static shapes.** A fixed stream capacity ``S`` and window ``W``;
+  micro-batches are padded to bucketed sizes. XLA compiles each bucket once.
+- **Lane-dense ring store.** The rings are logically ``[S, W]`` but live as
+  ``[ceil(S*W/128), 128]``: ring slot ``k`` of stream ``s`` is flat
+  position ``f = s*W + k``, stored at ``(f // 128, f % 128)``. A 128-wide
+  minor dimension is the one TPU tiling (8 x 128) that neither pads the
+  array nor needs a relayout to scatter into: the compiled step touches the
+  rows of the batch and nothing else, where XLA copies a ``[S, W]`` store
+  with W < 128 whole, lane-padded, to and from the flat form its scatter
+  wants — four passes over the state a step. ``init_window_state``,
+  ``_apply_update``, ``gather_windows`` and ``ring_values`` are the only
+  places that know the physical shape.
 - **Duplicate streams per batch.** One micro-batch routinely carries several
   samples of the same series. A plain scatter would be order-ambiguous, so
   we compute each row's *rank among same-stream rows* (sort + segment rank,
@@ -24,43 +33,69 @@ Design constraints (why it looks the way it does):
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+import dataclasses
+from functools import partial
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+LANES = 128  # minor dimension of the ring store: one TPU vector row
 
-class WindowState(NamedTuple):
-    """Per-stream ring buffers. All leaves live on device.
 
-    values: f32[S, W]   ring storage (raw measurement values)
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=("values", "pos", "count"),
+    meta_fields=("window",),
+)
+@dataclasses.dataclass(frozen=True)
+class WindowState:
+    """Per-stream ring buffers. All array leaves live on device.
+
+    values: f32[R, 128] ring storage (raw measurement values), lane-dense:
+                        slot k of stream s at flat position s*W + k;
+                        ``ring_values`` gives the logical [S, W] view
     pos:    i32[S]      next write slot per stream
     count:  i32[S]      total samples ever written per stream (saturating add
                         not needed: int32 @ 1M ev/s/stream ≈ 35 min to wrap is
                         fine because only ``min(count, W)`` is ever used)
+    window: W           static (the store's shape does not carry it)
     """
 
     values: jnp.ndarray
     pos: jnp.ndarray
     count: jnp.ndarray
+    window: int
 
     @property
     def capacity(self) -> int:
-        return self.values.shape[0]
-
-    @property
-    def window(self) -> int:
-        return self.values.shape[1]
+        return self.pos.shape[-1]
 
 
 def init_window_state(
-    max_streams: int, window: int, dtype=jnp.float32
+    max_streams: int, window: int, dtype=jnp.float32, shards: int = 1
 ) -> WindowState:
+    """Empty rings. ``shards`` > 1 lays the store out for a stream axis
+    split that many ways (``shard_map`` over the data axis): every shard
+    owns whole 128-lane rows, padded where ``S/shards * W`` is no
+    multiple of 128, and sees its part as a ``shards=1`` state."""
+    shard_rows = -(-(max_streams // shards * window) // LANES)  # ceil
     return WindowState(
-        values=jnp.zeros((max_streams, window), dtype),
+        values=jnp.zeros((shards * shard_rows, LANES), dtype),
         pos=jnp.zeros((max_streams,), jnp.int32),
         count=jnp.zeros((max_streams,), jnp.int32),
+        window=window,
     )
+
+
+def ring_values(state: WindowState, shards: int = 1) -> jnp.ndarray:
+    """The logical rings ``[..., S, W]`` in ring order (slot ``pos`` is
+    the oldest once a ring is full) — for tests, smokes and debugging;
+    the hot path never materialises it."""
+    s, w = state.capacity, state.window
+    lead = state.values.shape[:-2]
+    per_shard = state.values.reshape(lead + (shards, -1))
+    return per_shard[..., : s // shards * w].reshape(lead + (s, w))
 
 
 def _segment_ranks(stream_ids: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -103,20 +138,19 @@ def _apply_update(
     ``update_windows``, split out so the K-step fused path can reuse one
     ``_segment_ranks`` sort for both the scatter and the per-row
     timestep resolution)."""
-    s, w = state.values.shape
+    s, w = state.capacity, state.window
     write_slot = (state.pos[stream_ids] + ranks) % w
     flat_idx = stream_ids * w + write_slot
-    # invalid rows → out-of-range index → dropped by scatter mode='drop'.
+    # invalid rows → out-of-range row → dropped by scatter mode='drop'.
     # Bursts of > W same-stream rows in one batch: only the newest W rows
     # write (older ones would be overwritten in sequential order anyway;
     # without this, duplicate scatter indices pick an unspecified winner).
     newest_w = ranks >= (totals - w)
-    flat_idx = jnp.where(valid & newest_w, flat_idx, s * w)
-    new_values = (
-        state.values.reshape(-1)
-        .at[flat_idx]
-        .set(values.astype(state.values.dtype), mode="drop")
-        .reshape(s, w)
+    row = jnp.where(
+        valid & newest_w, flat_idx // LANES, state.values.shape[0]
+    )
+    new_values = state.values.at[row, flat_idx % LANES].set(
+        values.astype(state.values.dtype), mode="drop"
     )
     ones = jnp.where(valid, 1, 0).astype(jnp.int32)
     safe_ids = jnp.where(valid, stream_ids, s)  # drop row for invalid
@@ -125,6 +159,7 @@ def _apply_update(
         values=new_values,
         pos=(state.pos + per_stream) % w,
         count=state.count + per_stream,
+        window=w,
     )
 
 
@@ -150,20 +185,24 @@ def gather_windows(
     Streams with fewer than W samples are left-padded with their oldest
     value (constant padding keeps models shift-robust without NaNs).
     """
-    s, w = state.values.shape
-    raw = state.values[stream_ids]            # [B, W] ring order
+    w = state.window
     pos = state.pos[stream_ids]               # [B]
-    # roll each row so oldest..newest; slot (pos) is the oldest entry
-    col = jnp.arange(w, dtype=jnp.int32)[None, :]
-    src = (pos[:, None] + col) % w
-    ordered = jnp.take_along_axis(raw, src, axis=1)
     n = jnp.minimum(state.count[stream_ids], w)  # [B]
-    # left-pad short windows with their first valid sample
-    first_valid_col = w - n
-    first_val = jnp.take_along_axis(
-        ordered, jnp.minimum(first_valid_col, w - 1)[:, None], axis=1
-    )
-    windows = jnp.where(col < first_valid_col[:, None], first_val, ordered)
+    # window column c reads ring slot (pos + c) % W — slot ``pos`` is the
+    # oldest entry — and a column left of the first valid one (W - n)
+    # reads that one instead: the roll and the left-pad are one index
+    col = jnp.arange(w, dtype=jnp.int32)[None, :]
+    first_valid_col = jnp.minimum(w - n, w - 1)[:, None]
+    slot = (pos[:, None] + jnp.maximum(col, first_valid_col)) % w  # [B, W]
+    base = stream_ids * w                     # [B] flat position of slot 0
+    if LANES % w == 0:
+        # a ring never straddles a row: fetch its row, pick its lanes
+        rows = state.values[base // LANES]    # [B, 128]
+        lane = (base % LANES)[:, None] + slot
+        windows = jnp.take_along_axis(rows, lane, axis=1)
+    else:
+        flat = base[:, None] + slot
+        windows = state.values[flat // LANES, flat % LANES]
     return windows, n
 
 

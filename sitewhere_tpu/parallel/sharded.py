@@ -6,8 +6,9 @@ The 32-tenant concurrent-scoring config (BASELINE.json:10) runs here. Layout
 - params:  every leaf gains a leading stacked-tenant dim ``[T, ...]``,
   sharded along the mesh ``tenant`` axis (T = n_tenant_shards ×
   slots_per_shard).
-- window state: ``[T, S, W]`` — T over ``tenant``, stream capacity S over
-  ``data`` (each data shard owns a disjoint set of streams, so window
+- window state: logically ``[T, S, W]`` (``ops.windows`` owns the physical,
+  lane-dense shape) — T over ``tenant``, stream capacity S over ``data``
+  (each data shard owns a disjoint set of streams, so window
   updates never race across shards and the hot path needs **zero
   collectives**: pure SPMD fan-out, ICI stays free for training traffic).
 - batches: ``[T, B]`` with B over ``data``; the micro-batcher routes each
@@ -43,6 +44,7 @@ from sitewhere_tpu.ops.windows import (
     WindowState,
     gather_windows,
     init_window_state,
+    ring_values,
     update_and_gather,
     update_gather_ranked,
     update_windows,
@@ -98,14 +100,21 @@ def set_slot(stacked: Params, idx: int, params: Params) -> Params:
 
 
 def init_stacked_state(
-    n_slots: int, max_streams: int, window: int
+    n_slots: int, max_streams: int, window: int, data_shards: int = 1
 ) -> WindowState:
-    """Stacked window state [T, S, W]; S is the *global* stream capacity
-    (split across data shards inside shard_map)."""
-    st = init_window_state(max_streams, window)
+    """Stacked window state, slot-major: every leaf of
+    ``init_window_state`` with a leading [T]. S is the *global* stream
+    capacity; the stream axis (leaf axis 1) is split ``data_shards``
+    ways inside shard_map, each shard owning whole rows of the store."""
+    st = init_window_state(max_streams, window, shards=data_shards)
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (n_slots,) + x.shape).copy(), st
     )
+
+
+def _zero_slot(state: WindowState, idx: int) -> WindowState:
+    """One slot's rings, cursors and counts back to empty."""
+    return jax.tree_util.tree_map(lambda x: x.at[idx].set(0), state)
 
 
 class ShardedScorer:
@@ -260,13 +269,7 @@ class ShardedScorer:
             )
         else:
             self.step_param_specs = self.param_specs
-        state = init_stacked_state(self.n_slots, max_streams, window)
-        st_sharding = mm.sharding(AXIS_TENANT, AXIS_DATA)
-        self.state = WindowState(
-            values=jax.device_put(state.values, st_sharding),
-            pos=jax.device_put(state.pos, st_sharding),
-            count=jax.device_put(state.count, st_sharding),
-        )
+        self.state = self._fresh_state()
         self.active = jax.device_put(
             jnp.zeros((self.n_slots,), bool), t_shard
         )
@@ -292,6 +295,21 @@ class ShardedScorer:
         # lazy per-slot (unstacked) shard fns for weight paging's
         # stage_slot_params — most scorers never page and must not pay
         self._slot_shard_fns = None
+
+    def _fresh_state(self) -> WindowState:
+        """Empty stacked rings on the mesh: slots over the tenant axis,
+        streams over the data axis."""
+        return jax.device_put(
+            init_stacked_state(
+                self.n_slots, self.max_streams, self.window,
+                self.mm.n_data_shards,
+            ),
+            self.mm.sharding(AXIS_TENANT, AXIS_DATA),
+        )
+
+    def ring_values(self) -> jnp.ndarray:
+        """The serve state's logical rings f32[T, S, W] (ring order)."""
+        return ring_values(self.state, self.mm.n_data_shards)
 
     # -- fused kernel param view -----------------------------------------
     def _invalidate_kernel(self) -> None:
@@ -526,7 +544,7 @@ class ShardedScorer:
         # XLA calls the module (today ``jit_local_step``)
         @jax.named_scope("sw/step")
         def local_step(params, state, active, ids, vals, validity):
-            # local shapes: params [T_loc, ...], state [T_loc, S_loc, W],
+            # local shapes: params [T_loc, ...], state T_loc x S_loc rings,
             # ids/vals [T_loc, B_loc]; validity is bool[T_loc, B_loc]
             # (mask mode) or i32[T_loc, 1] lane counts (counts mode)
             if counts_mode:
@@ -797,11 +815,7 @@ class ShardedScorer:
         self.slot_lr = self.slot_lr.at[global_slot].set(1.0)
         self.params = set_slot(self.params, global_slot, self._base_params)
         self._invalidate_kernel()
-        self.state = WindowState(
-            values=self.state.values.at[global_slot].set(0.0),
-            pos=self.state.pos.at[global_slot].set(0),
-            count=self.state.count.at[global_slot].set(0),
-        )
+        self.state = _zero_slot(self.state, global_slot)
         if getattr(self, "_opt_state", None) is not None:
             self._opt_state = jax.tree_util.tree_map(
                 lambda s, f: s.at[global_slot].set(f.astype(s.dtype)),
@@ -811,10 +825,8 @@ class ShardedScorer:
         if self._train_feed_state is not None:
             # a recycled slot must not leak the previous tenant's
             # replayed training windows either
-            self._train_feed_state = WindowState(
-                values=self._train_feed_state.values.at[global_slot].set(0.0),
-                pos=self._train_feed_state.pos.at[global_slot].set(0),
-                count=self._train_feed_state.count.at[global_slot].set(0),
+            self._train_feed_state = _zero_slot(
+                self._train_feed_state, global_slot
             )
 
     def slot_params(self, global_slot: int) -> Params:
@@ -925,13 +937,7 @@ class ShardedScorer:
                 jnp.ones((self.n_slots,), jnp.float32), t_shard
             ),
         )
-        state = init_stacked_state(self.n_slots, self.max_streams, self.window)
-        st_sharding = self.mm.sharding(AXIS_TENANT, AXIS_DATA)
-        self.state = WindowState(
-            values=jax.device_put(state.values, st_sharding),
-            pos=jax.device_put(state.pos, st_sharding),
-            count=jax.device_put(state.count, st_sharding),
-        )
+        self.state = self._fresh_state()
         self._step = self._build_step()
         self._step_counts = self._build_step(counts_mode=True)
         self._kernel_params = None   # may reference dead buffers
@@ -1023,11 +1029,11 @@ class ShardedScorer:
         mesh = self.mm.mesh
         spec, cfg, window = self.spec, self.cfg, self.window
 
-        def local_step(params, opt_state, values, pos, count, active, lr):
-            # params/opt [T_loc, ...], values [T_loc, S_loc, W], active [T_loc]
-            def one(p, o, vals, ps, cnt, act, lr1):
-                st = WindowState(values=vals, pos=ps, count=cnt)
-                ids = jnp.arange(vals.shape[0], dtype=jnp.int32)
+        def local_step(params, opt_state, state, active, lr):
+            # params/opt [T_loc, ...], state: the local stacked window
+            # state, active [T_loc]
+            def one(p, o, st, act, lr1):
+                ids = jnp.arange(st.capacity, dtype=jnp.int32)
                 windows, n = gather_windows(st, ids)
                 # only streams with a full-enough history contribute; a
                 # masked per-row mean keeps cold/garbage windows out of the
@@ -1064,9 +1070,7 @@ class ShardedScorer:
                 )
                 return p2, o2, l
             act_f = active.astype(jnp.float32)
-            return jax.vmap(one)(
-                params, opt_state, values, pos, count, act_f, lr
-            )
+            return jax.vmap(one)(params, opt_state, state, act_f, lr)
 
         smapped = shard_map(
             local_step,
@@ -1074,9 +1078,7 @@ class ShardedScorer:
             in_specs=(
                 self.param_specs,            # params (per-leaf rules)
                 self._opt_specs,             # opt state (same rules)
-                P(AXIS_TENANT, AXIS_DATA),   # window values [T, S, W]
-                P(AXIS_TENANT, AXIS_DATA),   # pos
-                P(AXIS_TENANT, AXIS_DATA),   # count
+                P(AXIS_TENANT, AXIS_DATA),   # window state (S over data)
                 P(AXIS_TENANT),              # active mask
                 P(AXIS_TENANT),              # per-slot lr
             ),
@@ -1099,8 +1101,7 @@ class ShardedScorer:
             mask = mask & slots_mask
         self.params, self._opt_state, losses = self._train(
             self.params, self._opt_state,
-            self.state.values, self.state.pos, self.state.count,
-            mask, self.slot_lr,
+            self.state, mask, self.slot_lr,
         )
         # live weights changed: the next flush's fused step must score
         # against a re-quantized sidecar (hot-swap between flushes)
@@ -1129,16 +1130,15 @@ class ShardedScorer:
         mesh = self.mm.mesh
         spec, cfg, window = self.spec, self.cfg, self.window
 
-        def local_step(params, opt_state, values, pos, count, active, lr):
-            # params/opt [T_loc, ...], values [T_loc, S_loc, W]
-            def gather_one(vals, ps, cnt):
-                st = WindowState(values=vals, pos=ps, count=cnt)
-                ids = jnp.arange(vals.shape[0], dtype=jnp.int32)
+        def local_step(params, opt_state, state, active, lr):
+            # params/opt [T_loc, ...], state: the local stacked window state
+            def gather_one(st):
+                ids = jnp.arange(st.capacity, dtype=jnp.int32)
                 return gather_windows(st, ids)
 
             # window materialization is memory ops (gather/roll) — it
             # stays vmapped per slot like the scoring step's scatter
-            windows, n = jax.vmap(gather_one)(values, pos, count)
+            windows, n = jax.vmap(gather_one)(state)
             act_f = active.astype(jnp.float32)
             # same per-row gate as the legacy step: only streams with a
             # full-enough history contribute, masked mean stays
@@ -1193,9 +1193,7 @@ class ShardedScorer:
             in_specs=(
                 self.param_specs,            # params (per-leaf rules)
                 self._opt_specs,             # opt state (same rules)
-                P(AXIS_TENANT, AXIS_DATA),   # window values [T, S, W]
-                P(AXIS_TENANT, AXIS_DATA),   # pos
-                P(AXIS_TENANT, AXIS_DATA),   # count
+                P(AXIS_TENANT, AXIS_DATA),   # window state (S over data)
                 P(AXIS_TENANT),              # active mask
                 P(AXIS_TENANT),              # per-slot lr
             ),
@@ -1205,21 +1203,13 @@ class ShardedScorer:
 
     def init_train_feed(self) -> None:
         """Allocate the replay-fed TRAIN window state — the same
-        [T, S, W] stacked rings as serving, fed by the train lane's
+        stacked rings as serving, fed by the train lane's
         replayed microbatches instead of live traffic, so continual
         learning sees windows BEYOND the resident serve state. Lazy:
         only a slice with a replay-fed trainable tenant pays the HBM."""
         if self._train_feed_state is not None:
             return
-        state = init_stacked_state(
-            self.n_slots, self.max_streams, self.window
-        )
-        st_sharding = self.mm.sharding(AXIS_TENANT, AXIS_DATA)
-        self._train_feed_state = WindowState(
-            values=jax.device_put(state.values, st_sharding),
-            pos=jax.device_put(state.pos, st_sharding),
-            count=jax.device_put(state.count, st_sharding),
-        )
+        self._train_feed_state = self._fresh_state()
         self._ingest = self._build_ingest_step()
 
     def _build_ingest_step(self) -> Callable:
@@ -1290,8 +1280,7 @@ class ShardedScorer:
         st = self._train_feed_state if replay else self.state
         self.params, self._opt_state, losses = self._train_fused(
             self.params, self._opt_state,
-            st.values, st.pos, st.count,
-            mask, self.slot_lr,
+            st, mask, self.slot_lr,
         )
         return losses
 

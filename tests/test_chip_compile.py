@@ -15,6 +15,7 @@ not the device.
 """
 
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -27,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from sitewhere_tpu.models import get_model, make_config
 from sitewhere_tpu.parallel.mesh import AXIS_DATA, AXIS_TENANT, MeshManager
-from sitewhere_tpu.parallel.sharded import ShardedScorer
+from sitewhere_tpu.parallel.sharded import ShardedScorer, init_stacked_state
 
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
@@ -93,12 +94,19 @@ def described_scorer(devices, tenant: int, data: int, n_slots: int = 32):
     return scorer
 
 
-def lower_step_counts(scorer, b_lane: int):
+def lower_step_counts(scorer, b_lane: int, max_streams: int = 0):
+    """``step_counts`` at lane size ``b_lane``; ``max_streams`` lowers it
+    against a window state of that capacity (shapes only) in place of
+    the scorer's own small one."""
     mesh, d = scorer.mm.mesh, scorer.mm.n_data_shards
     t = scorer.n_slots
+    state = scorer.state
+    if max_streams:
+        state = jax.eval_shape(lambda: init_stacked_state(
+            t, max_streams, scorer.window, d))
     return scorer._build_step(counts_mode=True).lower(
         _sds(scorer.kernel_params(), mesh, scorer.step_param_specs),
-        _sds(scorer.state, mesh, TD),
+        _sds(state, mesh, TD),
         _sds(scorer.active, mesh, P(AXIS_TENANT)),
         jax.ShapeDtypeStruct((t, d * b_lane), scorer.ids_np_dtype,
                              sharding=NamedSharding(mesh, TD)),
@@ -125,12 +133,10 @@ def lower_train(scorer, fused: bool):
     mesh = scorer.mm.mesh
     build = (scorer._build_train_step_fused if fused
              else scorer._build_train_step)
-    st = scorer.state
     return build(scorer._optimizer, scorer._lr_sign).lower(
         _sds(scorer.params, mesh, scorer.param_specs),
         _sds(scorer._opt_state, mesh, scorer._opt_specs),
-        _sds(st.values, mesh, TD), _sds(st.pos, mesh, TD),
-        _sds(st.count, mesh, TD),
+        _sds(scorer.state, mesh, TD),
         _sds(scorer.active, mesh, P(AXIS_TENANT)),
         _sds(scorer.slot_lr, mesh, P(AXIS_TENANT)),
     )
@@ -174,6 +180,55 @@ def test_step_counts_compiles_for_v5e(one_chip_scorer, topo):
     rep = compile_report(lower_step_counts(one_chip_scorer, 2048))
     assert rep["collectives"] == {}, rep
     assert rep["temp_mb"] < 8_000, rep  # fits a 16 GB chip with room
+
+
+def whole_state_ops(hlo: str, n_elems: int) -> list:
+    """(name, opcode, called computation's text) of every instruction of
+    the entry computation that produces an array of ``n_elems`` elements
+    or more by doing work — parameters, bitcasts and tuple plumbing move
+    nothing and are left out."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M).group(1)
+    found = []
+    for line in entry.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?(\S+) = (\(.*?\)|\S+) ([\w-]+)\(", line)
+        if not m or m.group(3) in (
+                "parameter", "bitcast", "tuple", "get-tuple-element"):
+            continue
+        sizes = [
+            int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2))
+        ]
+        if max(sizes, default=0) >= n_elems:
+            callee = re.search(r"calls=(%[\w.-]+)", line)
+            body = ""
+            if callee:
+                body = re.search(
+                    r"^" + re.escape(callee.group(1)) + r" [^\n]*\{\n(.*?)^\}",
+                    hlo, re.S | re.M).group(1)
+            found.append((m.group(1), m.group(3), body))
+    return found
+
+
+@pytest.mark.parametrize("max_streams", [524_288, 1_048_576])
+def test_step_touches_the_window_state_only_where_it_scatters(
+        one_chip_scorer, topo, max_streams):
+    """At the benchmark cell's size (32 slots, 524,288 streams, W 32,
+    bucket 1,024) and at the published fleet's power of two, which the
+    [T, S, W] store could not compile ("Used 16.00G of 15.75G"): the
+    compiled step holds no temporary of the state's order and one
+    operation that yields the whole state — the in-place scatter."""
+    t, w = one_chip_scorer.n_slots, one_chip_scorer.window
+    compiled = lower_step_counts(
+        one_chip_scorer, 1024, max_streams).compile()
+    state_bytes = t * max_streams * w * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < state_bytes // 10, mem
+    assert mem.alias_size_in_bytes >= state_bytes, mem
+    ops = whole_state_ops(compiled.as_text(), t * max_streams * w)
+    assert len(ops) == 1, [(name, op) for name, op, _ in ops]
+    name, op, body = ops[0]
+    assert op == "scatter" or " scatter(" in body, (name, op)
 
 
 def test_gather_compiles_for_v5e(one_chip_scorer, topo):

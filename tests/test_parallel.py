@@ -170,8 +170,43 @@ class TestShardedScorer:
         """Params sharded over tenant axis; state over (tenant, data)."""
         leaf = jax.tree_util.tree_leaves(scorer.params)[0]
         assert len(leaf.sharding.device_set) >= 4
-        st = scorer.state.values
-        assert len(st.sharding.device_set) == 8
+        for leaf in jax.tree_util.tree_leaves(scorer.state):
+            assert len(leaf.sharding.device_set) == 8
+        t, s, w = scorer.n_slots, scorer.max_streams, scorer.window
+        assert scorer.ring_values().shape == (t, s, w)
+
+
+def test_data_shards_own_padded_rows_and_match_one_shard():
+    """3 streams x W 8 a data shard is 24 floats of a 128-lane row: each
+    shard's part of the ring store is padded to a whole row, and the rings
+    and scores equal those of the same streams on one shard."""
+    spec = get_model("lstm_ad")
+    cfg = make_config("lstm_ad", {"window": 8, "hidden": 8})
+
+    def build(data):
+        sc = ShardedScorer(
+            MeshManager(tenant=1, data=data, devices=jax.devices()[:data]),
+            spec, cfg, slots_per_shard=2, max_streams=6, window=8,
+        )
+        sc.activate(1)
+        return sc
+
+    two, one = build(2), build(1)
+    assert two.state.values.shape == (2, 2, 128)
+    assert one.state.values.shape == (2, 1, 128)
+    rng = np.random.default_rng(3)
+    b = 5
+    for _ in range(4):
+        local = rng.integers(0, 3, (2, 2 * b)).astype(np.int32)
+        flat = local + np.repeat([0, 3], b)[None, :].astype(np.int32)
+        vals = rng.normal(size=(2, 2 * b)).astype(np.float32)
+        valid = rng.random((2, 2 * b)) > 0.2
+        s2 = np.asarray(two.step(local, vals, valid))
+        s1 = np.asarray(one.step(flat, vals, valid))
+        np.testing.assert_allclose(s2, s1, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(two.ring_values()), np.asarray(one.ring_values()))
+    assert np.asarray(two.ring_values())[1].any()
 
 
 class TestStepCountsWire:

@@ -159,8 +159,7 @@ def test_kill_switch_restores_legacy_train_program_bitwise():
     # kill switch restores) on its identical params/opt/state
     p2, o2, l_ref = on._train(
         on.params, on._opt_state,
-        on.state.values, on.state.pos, on.state.count,
-        on.active & on.train_mask & mask, on.slot_lr,
+        on.state, on.active & on.train_mask & mask, on.slot_lr,
     )
     assert (l_off == np.asarray(l_ref)).all()
     for x, y in zip(_leaves(off.params), _leaves(p2)):
