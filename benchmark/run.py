@@ -422,7 +422,7 @@ async def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     del system
     gc.collect()
     verdict = check.judge(facts, run, builder.reference(config, seed), seed)
-    program_checks = verdict["checks"]
+    program_correct, program_checks = verdict["correct"], verdict["checks"]
     if control:
         verdict = check.judge(
             facts, run, builder.reference(config, seed), seed,
@@ -458,10 +458,15 @@ async def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         half = len(lat) // 2
         info["p50_first_half_ms"] = float(np.median(lat[:half]))
         info["p50_second_half_ms"] = float(np.median(lat[half:]))
-    ends = [w for t, w in run.lag_samples if t <= run.t0 + seconds][-3:]
-    info["lag_at_close"] = {
-        k: max(w.get(k, 0) for w in ends) for w in ends for k in w
-        if max(x.get(k, 0) for x in ends) > 0}
+    # every consumer group's deepest lag (batches) over the window's first
+    # and last three samples (300 ms): what benchmark.sweep's rule reads
+    inside = [w for t, w in run.lag_samples if run.t0 <= t <= run.t0 + seconds]
+    for key, part in (("lag_at_open", inside[:3]), ("lag_at_close", inside[-3:])):
+        deepest = info[key] = {}
+        for sample in part:
+            for group, lag in sample.items():
+                if lag > deepest.get(group, 0):
+                    deepest[group] = lag
     info["gc"] = {"collections": run.gc_count,
                   "pause_ms": [round(1000 * x, 1) for x in run.gc_pause_s],
                   "pause_max_ms": [round(1000 * x, 1)
@@ -469,6 +474,7 @@ async def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     info["loop_cpu_s"] = round(run.loop_cpu_s, 3)
     result["info"] = info
     if control:
+        result["program_correct"] = program_correct
         result["program_checks"] = program_checks
     result["checks"] = verdict["checks"]
     for line in verdict["notes"]:
