@@ -11,7 +11,10 @@ docs/PERFORMANCE.md has the full stage walkthrough):
   inbound-events[tenant_i] ─┐  MeasurementBatch (struct-of-arrays)
   inbound-events[tenant_j] ─┼→ lane RINGS[(slot, data_shard)]: rows are
           ...              ─┘  written into preallocated numpy segments
-                                AT ENQUEUE │ flush on deadline_ms OR full
+                                AT ENQUEUE │ due on deadline_ms OR full;
+                                           │ a due flush under the smallest
+                                           │ bucket waits for the one in
+                                           │ flight (``_flush_held``)
                                      ▼
               reusable staging buffers u16/bf16[T, D·B] (slice copies,
               two rotating sets per (family, bucket) — no fresh arrays)
@@ -41,15 +44,25 @@ Three latency-hiding moves matter here (SURVEY.md §7 hard parts):
   staging, never ``np.asarray`` over freshly built lists
   (tools/check_hotpath.py lints this invariant);
 - the staged device put is issued BEFORE dispatch and is asynchronous,
-  so flush N+1's host→device transfer rides under flush N's compute
-  (``tpu_inference.h2d_overlapped`` / ``h2d_staged`` expose the ratio);
+  so where flushes pipeline, flush N+1's host→device transfer rides
+  under flush N's compute (``tpu_inference.h2d_overlapped`` /
+  ``h2d_staged`` expose the ratio);
 - the readback is the mirror image: a device-side gather returns only
   the flushed rows (``ShardedScorer.gather_rows``), its d2h copy is
   started asynchronously at dispatch, and a completion reaper resolves
-  up to ``max_inflight`` in-flight flushes as their transfers land
+  the in-flight flushes as their transfers land
   (``tpu_inference.d2h_overlapped`` counts transfers that landed before
   the reaper asked). One device round-trip never stalls the collect
   loop; p99 still lands in the ``tpu_inference.latency`` histogram.
+
+When flushes pipeline and when they wait (``_flush_held``, beside
+``_deadline_reached``): a due flush joins the device queue behind a
+serve flush that has not landed — up to ``max_inflight`` deep — only if
+some lane already holds the smallest bucket; below it a bigger flush is
+the same program in the same device time, so the rows wait on their
+lanes and leave together when the flush in flight lands. Small-flush
+traffic therefore runs one flush deep, full flushes ``max_inflight``
+deep (``tpu_inference.flush_held`` / ``flush_pipelined``).
 
 Tenant start/stop flips the scorer's active mask — no recompile; batch-size
 buckets keep XLA at a handful of compiled shapes.
@@ -499,7 +512,10 @@ class _ReapQueue(list):
     """Per-(family, mesh-slice) FIFO of in-flight flush completions —
     the PER-DEVICE drain queues of the multi-chip result path. Depth is
     bounded by the ``max_inflight`` semaphore (acquired before rows are
-    popped from lanes) and observable via the
+    popped from lanes) and reaches it only where flushes pipeline — a
+    lane at the smallest bucket or over; smaller flushes wait for the
+    one in flight (``_flush_held``) and the queue stands one deep. It is
+    observable via the
     ``tpu_inference_deliver_inflight`` gauge (+ per-family and
     per-device labeled variants) and the
     ``tpu_inference.deliver_backpressure`` counter
@@ -2015,10 +2031,18 @@ class TpuInferenceService(MultitenantService):
             self.metrics.counter("tpu_inference.flush_rows").inc(moved)
             # flushes already in flight on this slice as this one joins
             # the device queue (÷ .flushes = the mean depth it waits
-            # behind)
+            # behind). In flight as the ``inflight`` interval has it:
+            # dispatched and not landed — a landed head whose resolve is
+            # still publishing holds a queue slot, not the device
+            ahead = self._in_flight((family, sl))
             self.metrics.counter("tpu_inference.inflight_depth_sum").inc(
-                len(self._reap.get((family, sl), ()))
+                len(ahead)
             )
+            if any(p.lane == "serve" for p in ahead):
+                # joined a device still busy with a serve flush: the
+                # policy let it through because a lane had reached the
+                # smallest bucket (``_flush_held``)
+                self.metrics.counter("tpu_inference.flush_pipelined").inc()
             # lane wait, per carried batch: its enqueue → the flush asked
             # for its permit
             seq_list = np.unique(seqs_cat).tolist()
@@ -4587,9 +4611,11 @@ class TpuInferenceService(MultitenantService):
     async def _scoring_loop(self) -> None:
         iters = self.metrics.counter("tpu_inference.loop_iters")
         throttled = self.metrics.counter("tpu_inference.fair_throttled")
+        held = self.metrics.counter("tpu_inference.flush_held")
         while True:
             iters.inc()
             moved = 0
+            holding = False
             fam_cfgs: Dict[str, Dict[int, TenantEngineConfig]] = {}
             # weighted fair queuing: every pass replenishes each tenant's
             # deficit (quantum × weight); a tenant that overdrew sits out
@@ -4709,6 +4735,10 @@ class TpuInferenceService(MultitenantService):
                 lanes = self._lanes[(family, sl)]
                 full = any(l.count >= mb.max_batch for l in lanes.values())
                 if full or self._deadline_reached((family, sl), mb.deadline_ms):
+                    if self._flush_held((family, sl), lanes, mb):
+                        held.inc()
+                        holding = True
+                        continue
                     moved += await self._flush_slice(cfgs, family, sl)
             if fam_cfgs:
                 # the async train lane runs AFTER serve flushes, off the
@@ -4716,7 +4746,12 @@ class TpuInferenceService(MultitenantService):
                 # dispatch per (family, slice) per pass, and only into a
                 # free in-flight permit (a saturated slice trains 0)
                 moved += await self._train_lane_tick(fam_cfgs)
-            if moved == 0:
+            if moved == 0 or holding:
+                # a pass that held a due flush yields like an idle one,
+                # even if intake moved rows: nothing can leave before the
+                # flush in flight lands, and under a firehose this is the
+                # turn the full semaphore used to give the other stages
+                # (``bus.consume(timeout_s=0)`` never suspends)
                 await asyncio.sleep(0.001)
 
     async def _passthrough(self, topic: str, items: list) -> None:
@@ -4751,6 +4786,54 @@ class TpuInferenceService(MultitenantService):
     def _deadline_reached(self, key: Tuple[str, int], deadline_ms: float) -> bool:
         first = self._first_pending_ts.get(key)
         return first is not None and (time.monotonic() - first) * 1000.0 >= deadline_ms
+
+    def _in_flight(self, key: Tuple[str, int]) -> List[_PendingFlush]:
+        """This (family, slice)'s entries that are dispatched and have
+        not landed: what its device is still working on. A landed head
+        that only waits for its resolve to publish is not among them —
+        the device is free from the landing on — nor is a poisoned
+        (host-only) entry, which lands by construction."""
+        return [
+            p for p in self._reap.get(key, ())
+            if not p.resolved and not p.landed()
+        ]
+
+    def _flush_held(self, key: Tuple[str, int], lanes: dict, mb) -> bool:
+        """THE flush policy's second half (the first is ``full`` or
+        ``_deadline_reached``: the flush is DUE). A due flush waits for
+        the one in flight unless some lane already holds the smallest
+        compiled bucket:
+
+          hold ⇔ due ∧ a serve flush of this slice is in flight
+                     ∧ every lane's count < buckets[0]
+
+        (a train-lane step in flight holds nothing: serving has the
+        right of way, and the lane only ever enters an empty window).
+
+        Below the smallest bucket a bigger flush runs the SAME program in
+        the same device time, so holding costs no throughput and saves a
+        whole step of device queue: the rows stay on the lanes (counted
+        by the lane watermark as ever) and ride out together when the
+        in-flight flush lands. From the smallest bucket up, coalescing
+        further would move to a larger program, and pipelining up to
+        ``max_inflight`` deep — which hides h2d and assembly under
+        compute for full flushes — applies unchanged.
+
+        Evaluated afresh every pass from the reap queue, per (family,
+        slice): whatever takes the in-flight flush out of the queue
+        (landing, the supervisor's force-resolve, teardown) lifts the
+        hold, and where ``_flush_slice`` would not dispatch at all (the
+        family parked, the slice quarantined, the breaker open: rows
+        pass through unscored) there is nothing to wait for."""
+        if not any(p.lane == "serve" for p in self._in_flight(key)):
+            return False
+        if key[0] in self._parked or key in self._quarantined:
+            return False
+        breaker = self.breakers.get(key)
+        if breaker is not None and breaker.state == "open":
+            return False
+        smallest = min(mb.buckets[0], mb.max_batch)
+        return all(l.count < smallest for l in lanes.values())
 
     def prewarm(self) -> None:
         """Compile every active family's bucket shapes (see

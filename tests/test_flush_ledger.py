@@ -185,7 +185,10 @@ async def _two_flushes_in_flight():
 
     scorer.step_counts = gated_step
     try:
-        b1, b2 = _batch(inst, toks, 8, 100.0), _batch(inst, toks, 8, 200.0)
+        # the second batch fills the smallest bucket, so it does not wait
+        # for the first flush to land (the flush policy's pipelining exit)
+        b1 = _batch(inst, toks, 8, 100.0)
+        b2 = _batch(inst, toks, MB.buckets[0], 200.0)
         t0 = time.perf_counter()
         await _publish(inst, b1)
         assert await _wait_for(
@@ -198,7 +201,7 @@ async def _two_flushes_in_flight():
         for g in gates:
             g.set()
         scored = svc.metrics.counter("tpu_inference.scored_total")
-        assert await _wait_for(lambda: scored.value >= 16)
+        assert await _wait_for(lambda: scored.value >= 8 + MB.buckets[0])
         wall = time.perf_counter() - t0
         return inst, (b1, b2), wall
     except BaseException:
@@ -248,6 +251,7 @@ async def test_service_never_overlaps_while_inflight_does():
         m = inst.inference.metrics
         # one flush in flight ahead of the second, none ahead of the first
         assert m.counter("tpu_inference.inflight_depth_sum").value == 1
+        assert m.counter("tpu_inference.flush_pipelined").value == 1
         # device seconds and MFU are fed the service time
         secs = m.counter("tpu_device_seconds_total", family="lstm_ad").value
         assert secs == pytest.approx(service, abs=1e-6)

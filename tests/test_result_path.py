@@ -273,9 +273,11 @@ async def test_in_order_per_tenant_within_family():
             _batch("acme", toks, 8, base=100.0),
         )
         assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1)
+        # a lane at the smallest bucket (32) does not wait for the flush
+        # in flight: the second flush joins it through the policy's exit
         await inst.bus.publish(
             inst.bus.naming.inbound_events("acme"),
-            _batch("acme", toks, 8, base=200.0),
+            _batch("acme", toks, MB.buckets[0], base=200.0),
         )
         assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 2)
         assert len(gates) == 2
@@ -325,9 +327,10 @@ async def test_failed_dispatch_stays_fifo_per_tenant():
             _batch("acme", toks, 8, base=100.0),
         )
         assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1)
+        # (a full smallest bucket, so it dispatches beside the gated one)
         await inst.bus.publish(
             inst.bus.naming.inbound_events("acme"),
-            _batch("acme", toks, 8, base=200.0),
+            _batch("acme", toks, MB.buckets[0], base=200.0),
         )
         # the failed flush queues as a poisoned entry BEHIND the gated one
         assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 2)

@@ -243,6 +243,12 @@ QUEUE_REGISTRY: Dict[Tuple[str, str], Dict[str, str]] = {
         # completions never shed: a full in-flight window backpressures
         # the NEXT flush at the semaphore (counted before the acquire)
         "backpressure_counter": "tpu_inference.deliver_backpressure",
+        # the flush policy in front of the semaphore (_flush_held): a
+        # due flush waits for the one in flight while every lane is
+        # under the smallest bucket (passes held), and joins an occupied
+        # device only from the smallest bucket up (flushes pipelined)
+        "held_counter": "tpu_inference.flush_held",
+        "pipelined_counter": "tpu_inference.flush_pipelined",
     },
     ("runtime/netbus.py", r"= _ReplRing\("): {
         "queue": "broker replication ring (primary-side mutation tail — "
