@@ -402,8 +402,7 @@ def sketch_edges(
 # excluded) the device executes to score ONE row with a length-``window``
 # series window. The scoring hot path multiplies by the flushed PLANE
 # (every padded lane row executes, valid or not) to feed the live
-# ``tpu_flops_total{family}`` / ``tpu_mfu_pct{family}`` accounting, and
-# ``bench.py`` reads its engine MFU from the same functions.
+# ``tpu_flops_total{family}`` / ``tpu_mfu_pct{family}`` accounting.
 #
 # Why analytic instead of XLA's cost analysis: XLA's ``cost_analysis()``
 # counts a ``lax.scan`` BODY once, not per trip — for the window-scan

@@ -14,7 +14,7 @@ docs/PERFORMANCE.md has the full stage walkthrough):
                                 AT ENQUEUE │ due on deadline_ms OR full;
                                            │ a due flush under the smallest
                                            │ bucket waits for the one in
-                                           │ flight (``_flush_held``)
+                                           │ flight (``SliceRuntime.held``)
                                      ▼
               reusable staging buffers u16/bf16[T, D·B] (slice copies,
               two rotating sets per (family, bucket) — no fresh arrays)
@@ -55,10 +55,10 @@ Three latency-hiding moves matter here (SURVEY.md §7 hard parts):
   the reaper asked). One device round-trip never stalls the collect
   loop; p99 still lands in the ``tpu_inference.latency`` histogram.
 
-When flushes pipeline and when they wait (``_flush_held``, beside
-``_deadline_reached``): a due flush joins the device queue behind a
-serve flush that has not landed — up to ``max_inflight`` deep — only if
-some lane already holds the smallest bucket; below it a bigger flush is
+When flushes pipeline and when they wait (``SliceRuntime.due`` and
+``.held``, ``pipeline/slices.py``): a due flush joins the device queue
+behind a serve flush that has not landed — up to ``max_inflight`` deep —
+only if some lane already holds the smallest bucket; below it a bigger flush is
 the same program in the same device time, so the rows wait on their
 lanes and leave together when the flush in flight lands. Small-flush
 traffic therefore runs one flush deep, full flushes ``max_inflight``
@@ -70,7 +70,8 @@ buckets keep XLA at a handful of compiled shapes.
 Multi-chip serving (docs/PERFORMANCE.md "Multi-chip serving"): the whole
 pipeline above is instantiated PER (family, mesh-slice) — the router
 places each tenant on a tenant-axis slice, and that slice's scorer,
-lane rings, staging pool, in-flight budget, and reap queue are its own.
+lane rings, staging pool, in-flight budget, and reap queue are its own:
+one ``SliceRuntime`` (``pipeline/slices.py``) in the service's one table.
 Slices flush concurrently with zero cross-slice collectives; tenant
 moves between slices (failover/rebalance) hold per-tenant FIFO through
 ``_SliceFence``. A single-slice mesh degenerates to exactly the
@@ -82,6 +83,7 @@ from __future__ import annotations
 import asyncio
 import time
 from collections import OrderedDict
+from collections.abc import Mapping
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -111,10 +113,13 @@ from sitewhere_tpu.runtime.lifecycle import (
     cancel_and_wait,
 )
 from sitewhere_tpu.runtime.loopledger import spanned, sw
+from sitewhere_tpu.pipeline.slices import (
+    SliceRuntime, _empty_taken, _LaneRing, _PendingFlush,
+)
 from sitewhere_tpu.runtime.metrics import (
     D2H_OVERLAP_EPS_S as _D2H_OVERLAP_EPS_S,
+    MfuAccount,
     MetricsRegistry,
-    RollingQuantile,
 )
 from sitewhere_tpu.runtime.tenant import MultitenantService, TenantEngine
 
@@ -177,394 +182,54 @@ class StreamRegistry:
         return len(self._map)
 
 
-class _LaneRing:
-    """Pending rows for one (slot, data_shard): a preallocated numpy ring.
-
-    Rows are written into fixed-dtype ring segments at enqueue time
-    (``push`` — slice assignment, no per-row Python, no per-enqueue
-    allocation) and leave either straight into a flush's reusable staging
-    buffers (``pop_into``) or as fresh arrays on the cold paths (``pop``:
-    drain / park / breaker / failover). Capacity doubles when an intake
-    burst overshoots — the per-tenant lane watermark bounds steady-state
-    depth, so growth is rare and amortized.
-    """
-
-    COLS = ("ids", "vals", "seqs", "rows")
-    __slots__ = COLS + ("head", "count")
-
-    def __init__(self, capacity: int = 4096) -> None:
-        cap = max(64, int(capacity))
-        self.ids = np.empty((cap,), np.int32)   # local stream ids
-        self.vals = np.empty((cap,), np.float32)
-        self.seqs = np.empty((cap,), np.int64)  # batch sequence numbers
-        self.rows = np.empty((cap,), np.int32)  # row index inside the batch
-        self.head = 0
-        self.count = 0
-
-    @property
-    def capacity(self) -> int:
-        return len(self.ids)
-
-    def _grow(self, need: int) -> None:
-        cap = self.capacity
-        new_cap = cap
-        while new_cap < need:
-            new_cap *= 2
-        k = self.count
-        first = min(k, cap - self.head)
-        for name in self.COLS:
-            old = getattr(self, name)
-            new = np.empty((new_cap,), old.dtype)
-            new[:first] = old[self.head : self.head + first]
-            new[first:k] = old[: k - first]
-            setattr(self, name, new)
-        self.head = 0
-
-    def push(self, ids, vals, seq, rows) -> None:
-        """Append rows. ``seq`` may be a scalar (the per-enqueue common
-        case — broadcast into the ring, no per-batch full() array)."""
-        n = len(ids)
-        if self.count + n > self.capacity:
-            self._grow(self.count + n)
-        cap = self.capacity
-        tail = (self.head + self.count) % cap
-        first = min(n, cap - tail)
-        second = n - first
-        self.ids[tail : tail + first] = ids[:first]
-        self.vals[tail : tail + first] = vals[:first]
-        self.rows[tail : tail + first] = rows[:first]
-        if np.ndim(seq):
-            self.seqs[tail : tail + first] = seq[:first]
-        else:
-            self.seqs[tail : tail + first] = seq
-        if second:
-            self.ids[:second] = ids[first:]
-            self.vals[:second] = vals[first:]
-            self.rows[:second] = rows[first:]
-            self.seqs[:second] = seq[first:] if np.ndim(seq) else seq
-        self.count += n
-
-    def pop_into(
-        self, k: int, ids_row, vals_row, col0: int, seqs_out, rows_out, off: int
-    ) -> None:
-        """Move k rows FIFO off the front, straight into one slot's
-        staging views (``ids_row``/``vals_row`` at column ``col0`` — the
-        dtype cast to the scorer's wire happens inside the slice write)
-        and the flush's bookkeeping arrays at offset ``off``. At most two
-        slice copies per column; zero intermediate arrays."""
-        h, cap = self.head, self.capacity
-        first = min(k, cap - h)
-        second = k - first
-        ids_row[col0 : col0 + first] = self.ids[h : h + first]
-        vals_row[col0 : col0 + first] = self.vals[h : h + first]
-        seqs_out[off : off + first] = self.seqs[h : h + first]
-        rows_out[off : off + first] = self.rows[h : h + first]
-        if second:
-            ids_row[col0 + first : col0 + k] = self.ids[:second]
-            vals_row[col0 + first : col0 + k] = self.vals[:second]
-            seqs_out[off + first : off + k] = self.seqs[:second]
-            rows_out[off + first : off + k] = self.rows[:second]
-        self.head = (h + k) % cap
-        self.count -= k
-
-    def pop(self, n: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """Take up to n rows off the front as fresh arrays (cold paths)."""
-        k = min(int(n), self.count)
-        h, cap = self.head, self.capacity
-        first = min(k, cap - h)
-        out = []
-        for name in self.COLS:
-            a = getattr(self, name)
-            dst = np.empty((k,), a.dtype)
-            dst[:first] = a[h : h + first]
-            if k > first:
-                dst[first:] = a[: k - first]
-            out.append(dst)
-        self.head = (h + k) % cap
-        self.count -= k
-        return tuple(out)
-
-
-class _TrainLaneRing(_LaneRing):
-    """Replay-fed train-lane ring: pending TRAINING rows for one
-    (slot, data-shard), consumed from the tenant's ``replay-train-feed``
-    topic and packed into train microbatches through the same staging →
-    h2d wire as scoring flushes. Bounded by the train watermark
-    (2 × ``replay_microbatch``): past it the feed consumer stops pulling
-    (``tpu_inference.train_feed_backpressure``) and the backlog stays in
-    the bus topic, where retention bounds it and the replay pump's own
-    overload arbitration already parks the producer. Depth is the
-    ``tpu_inference_train_rows{family}`` gauge (tools/check_queues.py).
-    Same columnar ring mechanics as the serve lanes — distinct type so
-    the bounded-queue lint tracks the train lane as its own queue."""
-
-    __slots__ = ()
-
-
-def _empty_taken():
-    """A train-lane pending entry's ``taken`` placeholder: zero rows, so
-    every row-oriented resolve/teardown path (``_resolve_rows`` on the
-    seqs/rows columns) is a structural no-op without branching."""
-    return (None, None, np.empty((0,), np.int64), np.empty((0,), np.int32))
-
-
-class _StagingSet:
-    """One reusable flush staging set: ids/vals ``[T, D*B]`` in the
-    scorer's wire dtypes, lane counts ``[T, D]``, and a cached column
-    arange. A flush packs lanes into these buffers in place (no fresh
-    ``np.zeros`` per flush) and ``jax.device_put``s them; ``staged``
-    pins the device arrays from this set's LAST put — the async h2d copy
-    reads the host buffers, so reuse must wait on it (two sets rotating
-    per (family, bucket) normally hides that wait entirely)."""
-
-    __slots__ = ("ids", "vals", "counts", "arange", "staged")
-
-    def __init__(self, scorer, b_lane: int) -> None:
-        t, d = scorer.n_slots, scorer.mm.n_data_shards
-        self.ids = np.zeros((t, d * b_lane), scorer.ids_np_dtype)
-        self.vals = np.zeros((t, d * b_lane), scorer.vals_np_dtype)
-        self.counts = np.zeros((t, d), np.int32)
-        self.arange = np.arange(d * b_lane, dtype=np.int32)
-        self.staged = None
-
-    def ensure_reusable(self, metrics) -> None:
-        """Block until this set's previous device copy finished (counted;
-        with overlap working the transfer is long done by recycle time)."""
-        staged = self.staged
-        if staged is None:
-            return
-        self.staged = None
-        try:
-            if all(a.is_ready() for a in staged):
-                return
-            metrics.counter("tpu_inference.stage_reuse_waits").inc()
-            for a in staged:
-                a.block_until_ready()
-        except Exception:  # noqa: BLE001 - non-jax arrays (tests) or a
-            # dead device buffer (failover mid-rotation): treat as free
-            pass
-
-
-class _PendingFlush:
-    """One dispatched flush awaiting its device→host score transfer —
-    and the span record of that flush: ``flush_id`` names it (each batch
-    it completes stamps the id on its inference span), ``rec`` is the
-    record dict the flight recorder's ring and ``flush_records`` share,
-    which carries the batch ``seqs`` and the contiguous
-    ``time.perf_counter()`` stamps ``t_oldest → t_asked → t_got →
-    t_assembled → t_staged → t_dispatched → t_landed → t_resolved`` (the
-    first six written by ``_flush_slice``, the last two by
-    ``_resolve_flush``).
-
-    ``scores`` is either the device-gathered row vector (``gathered``
-    True — slice ``[:moved]`` is the picks, already in pack order) or
-    the full score plane (fallback for scorers without ``gather_rows``,
-    e.g. monkeypatched test doubles — the host then picks
-    ``scores[slots, cols]``). The d2h copy was started at dispatch
-    (``copy_to_host_async``); outputs that can't copy asynchronously
-    get an eager executor materialization instead (``host_future``), so
-    fallback flushes still overlap each other like the old per-flush
-    deliver tasks did."""
-
-    __slots__ = (
-        "family", "sl", "scores", "taken", "moved", "gathered",
-        "t_dispatch", "nbytes", "plane_nbytes", "host_future", "t_wait",
-        "poisoned", "flops", "rec", "sketch", "shadow", "slot_override",
-        "resolved", "lane", "deadline", "retried", "retry_rows",
-        "retry_from", "owns_permit", "flush_id",
-    )
-
-    def __init__(
-        self, family: str, scores, taken, moved: int, gathered: bool,
-        nbytes: int, plane_nbytes: int, poisoned: bool = False,
-        flops: float = 0.0, rec: Optional[dict] = None,
-        sketch=None, shadow=None, sl: int = 0, lane: str = "serve",
-        flush_id: int = -1, t_dispatch: Optional[float] = None,
-    ) -> None:
-        self.family = family
-        self.flush_id = flush_id
-        # the mesh slice that ran this flush: reap queues, overlap
-        # probes, and device-labeled attribution are all keyed
-        # (family, slice) on a multi-chip mesh
-        self.sl = sl
-        # set when the flush's resolution finished (either way) — the
-        # slice-move fence waits on this, never on queue identity
-        self.resolved = False
-        self.scores = scores
-        self.taken = taken
-        self.moved = moved
-        self.gathered = gathered
-        # when the dispatch call returned (perf_counter): the start of
-        # the in-flight interval and of the supervisor's deadline
-        self.t_dispatch = (
-            time.perf_counter() if t_dispatch is None else t_dispatch
-        )
-        self.nbytes = nbytes
-        self.plane_nbytes = plane_nbytes
-        self.host_future = None
-        self.t_wait = None  # when the reaper first started waiting on us
-        # a flush whose DISPATCH failed (no scores, no transfer): it
-        # rides the FIFO so its unscored resolution can't overtake an
-        # earlier in-flight flush of the same family
-        self.poisoned = poisoned
-        # device-time attribution: FLOPs this flush's padded plane
-        # executes (scorer.flops_per_flush) and the flight-recorder
-        # record completed in place when the flush resolves
-        self.flops = flops
-        self.rec = rec
-        # score-quality payloads riding the same reaper slot: the step's
-        # per-slot score sketch (i32[T, D, NBINS] — runtime.scorehealth)
-        # and the canary's shadow-scored row vector (previous-variant
-        # divergence). Their async host copies start at dispatch like the
-        # scores'; by the time the scores land these few-KB transfers
-        # have long since followed — no extra round-trip.
-        self.sketch = sketch
-        self.shadow = shadow
-        # the single-used-slot fallback slice zeroes the pack-order slot
-        # indices (rows then index row 0 of the slice); this remembers
-        # the real slot so NaN attribution survives that path
-        self.slot_override: Optional[int] = None
-        # which lane dispatched this entry: "serve" (a scoring flush —
-        # everything above applies) or "train" (a continual-learning
-        # train step riding the same per-slice in-flight window and
-        # reaper: ``scores`` holds the per-slot loss vector, ``taken``
-        # is empty, and resolution records training metrics instead of
-        # publishing batches). One FIFO per (family, slice) keeps the
-        # permit accounting and teardown drain uniform across lanes.
-        self.lane = lane
-        # flush supervision (docs/ROBUSTNESS.md "Device fault domains"):
-        # the absolute perf_counter() moment by which this flush's
-        # transfer must have landed — past it the reaper force-resolves
-        # the rows unscored in this FIFO slot and quarantines the slice.
-        # None = unsupervised (flush_deadline_ms knob off, or poisoned
-        # entries that land immediately by construction).
-        self.deadline: Optional[float] = None
-        # poison-batch ejection: this pf IS the one-shot retry of a
-        # faulted flush's rows (``retry_from`` = the slice the FIRST
-        # failure happened on) — a second failure on a DIFFERENT slice
-        # attributes the fault to the data and ships the batches to the
-        # scorer-poison DLQ; a second failure on the SAME chip stays a
-        # chip signal (unscored resolve + breaker/failover pacing)
-        self.retried = False
-        self.retry_from: Optional[int] = None
-        # host copies of the staged (ids, vals, dshards) rows, kept so a
-        # TIMED-OUT flush can retry with the same bytes (the staging set
-        # recycles long before a deadline expires); populated only while
-        # the family's poison_retry knob is on
-        self.retry_rows: Optional[tuple] = None
-        # False for ORDERED host-only entries enqueued from inside a
-        # resolve task (per-tenant FIFO fallbacks of the poison-retry
-        # path): acquiring a permit there can deadlock against the very
-        # head whose resolution is enqueueing them, and a host-only
-        # poisoned entry holds no device resources for the in-flight
-        # window to meter — the resolve/teardown release sites skip it
-        self.owns_permit = True
-
-    @property
-    def key(self) -> Tuple[str, int]:
-        return (self.family, self.sl)
-
-    def overdue(self, now: Optional[float] = None) -> bool:
-        """Deadline passed without resolution — the supervisor's
-        force-resolve trigger (poisoned entries land instantly and are
-        never overdue)."""
-        if self.deadline is None or self.poisoned:
-            return False
-        return (time.perf_counter() if now is None else now) > self.deadline
-
-    def _materialize(self):
-        """Worker-thread materialization of every device output riding
-        this flush — one executor hop for scores + sketch + shadow."""
-        return (
-            np.asarray(self.scores),
-            None if self.sketch is None else np.asarray(self.sketch),
-            None if self.shadow is None else np.asarray(self.shadow),
-        )
-
-    def landed(self) -> bool:
-        """Probably-complete signal used to PRIORITIZE heads: a finished
-        executor materialization, or (for jax arrays) ``is_ready`` —
-        which only proves the device COMPUTE finished, not that the
-        async host copy crossed the link. Honest overlap accounting is
-        therefore measured at materialize time (see ``_resolve_flush``),
-        never inferred from this."""
-        if self.poisoned:
-            return True  # nothing to wait for — resolvable immediately
-        if self.host_future is not None:
-            return self.host_future.done()
-        try:
-            return bool(self.scores.is_ready())
-        except Exception:  # noqa: BLE001 - non-jax doubles: never "landed"
-            return False
-
-    def ensure_host_future(self, loop, pool):
-        """Lazily start (and cache) an executor materialization — used
-        when the reaper must wait on several families' heads at once.
-        Resolves to the (scores, sketch, shadow) host triple."""
-        if self.host_future is None:
-            self.host_future = loop.run_in_executor(
-                pool, self._materialize
-            )
-        return self.host_future
-
-
-class _ReapQueue(list):
-    """Per-(family, mesh-slice) FIFO of in-flight flush completions —
-    the PER-DEVICE drain queues of the multi-chip result path. Depth is
-    bounded by the ``max_inflight`` semaphore (acquired before rows are
-    popped from lanes) and reaches it only where flushes pipeline — a
-    lane at the smallest bucket or over; smaller flushes wait for the
-    one in flight (``_flush_held``) and the queue stands one deep. It is
-    observable via the
-    ``tpu_inference_deliver_inflight`` gauge (+ per-family and
-    per-device labeled variants) and the
-    ``tpu_inference.deliver_backpressure`` counter
-    (tools/check_queues.py registry). FIFO per (family, slice) is what
-    gives per-tenant in-order delivery: a tenant lives on exactly one
-    slice of one family, the reaper never resolves past an unfinished
-    head, and a slice MOVE (failover/rebalance) holds the tenant's rows
-    behind a ``_SliceFence`` until the old slice's in-flight flushes
-    resolve — so one slow chip's transfers never head-of-line block
-    another slice's deliveries, and ordering still survives the move."""
-
-    __slots__ = ()
-
-    def popleft(self) -> _PendingFlush:
-        return self.pop(0)
-
-
 class AmbiguousFamilyError(KeyError):
     """A family-string lookup matched MORE than one mesh slice — the
     caller must key by (family, slice). Distinct from a plain missing
     key so ``get()`` can default only the truly-absent case."""
 
 
-class _ScorerMap(dict):
-    """(family, slice) → ShardedScorer, with family-string convenience
-    lookup: ``scorers["lstm_ad"]`` resolves when exactly one slice hosts
-    the family (the common single-tenant/operator case); ambiguous
-    lookups must name the slice explicitly."""
+class _ScorerMap(Mapping):
+    """(family, slice) → one attribute of each ``SliceRuntime`` (its
+    scorer, its breaker, its last train losses): a read-only view over
+    the service's slice table, with family-string convenience lookup —
+    ``scorers["lstm_ad"]`` resolves when exactly one slice hosts the
+    family; ambiguous lookups must name the slice. A slice whose
+    attribute is still None (no train step yet) is not in the view."""
 
-    def _resolve(self, family: str):
-        hits = [k for k in self if k[0] == family]
-        if len(hits) == 1:
-            return hits[0]
-        if not hits:
-            raise KeyError(family)
-        raise AmbiguousFamilyError(
-            f"family '{family}' is served on {len(hits)} mesh slices "
-            f"({sorted(k[1] for k in hits)}) — key scorers[(family, slice)]"
+    def __init__(self, slices: Dict[Tuple[str, int], SliceRuntime], attr: str) -> None:
+        self._slices = slices
+        self._attr = attr
+
+    def __iter__(self):
+        return (
+            k for k, s in self._slices.items()
+            if getattr(s, self._attr) is not None
         )
+
+    def __len__(self) -> int:
+        return sum(1 for _ in self)
 
     def __getitem__(self, key):
         if isinstance(key, str):
-            key = self._resolve(key)
-        return dict.__getitem__(self, key)
+            hits = [k for k in self if k[0] == key]
+            if len(hits) > 1:
+                raise AmbiguousFamilyError(
+                    f"family '{key}' is served on {len(hits)} mesh slices "
+                    f"({sorted(k[1] for k in hits)}) — key "
+                    f"scorers[(family, slice)]"
+                )
+            if not hits:
+                raise KeyError(key)
+            key = hits[0]
+        value = getattr(self._slices[key], self._attr)
+        if value is None:
+            raise KeyError(key)
+        return value
 
     def __contains__(self, key) -> bool:
         if isinstance(key, str):
             return any(k[0] == key for k in self)
-        return dict.__contains__(self, key)
+        return Mapping.__contains__(self, key)
 
     def get(self, key, default=None):
         try:
@@ -577,9 +242,7 @@ class _ScorerMap(dict):
             return default
 
     def family_items(self, family: str):
-        return sorted(
-            ((k[1], v) for k, v in self.items() if k[0] == family)
-        )
+        return sorted((k[1], v) for k, v in self.items() if k[0] == family)
 
 
 class _SliceFence:
@@ -739,11 +402,8 @@ class TpuInferenceEngine(TenantEngine):
         # and clears the family breaker's failure history with it
         svc._parked.discard(self.config.model)
         svc._failover_rounds.pop(self.config.model, None)
-        for _sl, breaker in [
-            (k[1], v) for k, v in svc.breakers.items()
-            if k[0] == self.config.model
-        ]:
-            breaker.reset()
+        for s in svc._family_slices(self.config.model):
+            s.breaker.reset()
         # ...and the quarantine ledger: an explicit engine (re)start is
         # the operator's heal signal, the same contract as the breaker
         # resets above — probation probes are for UNATTENDED recovery
@@ -752,9 +412,10 @@ class TpuInferenceEngine(TenantEngine):
     async def on_stop(self) -> None:
         svc = self.service
         if self.placement is not None:
-            sl = self.placement.shard
             slot = self.placement.slot
-            scorer = svc.scorers.get((self.config.model, sl))
+            # None only if the slice's scorer never built (a failed start)
+            s = svc._slices.get((self.config.model, self.placement.shard))
+            scorer = s.scorer if s is not None else None
             if slot >= 0 and scorer is not None and svc.checkpoints is not None:
                 # save this tenant's (possibly trained) weights BEFORE the
                 # slot wipe below destroys them. Materialize to numpy ON
@@ -800,19 +461,14 @@ class TpuInferenceEngine(TenantEngine):
             # already advanced past these rows, so dropping them would lose
             # them from the store on every tenant restart — resolve them
             # unscored (NaN) instead
-            lanes = svc._lanes.get((self.config.model, sl))
-            if lanes is not None:
+            if s is not None:
                 drained = svc.metrics.counter("tpu_inference.drained_on_stop")
-                for key in [k for k in lanes if k[0] == slot]:
-                    lane = lanes.pop(key)
-                    n = lane.count
-                    if n:
-                        _ids, _vals, seqs, rows = lane.pop(n)
-                        await svc._resolve_rows(
-                            seqs, rows, None, publish_nowait=True,
-                            family=self.config.model,
-                        )
-                        drained.inc(n)
+                for _d, _i, _v, seqs, rows in s.drain_lanes(slot):
+                    await svc._resolve_rows(
+                        seqs, rows, None, publish_nowait=True,
+                        family=self.config.model,
+                    )
+                    drained.inc(len(seqs))
             # a tenant removed mid-slice-move: its fenced rows were
             # consumed off the bus, so they resolve unscored too
             fence = svc._fences.pop(self.tenant, None)
@@ -827,20 +483,10 @@ class TpuInferenceEngine(TenantEngine):
                             seqs, rows, None, publish_nowait=True,
                             family=self.config.model,
                         )
-            # the tenant's pending TRAIN rows are droppable — they are
-            # replayed history the segment store still holds (a future
-            # replay train job re-feeds them); no loss accounting rides
-            # on the train lane
-            tl = svc._train_lanes.get((self.config.model, sl))
-            if tl is not None:
-                for key in [k for k in tl if k[0] == slot]:
-                    tl.pop(key)
-                svc._train_rows_gauge(self.config.model, sl)
-            # a recycled slot must not inherit this tenant's mature
-            # cadence tick either
-            svc._train_ticks.get((self.config.model, sl), {}).pop(
-                slot, None
-            )
+            # the tenant's pending TRAIN rows and cadence tick go with
+            # it; no loss accounting rides on the train lane
+            if s is not None:
+                svc._forget_slot_training(s, slot)
             # the train-feed cursor must leave with the tenant: a stale
             # registered group never advances and would backpressure the
             # topic forever — wedging any LATER replay train job exactly
@@ -922,80 +568,38 @@ class TpuInferenceService(MultitenantService):
         # live device-time/MFU attribution per family (runtime.metrics
         # .MfuAccount; fed by resolved flushes, decayed by refresh_mfu)
         self._mfu: Dict[str, object] = {}
-        # per-(family, mesh-slice) device-labeled MFU accounts beside
-        # the family aggregate (separate metric names — see
-        # MfuAccount.DEVICE_NAMES): on a multi-chip mesh, per-chip
-        # utilization is what keeps tpu_mfu_pct honest at n_devices>1
-        self._mfu_dev: Dict[Tuple[str, int], object] = {}
         self._stage_timers: Dict[str, object] = {}
-        self._seen_shapes: set = set()
         # the flush records of the newest flushes by flush_id — the same
         # dicts the flight recorder's ring holds. The latency ledger
         # splits a batch's inference span on ITS OWN flush through the
         # flush_id the span carries (runtime.latency.stage_vector).
         self.flush_records: "OrderedDict[int, dict]" = OrderedDict()
         self._next_flush_id = 0
-        # per-(family, slice) perf_counter() of the newest landing: the
-        # device queue is FIFO, so a flush's non-overlapping service
-        # time runs from the later of its own dispatch and this
-        self._last_landed: Dict[Tuple[str, int], float] = {}
         self.slots_per_shard = slots_per_shard
         self.poll_batch = poll_batch  # bus items (batches) per poll
         self.router = TenantRouter(self.mm.n_tenant_shards, slots_per_shard)
-        # (family, mesh-slice) → ShardedScorer over that slice's
-        # sub-mesh: each slice dispatches/stages/reaps independently —
-        # the unit of horizontal scale (ROADMAP item 1). String lookup
-        # resolves single-slice families for operator/test convenience.
-        self.scorers: _ScorerMap = _ScorerMap()
+        # THE per-slice table: (family, mesh-slice) → SliceRuntime, the
+        # scorer over that slice's sub-mesh with everything kept per
+        # slice (pipeline/slices.py). Each slice dispatches, stages and
+        # reaps independently — the unit of horizontal scale. Filled by
+        # ``scorer_for_slice``, emptied by ``on_stop``; nothing else
+        # here is keyed by (family, slice) but the three views over it
+        # (string lookup resolves single-slice families)
+        self._slices: Dict[Tuple[str, int], SliceRuntime] = {}
+        self.scorers = _ScorerMap(self._slices, "scorer")
+        self.breakers = _ScorerMap(self._slices, "breaker")
+        self.last_train_losses = _ScorerMap(self._slices, "last_train_losses")
         # first tenant of a family pins the family-wide knobs (wire
         # dtype, fused kernel shape, model config): EVERY slice scorer
         # of the family builds from this config so slices are
         # numerically interchangeable across failover/rebalance moves
         self._family_cfg: Dict[str, TenantEngineConfig] = {}
-        # per-(family, slice) circuit breaker over scorer dispatch +
-        # materialization (the first tenant's FaultTolerancePolicy pins
-        # the policy family-wide, like wire_dtype): breaker scope
-        # matches failure scope — one sick chip's open breaker must not
-        # short-circuit healthy slices of the family into unscored
-        # pass-through. String lookup resolves single-slice families.
-        self.breakers: _ScorerMap = _ScorerMap()
-        self._lanes: Dict[
-            Tuple[str, int], Dict[Tuple[int, int], _LaneRing]
-        ] = {}
-        # reusable flush staging: (family, slice, bucket) → [next_idx,
-        # sets]; ``staging_slots`` sets rotate PER SLICE so every slice
-        # packs host buffers while its own previous flush's async h2d
-        # copy is still in flight — slices never contend on one pool
         self.staging_slots = max(2, int(staging_slots))
-        self._staging: Dict[Tuple[str, int, int], list] = {}
-        # per-(family, slice) last dispatch output — the overlap probe
-        # (next flush's staging "overlapped" ⇔ this is still computing).
-        # With the device-side gather it holds the GATHERED rows (a few
-        # KB), never the score plane, and the reaper drops it when the
-        # slice's in-flight queue drains so an idle slice pins nothing
-        self._last_scores: Dict[Tuple[str, int], object] = {}
-        self._first_pending_ts: Dict[Tuple[str, int], float] = {}
         self._loop_super: Optional[SupervisedTask] = None
         # batch registry: seq → [batch, rows_awaiting_scores]
         self._batches: Dict[int, list] = {}
         self._next_seq = 0
-        # live-training cadence: per-(family, slice) {slot: flush-tick}.
-        # With the async train lane, a LANE slot's tick only accumulates
-        # here (maturity is checked — and reset — at lane dispatch, so a
-        # throttled slot keeps its mature tick until admitted); inline
-        # slots keep the legacy check-and-reset-per-flush semantics.
-        self._train_ticks: Dict[Tuple[str, int], Dict[int, int]] = {}
-        # continual-learning train lane (docs/PERFORMANCE.md "Continual
-        # learning lane"): replay-fed training rows per (family, slice),
-        # keyed (slot, data-shard) like the serve lanes; steps since the
-        # last weight commit per slice; scratch columns for the packer
-        self._train_lanes: Dict[
-            Tuple[str, int], Dict[Tuple[int, int], _TrainLaneRing]
-        ] = {}
-        self._lane_swap: Dict[Tuple[str, int], int] = {}
-        # last dispatched lane source per slice ("replay" | "resident")
-        # — the alternation token when both sources are pending
-        self._lane_last_source: Dict[Tuple[str, int], str] = {}
+        # scratch columns for the train lane's packer
         self._train_scratch: Optional[tuple] = None
         self.metrics.describe(
             "tpu_train_skipped_total",
@@ -1018,22 +622,18 @@ class TpuInferenceService(MultitenantService):
             "tpu_train_flops_total",
             "analytic FLOPs executed by train-lane steps per family — "
             "kept OUT of tpu_flops_total/tpu_mfu_pct (serving work); "
-            "the bench's overlap-MFU column sums the two",
+            "an overlap-MFU reading sums the two",
         )
         self.metrics.describe(
             "tpu_train_swaps_total",
             "train-lane weight commits (kernel-sidecar re-derivation + "
             "canary arm) per family — one every swap_every lane steps",
         )
-        # per-(family, slice) last train losses (device arrays; string
-        # lookup resolves while one slice serves the family)
-        self.last_train_losses: _ScorerMap = _ScorerMap()
-        # auto-failover: consecutive scorer errors per (family, slice) —
-        # errors are chip-local, so only the sick slice's tenants
+        # auto-failover: at this many consecutive scorer errors on a
+        # slice (errors are chip-local) only the sick slice's tenants
         # re-place onto different mesh shards (SURVEY.md §5:
         # "tenant-engine failover to a different mesh shard")
         self.failover_threshold = 3
-        self._consec_errors: Dict[Tuple[str, int], int] = {}
         # escalation: failover rounds without an intervening healthy
         # delivery; past max_failover_rounds the family PARKS — events
         # flow through unscored (degraded, never lost) until a tenant
@@ -1044,27 +644,15 @@ class TpuInferenceService(MultitenantService):
         # slice-move fences: tenant → _SliceFence while a failover/
         # rebalance move outwaits the old slice's in-flight flushes
         self._fences: Dict[str, _SliceFence] = {}
-        # in-flight flush budget PER (family, slice): the bound exists
-        # to limit concurrent d2h round trips on ONE device queue, so a
-        # saturated slice exhausts ITS OWN permits while other slices
-        # keep flushing — a global semaphore would let one slow chip
-        # starve every other slice's flush admission (the multi-chip
-        # analog of the head-of-line blocking the reaper already avoids)
-        self._inflight: Dict[Tuple[str, int], asyncio.Semaphore] = {}
+        # in-flight flush budget PER SLICE (``SliceRuntime.permits``): a
+        # global semaphore would let one slow chip starve every other
+        # slice's flush admission
         self.max_inflight = max_inflight
         self._deliver_pool = None  # created on start, shut down on stop
-        # result path: per-(family, slice) FIFOs of in-flight flush
-        # completions — per-DEVICE drain queues, drained by the reaper
-        # task as d2h transfers land (out of order across slices and
-        # families, in order per tenant)
-        self._reap: Dict[Tuple[str, int], _ReapQueue] = {}
+        # result path: the reaper drains every slice's FIFO as transfers
+        # land (out of order across slices, in order per tenant)
         self._reap_event = asyncio.Event()
         self._reaper_super: Optional[SupervisedTask] = None
-        # per-(family, slice) resolve task in flight (≤ 1 per slice
-        # queue keeps the per-tenant FIFO; separate tasks keep one
-        # tenant's backpressured publish from head-of-line blocking
-        # other slices' landed transfers behind the reaper coroutine)
-        self._resolving: Dict[Tuple[str, int], asyncio.Task] = {}
         # teardown grace for in-flight transfers before they force-resolve
         # unscored (a dead device must not hang the stop cascade)
         self.deliver_drain_timeout_s = 10.0
@@ -1073,17 +661,6 @@ class TpuInferenceService(MultitenantService):
         # None in production). Consulted at every dispatch: serve, train,
         # shadow, and probation-probe lanes.
         self.faultplan = None
-        # per-(family, slice) dispatch→transfer-landed history: the
-        # flush deadline is max(flush_deadline_ms, flush_deadline_x ×
-        # this window's p99) — the same samples the flightrec flush
-        # records carry as device_s
-        self._flush_p99: Dict[Tuple[str, int], RollingQuantile] = {}
-        # quarantined (family, slice)s: SUSPECT after a flush timeout or
-        # the failover escalation; the router routes around them, their
-        # lanes drain unscored (degraded, never lost), and a background
-        # probe re-admits after probation_probes consecutive landings
-        self._quarantined: Dict[Tuple[str, int], dict] = {}
-        self._probing: Dict[Tuple[str, int], asyncio.Task] = {}
         # poison-batch ejection: batch seqs already granted their one
         # retry — a second failure ships them to the scorer-poison DLQ
         self._retried_seqs: set = set()
@@ -1148,11 +725,18 @@ class TpuInferenceService(MultitenantService):
     def group(self) -> str:
         return "tpu-inference"
 
-    def _inflight_sem(self, key: Tuple[str, int]) -> asyncio.Semaphore:
-        sem = self._inflight.get(key)
-        if sem is None:
-            sem = self._inflight[key] = asyncio.Semaphore(self.max_inflight)
-        return sem
+    def _family_slices(self, family: str) -> List[SliceRuntime]:
+        """Every slice that serves ``family``, in birth order."""
+        return [s for s in self._slices.values() if s.family == family]
+
+    def quarantined_slices(self) -> int:
+        """How many (family, slice)s are quarantined right now."""
+        return sum(s.quarantine is not None for s in self._slices.values())
+
+    def _quarantine_gauge(self) -> None:
+        self.metrics.gauge("tpu_inference_quarantined_slices").set(
+            self.quarantined_slices()
+        )
 
     # -- flush supervision -------------------------------------------------
     def _family_ft(self, family: str) -> FaultTolerancePolicy:
@@ -1162,36 +746,6 @@ class TpuInferenceService(MultitenantService):
         return pin.fault_tolerance if pin is not None else (
             FaultTolerancePolicy()
         )
-
-    def _flush_deadline_s(self, family: str, sl: int) -> Optional[float]:
-        """Seconds a newly dispatched flush gets before the supervisor
-        force-resolves it: max(floor, x × the (family, slice)'s observed
-        dispatch→landed p99). None = supervision off for the family
-        (``flush_deadline_ms = 0`` — the rollback knob)."""
-        ft = self._family_ft(family)
-        floor = ft.flush_deadline_ms / 1000.0
-        if floor <= 0:
-            return None
-        rq = self._flush_p99.get((family, sl))
-        p99 = rq.quantile() if rq is not None else None
-        if p99 is None:
-            return floor
-        return max(floor, ft.flush_deadline_x * p99)
-
-    def _note_device_s(self, key: Tuple[str, int], device_s: float) -> None:
-        rq = self._flush_p99.get(key)
-        if rq is None:
-            rq = self._flush_p99[key] = RollingQuantile()
-        rq.add(device_s)
-        p99 = rq.quantile()
-        if p99 is not None:
-            # the deadline source, surfaced live: per-(family, slice)
-            # dispatch→landed p99 used to feed ONLY deadline sizing —
-            # the latency waterfall and history sampler read this gauge
-            self.metrics.gauge(
-                "tpu_flush_latency_p99_ms",
-                family=key[0], slice=str(key[1]),
-            ).set(round(p99 * 1000.0, 3))
 
     def _make_engine(self, cfg: TenantEngineConfig) -> TpuInferenceEngine:
         return TpuInferenceEngine(cfg, self)
@@ -1205,9 +759,7 @@ class TpuInferenceService(MultitenantService):
         kernel, or a failover move would change a tenant's numerics)."""
         # knob-conflict checks compare against the family's pinned
         # representative (any existing slice scorer of the family)
-        scorer = next(
-            (v for (f, _s), v in self.scorers.items() if f == family), None
-        )
+        scorer = next((s.scorer for s in self._family_slices(family)), None)
         if scorer is not None and scorer.wire_dtype != cfg.wire_dtype:
             # the wire dtype is a property of the FAMILY stack (first
             # tenant wins); a later tenant asking for a different wire
@@ -1250,62 +802,76 @@ class TpuInferenceService(MultitenantService):
                 ),
             )
             self.metrics.counter("tpu_inference.fused_knob_conflicts").inc()
-        if (family, sl) not in self.scorers:
-            # build THIS slice's scorer from the family-pinned config so
-            # every slice compiles the identical kernel variant
-            pin = self._family_cfg.setdefault(family, cfg)
-            spec = get_model(family)
-            mcfg = make_config(family, {
-                **pin.model_config, "window": pin.microbatch.window,
-            })
-            scorer = ShardedScorer(
-                self.mm.slice_manager(sl),
-                spec,
-                mcfg,
-                slots_per_shard=self.slots_per_shard,
-                max_streams=pin.max_streams,
-                window=pin.microbatch.window,
-                wire_dtype=pin.wire_dtype,
-                fuse_k=getattr(pin, "fuse_k", 1),
-                param_dtype=getattr(pin, "param_dtype", "f32"),
-            )
-            # shadow-canary fraction: family-pinned like the fused knobs
-            # (first tenant wins; one shadow step per family stack)
-            scorer.canary_frac = float(getattr(pin, "canary_frac", 0.0) or 0.0)
-            self.scorers[(family, sl)] = scorer
-            self._lanes[(family, sl)] = {}
-            if self.mm.n_devices > 1:
-                # how many mesh slices currently serve this family —
-                # slice spread is the first thing to read when per-device
-                # rows/MFU look uneven (docs/OBSERVABILITY.md)
-                self.metrics.gauge(
-                    "tpu_inference_slice_scorers", family=family
-                ).set(sum(1 for k in self.scorers if k[0] == family))
-        else:
-            return self.scorers[(family, sl)]
-        if (family, sl) not in self.breakers:
-            # the failover→park escalation is the scorer's first-line
-            # healing; by default the breaker must not open mid-escalation
-            # and starve it of failure outcomes (parked families stop
-            # flushing), so its verdict window is floored at the park
-            # budget. Chaos/testing configs set breaker_defer_to_failover
-            # False to let the breaker act first.
-            from dataclasses import replace as _replace
+        s = self._slices.get((family, sl))
+        if s is not None:
+            return s.scorer
+        # build THIS slice's scorer from the family-pinned config so
+        # every slice compiles the identical kernel variant
+        pin = self._family_cfg.setdefault(family, cfg)
+        spec = get_model(family)
+        mcfg = make_config(family, {
+            **pin.model_config, "window": pin.microbatch.window,
+        })
+        scorer = ShardedScorer(
+            self.mm.slice_manager(sl),
+            spec,
+            mcfg,
+            slots_per_shard=self.slots_per_shard,
+            max_streams=pin.max_streams,
+            window=pin.microbatch.window,
+            wire_dtype=pin.wire_dtype,
+            fuse_k=getattr(pin, "fuse_k", 1),
+            param_dtype=getattr(pin, "param_dtype", "f32"),
+        )
+        # shadow-canary fraction: family-pinned like the fused knobs
+        # (first tenant wins; one shadow step per family stack)
+        scorer.canary_frac = float(getattr(pin, "canary_frac", 0.0) or 0.0)
+        # the failover→park escalation is the scorer's first-line
+        # healing; by default the breaker must not open mid-escalation
+        # and starve it of failure outcomes (parked families stop
+        # flushing), so its verdict window is floored at the park
+        # budget. Chaos/testing configs set breaker_defer_to_failover
+        # False to let the breaker act first.
+        from dataclasses import replace as _replace
 
-            ft = cfg.fault_tolerance
-            park_budget = (
-                self.failover_threshold * (self.max_failover_rounds + 1) + 1
+        ft = cfg.fault_tolerance
+        park_budget = (
+            self.failover_threshold * (self.max_failover_rounds + 1) + 1
+        )
+        if (
+            ft.breaker_defer_to_failover
+            and ft.breaker_min_samples < park_budget
+        ):
+            ft = _replace(ft, breaker_min_samples=park_budget)
+        breaker = CircuitBreaker(
+            f"tpu_inference.{family}.s{sl}",
+            policy=ft,
+            metrics=self.metrics,
+        )
+        mfu = None
+        if self.mm.n_devices > 1:
+            # chip-level utilization under the DEVICE-labeled names: an
+            # idle or skewed slice is visible instead of averaged away
+            # by the family aggregate. Cardinality is mesh-bounded.
+            f_name, s_name, g_name = MfuAccount.DEVICE_NAMES
+            mfu = MfuAccount(
+                self.metrics, family,
+                flops_name=f_name, secs_name=s_name, gauge_name=g_name,
+                device=self.mm.slice_device_label(sl),
             )
-            if (
-                ft.breaker_defer_to_failover
-                and ft.breaker_min_samples < park_budget
-            ):
-                ft = _replace(ft, breaker_min_samples=park_budget)
-            self.breakers[(family, sl)] = CircuitBreaker(
-                f"tpu_inference.{family}.s{sl}",
-                policy=ft,
-                metrics=self.metrics,
-            )
+        # THE birth of a slice, whole
+        self._slices[(family, sl)] = SliceRuntime(
+            family, sl, scorer, breaker, self.metrics,
+            max_inflight=self.max_inflight,
+            staging_slots=self.staging_slots, mfu=mfu,
+        )
+        if self.mm.n_devices > 1:
+            # how many mesh slices currently serve this family —
+            # slice spread is the first thing to read when per-device
+            # rows/MFU look uneven (docs/OBSERVABILITY.md)
+            self.metrics.gauge(
+                "tpu_inference_slice_scorers", family=family
+            ).set(len(self._family_slices(family)))
         return scorer
 
     # -- lifecycle -------------------------------------------------------
@@ -1360,7 +926,10 @@ class TpuInferenceService(MultitenantService):
         # (they hold rows already popped from lanes — dropping them would
         # lose events); only give up if the device never answers
         deadline = time.monotonic() + self.deliver_drain_timeout_s
-        while any(self._reap.values()) and time.monotonic() < deadline:
+        while (
+            any(s.reap for s in self._slices.values())
+            and time.monotonic() < deadline
+        ):
             await asyncio.sleep(0.02)
         if self._reaper_super is not None:
             await self._reaper_super.terminate()
@@ -1368,31 +937,31 @@ class TpuInferenceService(MultitenantService):
         # cancel per-family resolves still blocked (e.g. a publish against
         # a stopped consumer): their CancelledError path resolves the
         # popped rows unscored via publish_nowait before re-raising
-        for task in list(self._resolving.values()):
-            task.cancel()
-            try:
-                await task
-            except (asyncio.CancelledError, Exception):  # noqa: BLE001
-                pass
-        self._resolving.clear()
+        for task in [s.resolving for s in self._slices.values()]:
+            if task is not None:
+                task.cancel()
+                try:
+                    await task
+                except (asyncio.CancelledError, Exception):  # noqa: BLE001
+                    pass
         # force-resolve anything still stuck, unscored (zero loss even
         # when a transfer never completes) — the SAME accounting helper
         # the supervisor's mid-run deadline path uses, so teardown and
         # in-flight force-resolution cannot diverge
-        for q in self._reap.values():
-            while q:
-                pf = q.popleft()
+        for s in self._slices.values():
+            while s.reap:
+                pf = s.reap.popleft()
                 await self._force_resolve(pf, nowait=True)
                 pf.resolved = True
                 if pf.owns_permit:
-                    self._inflight_sem(pf.key).release()
+                    s.permits.release()
         self._deliver_gauge()
         # probation probes die with the service; a hung chaos plan must
         # release its blocked worker threads or the deliver pool's
         # shutdown below strands them past interpreter exit
-        for task in list(self._probing.values()):
-            task.cancel()
-        self._probing.clear()
+        for s in self._slices.values():
+            if s.probing is not None:
+                s.probing.cancel()
         if self.faultplan is not None:
             self.faultplan.clear()
         pool = getattr(self, "_probe_pool", None)
@@ -1405,14 +974,11 @@ class TpuInferenceService(MultitenantService):
         # AFTER their engine's own stop-drain (the scoring loop keeps
         # consuming during the stop cascade) — resolve them unscored so
         # no consumed event is lost
-        for (fam, _sl), lanes in self._lanes.items():
-            for key in list(lanes):
-                lane = lanes.pop(key)
-                if lane.count:
-                    _i, _v, seqs, rows = lane.pop(lane.count)
-                    await self._resolve_rows(
-                        seqs, rows, None, publish_nowait=True, family=fam
-                    )
+        for s in self._slices.values():
+            for _d, _i, _v, seqs, rows in s.drain_lanes():
+                await self._resolve_rows(
+                    seqs, rows, None, publish_nowait=True, family=s.family
+                )
         for fence in list(self._fences.values()):
             for ring in fence.stash.values():
                 if ring.count:
@@ -1427,12 +993,14 @@ class TpuInferenceService(MultitenantService):
         # unscored-resolve obligation on the train lane. Zero the depth
         # gauges as the rings go: a stopped service must not report
         # phantom pending training rows forever.
-        for fam in {f for (f, _sl) in self._train_lanes}:
-            self.metrics.gauge(
-                "tpu_inference_train_rows", family=fam
-            ).set(0)
-        self._train_lanes.clear()
-        self._last_scores.clear()  # drop any pinned device score memory
+        for fam in {s.family for s in self._slices.values() if s.train_lanes}:
+            self.metrics.gauge("tpu_inference_train_rows", family=fam).set(0)
+        # THE death of every slice, with the scorer, the device scores
+        # and the staging sets it pins: a service started again builds
+        # them anew in ``scorer_for_slice``
+        if any(s.staging for s in self._slices.values()):
+            self.metrics.gauge("tpu_inference_staging_sets").set(0)
+        self._slices.clear()
         if self.mm.n_devices > 1:
             # cardinality guard (the drop_labeled pattern): a stopped
             # service's device-labeled children must not be exported
@@ -1467,9 +1035,9 @@ class TpuInferenceService(MultitenantService):
         batch.t_lane = t_lane
         family = engine.config.model
         sl = engine.placement.shard
-        # setdefault: a GHOST (paged-out) tenant's slice may not have
-        # served yet — its rows only ever park behind the paging fence
-        lanes = self._lanes.setdefault((family, sl), {})
+        # a placement names a slice that exists: the engine's start (a
+        # ghost's too) and every move build it before rows can arrive
+        s = self._slices[(family, sl)]
         slot = engine.placement.slot
         fence = self._fences.get(engine.tenant)
         if self.pager is not None:
@@ -1522,6 +1090,13 @@ class TpuInferenceService(MultitenantService):
             await self._publish_batch(seq)
             return
         parked = 0
+        # a new lane is sized to the lane watermark (2× max_batch split
+        # across data shards) so steady state never reallocates
+        lane_cap = max(
+            4096,
+            2 * engine.config.microbatch.max_batch
+            // max(1, self.mm.n_data_shards),
+        )
         for d in range(self.mm.n_data_shards):
             sel = np.nonzero(dshards == d)[0]
             if sel.size == 0:
@@ -1533,20 +1108,11 @@ class TpuInferenceService(MultitenantService):
                 fence.park(d, locals_[sel], batch.values[sel], seq, sel)
                 parked += sel.size
                 continue
-            lane = lanes.get((slot, d))
-            if lane is None:
-                # sized to the lane watermark (2× max_batch split across
-                # data shards) so steady state never reallocates
-                lane = lanes[(slot, d)] = _LaneRing(
-                    max(
-                        4096,
-                        2 * engine.config.microbatch.max_batch
-                        // max(1, self.mm.n_data_shards),
-                    )
-                )
             # sel doubles as the row indices inside the batch; seq
             # broadcasts — rows land in the ring right here, at enqueue
-            lane.push(locals_[sel], batch.values[sel], seq, sel)
+            s.lane(slot, d, lane_cap).push(
+                locals_[sel], batch.values[sel], seq, sel
+            )
         if fence is not None:
             if parked:
                 self.metrics.counter("tpu_inference.fenced_rows").inc(parked)
@@ -1557,8 +1123,7 @@ class TpuInferenceService(MultitenantService):
                     # mark keys it out of the hot-path latency columns
                     batch.mark("paged")
             return
-        if (family, sl) not in self._first_pending_ts:
-            self._first_pending_ts[(family, sl)] = time.monotonic()
+        s.mark_pending()
 
     # -- score write-back -------------------------------------------------
     async def _resolve_rows(
@@ -1744,39 +1309,8 @@ class TpuInferenceService(MultitenantService):
         self.metrics.meter("tpu_inference.scored").mark(batch.n)
 
     # -- flush -----------------------------------------------------------
-    def _pick_bucket(self, need: int, buckets: Tuple[int, ...], max_batch: int) -> int:
-        for b in buckets:
-            if need <= b:
-                return min(b, max_batch)
-        return max_batch
-
-    def _staging_set(
-        self, family: str, sl: int, scorer, b_lane: int
-    ) -> _StagingSet:
-        """Next rotating staging set for (family, slice, bucket) —
-        created once, reused for the lifetime of the shape. Per-slice
-        pools are what let slices pack+stage concurrently instead of
-        funneling through one rotation."""
-        key = (family, sl, b_lane)
-        rot = self._staging.get(key)
-        if rot is None:
-            rot = self._staging[key] = [
-                0, [_StagingSet(scorer, b_lane) for _ in range(self.staging_slots)],
-            ]
-            # bounded-pool observability (check_queues): total resident
-            # staging sets across every (family, slice, bucket) rotation
-            self.metrics.gauge("tpu_inference_staging_sets").set(
-                sum(len(r[1]) for r in self._staging.values())
-            )
-        idx, sets = rot
-        rot[0] = (idx + 1) % len(sets)
-        st = sets[idx]
-        st.ensure_reusable(self.metrics)
-        return st
-
     async def _flush_slice(
-        self, engine_cfgs: Dict[int, TenantEngineConfig], family: str,
-        sl: int,
+        self, engine_cfgs: Dict[int, TenantEngineConfig], s: SliceRuntime,
     ) -> int:
         """Pack one (family, mesh-slice)'s lane rings into the slice's
         reusable staging set, stage the buffers to the SLICE's devices
@@ -1785,9 +1319,8 @@ class TpuInferenceService(MultitenantService):
         score materialization to the per-device reap queue. Slices flush
         independently: no cross-slice collectives, no shared staging
         pool, no shared completion stream."""
-        scorer = self.scorers[(family, sl)]
-        lanes = self._lanes[(family, sl)]
-        if family in self._parked or (family, sl) in self._quarantined:
+        family, sl, scorer, lanes = s.family, s.sl, s.scorer, s.lanes
+        if family in self._parked or s.quarantine is not None:
             # degraded mode: resolve pending rows unscored so events keep
             # flowing to persistence/rules while the scorer is parked —
             # or while THIS slice is quarantined and its tenants could
@@ -1797,32 +1330,17 @@ class TpuInferenceService(MultitenantService):
                 self.metrics.counter(
                     "tpu_inference.quarantine_passthrough"
                 ).inc()
-            drained = 0
-            for key in list(lanes):
-                lane = lanes.pop(key)
-                if lane.count:
-                    _i, _v, seqs, rows = lane.pop(lane.count)
-                    await self._resolve_rows(seqs, rows, None, family=family)
-                    drained += len(seqs)
-            self._first_pending_ts.pop((family, sl), None)
-            return drained
+            return await self._pass_unscored(s)
         if not any(l.count for l in lanes.values()):
-            self._first_pending_ts.pop((family, sl), None)
+            s.first_pending_ts = None
             return 0
-        breaker = self.breakers.get((family, sl))
-        if breaker is not None and not breaker.allow():
+        breaker = s.breaker
+        if not breaker.allow():
             # breaker OPEN: stop hammering the scorer — resolve pending
             # rows unscored (degraded, never lost) until the half-open
             # schedule lets a trial flush probe recovery. Trial failures
             # keep feeding the failover→park escalation below.
-            drained = 0
-            for key in list(lanes):
-                lane = lanes.pop(key)
-                if lane.count:
-                    _i, _v, seqs, rows = lane.pop(lane.count)
-                    await self._resolve_rows(seqs, rows, None, family=family)
-                    drained += len(seqs)
-            self._first_pending_ts.pop((family, sl), None)
+            drained = await self._pass_unscored(s)
             self.metrics.counter("tpu_inference.breaker_short_circuits").inc()
             return drained
         any_cfg = next(iter(engine_cfgs.values()))
@@ -1834,7 +1352,7 @@ class TpuInferenceService(MultitenantService):
         flush_id = self._next_flush_id
         self._next_flush_id += 1
         t_asked = time.perf_counter()
-        sem = self._inflight_sem((family, sl))
+        sem = s.permits
         if sem.locked():
             # all of THIS slice's completion slots busy: the flush
             # backpressures here, where depth is the deliver_inflight
@@ -1850,7 +1368,7 @@ class TpuInferenceService(MultitenantService):
         # accumulated while every slot was busy should ride out in ONE
         # bigger flush, not drain at the stale pre-wait size
         pending_max = max((l.count for l in lanes.values()), default=0)
-        b_lane = self._pick_bucket(pending_max, tuple(mb.buckets), mb.max_batch)
+        b_lane = s.pick_bucket(pending_max, tuple(mb.buckets), mb.max_batch)
         # wire-thin stacked batch: compact id/value dtypes + one count per
         # (slot, data-shard) lane instead of a bool mask — rows fill each
         # lane from the front, so validity is derivable on device (see
@@ -1860,7 +1378,7 @@ class TpuInferenceService(MultitenantService):
         # Python lists (tools/check_hotpath.py enforces this stays true).
         with sw("flush_assembly", flush_id=flush_id):
             t_asm = time.perf_counter()
-            st = self._staging_set(family, sl, scorer, b_lane)
+            st = s.staging_set(b_lane)
             ids, vals, counts = st.ids, st.vals, st.counts
             counts[:] = 0
             take_total = 0
@@ -1893,14 +1411,10 @@ class TpuInferenceService(MultitenantService):
             self.metrics.gauge("tpu_inference_lane_rows", family=family).set(
                 depth_left
             )
-            if depth_left:
-                self._first_pending_ts[(family, sl)] = time.monotonic()
-            else:
-                self._first_pending_ts.pop((family, sl), None)
+            s.first_pending_ts = time.monotonic() if depth_left else None
             if moved == 0:
                 sem.release()
-                if breaker is not None:
-                    breaker.release_trial()  # allowed, but no call was made
+                breaker.release_trial()  # allowed, but no call was made
                 return 0
             t_assembled = time.perf_counter()
             assembly_s = t_assembled - t_asm
@@ -1909,8 +1423,7 @@ class TpuInferenceService(MultitenantService):
             ).record(assembly_s)
 
         taken = (slots_cat, cols_cat, seqs_cat, rows_cat)
-        shape_key = (family, sl, b_lane)
-        compiling = shape_key not in self._seen_shapes
+        compiling = b_lane not in s.seen_shapes
         h2d_stage_s: Optional[float] = None  # for the fault record when
         dispatch_s: Optional[float] = None   # the try below dies early
         rec: Optional[dict] = None           # blackbox record, once made
@@ -1920,13 +1433,13 @@ class TpuInferenceService(MultitenantService):
             # dispatch output is not yet ready ⇔ this staging copy rides
             # under genuinely in-flight device compute (a pending deliver
             # task alone could just be awaiting its publish).
-            prev_scores = self._last_scores.get((family, sl))
+            prev_scores = s.last_scores
             try:
                 overlapped = (
                     prev_scores is not None and not prev_scores.is_ready()
                 )
             except Exception:  # noqa: BLE001 - monkeypatched scorers
-                overlapped = bool(any(self._reap.values()))
+                overlapped = any(x.reap for x in self._slices.values())
             t_stage = time.perf_counter()
             stage = getattr(scorer, "stage_inputs", None)
             if stage is not None:
@@ -2014,7 +1527,7 @@ class TpuInferenceService(MultitenantService):
                 # a counter bump here is how a mid-traffic recompile (new
                 # bucket, missed prewarm) becomes attributable instead of
                 # an anonymous p99 cliff
-                self._seen_shapes.add(shape_key)
+                s.seen_shapes.add(b_lane)
                 self.metrics.counter("tpu_inference.compiles").inc()
                 self.metrics.counter(
                     "tpu_inference_compiles", family=family,
@@ -2034,14 +1547,14 @@ class TpuInferenceService(MultitenantService):
             # behind). In flight as the ``inflight`` interval has it:
             # dispatched and not landed — a landed head whose resolve is
             # still publishing holds a queue slot, not the device
-            ahead = self._in_flight((family, sl))
+            ahead = s.in_flight()
             self.metrics.counter("tpu_inference.inflight_depth_sum").inc(
                 len(ahead)
             )
             if any(p.lane == "serve" for p in ahead):
                 # joined a device still busy with a serve flush: the
                 # policy let it through because a lane had reached the
-                # smallest bucket (``_flush_held``)
+                # smallest bucket (``SliceRuntime.held``)
                 self.metrics.counter("tpu_inference.flush_pipelined").inc()
             # lane wait, per carried batch: its enqueue → the flush asked
             # for its permit
@@ -2130,7 +1643,7 @@ class TpuInferenceService(MultitenantService):
             # overlap probe for the NEXT flush — now holds the gathered
             # rows (a few KB), not a full flush of plane memory; the
             # reaper drops it when the family goes idle
-            self._last_scores[(family, sl)] = scores_dev
+            s.last_scores = scores_dev
             try:
                 # start the d2h copy NOW: it rides under the next
                 # flush's compute and is (ideally) done by the time the
@@ -2142,8 +1655,7 @@ class TpuInferenceService(MultitenantService):
             # not strand popped rows or kill the loop; repeated failures
             # trigger shard failover
             self._record_error("step", exc)
-            if breaker is not None:
-                breaker.record_failure()
+            breaker.record_failure()
             err_rec = None
             if self.flightrec is not None:
                 if rec is not None:
@@ -2204,11 +1716,7 @@ class TpuInferenceService(MultitenantService):
                     family, None, taken, moved, False, 0, 0, poisoned=True,
                     rec=err_rec, sl=sl,
                 ))
-            if (
-                self.flightrec is not None
-                and breaker is not None
-                and breaker.state == "open"
-            ):
+            if self.flightrec is not None and breaker.state == "open":
                 # breaker TRIP: freeze the blackbox NOW, with the
                 # faulting flush's record (timings + trace_id) already
                 # in the ring it snapshots
@@ -2216,7 +1724,7 @@ class TpuInferenceService(MultitenantService):
                     f"breaker:{family}", family=family,
                     trace_id=err_rec.get("trace_id") if err_rec else None,
                 )
-            await self._note_scorer_error(family, sl)
+            await self._note_scorer_error(s)
             if retry_rows is not None:
                 # the rows leave through the retry dispatch's OWN permit
                 # (possibly on another slice) — this flush's permit goes
@@ -2230,7 +1738,7 @@ class TpuInferenceService(MultitenantService):
                 await self._retry_poison(family, sl, retry_rows, taken, exc)
             return moved
         try:
-            self._train_tick(family, sl, scorer, engine_cfgs)
+            self._train_tick(s, engine_cfgs)
         except Exception as exc:  # noqa: BLE001 - a training fault must not
             # leak the inflight permit or strand the step's rows (the
             # scoring step itself succeeded; delivery proceeds below)
@@ -2246,10 +1754,11 @@ class TpuInferenceService(MultitenantService):
         pf.slot_override = slot_override
         # flush supervision: the completion deadline the reaper races
         # (family p99-derived, floored by flush_deadline_ms; None = off)
-        dl = self._flush_deadline_s(family, sl)
+        ft = self._family_ft(family)
+        dl = s.flush_deadline_s(ft)
         if dl is not None:
             pf.deadline = pf.t_dispatch + dl
-            if self._family_ft(family).poison_retry:
+            if ft.poison_retry:
                 # staged-byte copies for the one-shot poison retry: a
                 # TIMED-OUT flush needs them long after the staging set
                 # recycled — the price of retry-with-identical-bytes.
@@ -2264,6 +1773,15 @@ class TpuInferenceService(MultitenantService):
             )
         self._reap_enqueue(pf)
         return moved
+
+    async def _pass_unscored(self, s: SliceRuntime) -> int:
+        """Degraded mode: the slice's pending rows leave unscored."""
+        drained = 0
+        for _d, _i, _v, seqs, rows in s.drain_lanes():
+            await self._resolve_rows(seqs, rows, None, family=s.family)
+            drained += len(seqs)
+        s.first_pending_ts = None
+        return drained
 
     FLUSH_RECORDS = 512  # newest flush records kept for the ledger
 
@@ -2319,12 +1837,10 @@ class TpuInferenceService(MultitenantService):
 
     def _reap_enqueue(self, pf: _PendingFlush) -> None:
         """Queue one pending flush (normal or poisoned) for the reaper:
-        the single definition of the enqueue protocol — FIFO append,
-        gauge refresh, reaper wake."""
-        q = self._reap.get(pf.key)
-        if q is None:
-            q = self._reap[pf.key] = _ReapQueue()
-        q.append(pf)
+        the single definition of the enqueue protocol — FIFO append on
+        its slice, gauge refresh, reaper wake (the gauge and the reaper
+        span slices, which is why this is the service's)."""
+        self._slices[pf.key].reap.append(pf)
         self._deliver_gauge()
         self._reap_event.set()
 
@@ -2338,7 +1854,7 @@ class TpuInferenceService(MultitenantService):
         other in-flight batches, so its rows take an ORDERED fallback
         instead."""
         out: set = set()
-        for p in self._reap.get((family, sl), ()):
+        for p in self._slices[(family, sl)].reap:
             if p is exclude or p.resolved or p.lane != "serve":
                 continue
             for s in np.unique(p.taken[2]).tolist():
@@ -2415,7 +1931,10 @@ class TpuInferenceService(MultitenantService):
                 )
                 continue
             p = engine.placement
-            if (family, p.shard) in self._quarantined or tenant in busy:
+            if (
+                self._slices[(family, p.shard)].quarantine is not None
+                or tenant in busy
+            ):
                 # capacity-stranded (retrying on a known-sick slice is
                 # pointless) or FIFO-guarded (other in-flight batches
                 # of this tenant on the first slice): ordered unscored
@@ -2467,10 +1986,9 @@ class TpuInferenceService(MultitenantService):
         may be the last holder of."""
         p = engine.placement
         sl2, slot2 = p.shard, p.slot
+        s2 = self._slices[(family, sl2)]
+        scorer = s2.scorer
         try:
-            scorer = self.scorers.get((family, sl2))
-            if scorer is None:
-                scorer = self.scorer_for_slice(family, sl2, engine.config)
             mb = engine.config.microbatch
             # stable per-dshard regrouping keeps each lane's rows in
             # their original FIFO order (= the device gather's pack
@@ -2481,7 +1999,7 @@ class TpuInferenceService(MultitenantService):
             lane_counts = np.bincount(
                 dsh, minlength=self.mm.n_data_shards
             )
-            b_lane = self._pick_bucket(
+            b_lane = s2.pick_bucket(
                 int(lane_counts.max()), tuple(mb.buckets), mb.max_batch
             )
             t, d = scorer.n_slots, self.mm.n_data_shards
@@ -2505,10 +2023,10 @@ class TpuInferenceService(MultitenantService):
             slots2 = np.full((len(seqs),), slot2, np.int32)
             taken2 = (slots2, cols, seqs, rows)
         except Exception as exc2:  # noqa: BLE001 - retry infra failed
-            # BEFORE dispatch (scorer build on a degraded fleet /
-            # staging alloc): chip-attributed, never poison — and the
-            # rows must still resolve (unscored, permit-less, through
-            # the retry slice's FIFO) or the zero-loss invariant breaks
+            # BEFORE dispatch (staging alloc): chip-attributed, never
+            # poison — and the rows must still resolve (unscored,
+            # permit-less, through the retry slice's FIFO) or the
+            # zero-loss invariant breaks
             self._record_error("poison-retry-setup", exc2)
             for s in np.unique(seqs).tolist():
                 self._retried_seqs.discard(int(s))
@@ -2522,9 +2040,9 @@ class TpuInferenceService(MultitenantService):
             )
             pf2.owns_permit = False
             self._reap_enqueue(pf2)
-            await self._note_scorer_error(family, sl2)
+            await self._note_scorer_error(s2)
             return
-        sem = self._inflight_sem((family, sl2))
+        sem = s2.permits
         own_permit = not (inline and sl2 == sl_first)
         if own_permit:
             await sem.acquire()
@@ -2541,9 +2059,8 @@ class TpuInferenceService(MultitenantService):
                 # selector would race other tenants' regular flushes on
                 # the retry slice for the fault budget)
                 self.faultplan.maybe_raise(family, sl2, "retry")
-            shape_key = (family, sl2, b_lane)
-            if shape_key not in self._seen_shapes:
-                self._seen_shapes.add(shape_key)
+            if b_lane not in s2.seen_shapes:
+                s2.seen_shapes.add(b_lane)
                 self.metrics.counter("tpu_inference.compiles").inc()
             scores_dev = scorer.step_counts(*staged)
             gathered = False
@@ -2580,7 +2097,7 @@ class TpuInferenceService(MultitenantService):
             pf.owns_permit = own_permit
             if not gathered:
                 pf.slot_override = slot2
-            dl = self._flush_deadline_s(family, sl2)
+            dl = s2.flush_deadline_s(self._family_ft(family))
             if dl is not None:
                 pf.deadline = pf.t_dispatch + dl
             if not hasattr(scores_dev, "copy_to_host_async"):
@@ -2602,9 +2119,7 @@ class TpuInferenceService(MultitenantService):
                 # breaker/failover exactly like an un-retried fault
                 for s in np.unique(seqs).tolist():
                     self._retried_seqs.discard(int(s))
-                breaker = self.breakers.get((family, sl2))
-                if breaker is not None:
-                    breaker.record_failure()
+                s2.breaker.record_failure()
                 pf2 = _PendingFlush(
                     family, None, taken2, len(seqs), False, 0, 0,
                     poisoned=True, sl=sl2,
@@ -2612,7 +2127,7 @@ class TpuInferenceService(MultitenantService):
                 pf2.owns_permit = own_permit
                 self._reap_enqueue(pf2)
                 enqueued = True  # the poisoned entry inherits the permit
-                await self._note_scorer_error(family, sl2)
+                await self._note_scorer_error(s2)
         finally:
             if own_permit and not enqueued:
                 sem.release()
@@ -2628,7 +2143,7 @@ class TpuInferenceService(MultitenantService):
         return (
             sl_retry != sl_first
             and family not in self._parked
-            and (family, sl_retry) not in self._quarantined
+            and self._slices[(family, sl_retry)].quarantine is None
         )
 
     async def _eject_poison(
@@ -2673,7 +2188,7 @@ class TpuInferenceService(MultitenantService):
         return ejected
 
     # -- auto-failover ----------------------------------------------------
-    async def _note_scorer_error(self, family: str, sl: int = 0) -> None:
+    async def _note_scorer_error(self, s: SliceRuntime) -> None:
         """Count consecutive scorer failures per (family, mesh-slice);
         at the threshold, rebuild the SICK SLICE's scorer runtime (a
         failed dispatch can invalidate the donated state buffer) and
@@ -2683,11 +2198,11 @@ class TpuInferenceService(MultitenantService):
         serving untouched. Repeated rounds without a healthy delivery
         PARK the family: events pass through unscored rather than
         churning failovers forever — degraded, never lost."""
-        n = self._consec_errors.get((family, sl), 0) + 1
-        self._consec_errors[(family, sl)] = n
-        if n < self.failover_threshold or family in self._parked:
+        family = s.family
+        s.consec_errors += 1
+        if s.consec_errors < self.failover_threshold or family in self._parked:
             return
-        self._consec_errors[(family, sl)] = 0
+        s.consec_errors = 0
         rounds = self._failover_rounds.get(family, 0) + 1
         self._failover_rounds[family] = rounds
         if rounds > self.max_failover_rounds:
@@ -2701,48 +2216,35 @@ class TpuInferenceService(MultitenantService):
             self.metrics.counter("tpu_inference.parked").inc()
             return
         # may reference dead buffers
-        self._last_scores.pop((family, sl), None)
-        scorer = self.scorers.get((family, sl))
-        if scorer is not None:
-            try:
-                scorer.rebuild_runtime()
-                # the rebuilt jit cache recompiles every shape: reset the
-                # slice's seen-shape set so the compile counter stays true
-                self._seen_shapes = {
-                    k for k in self._seen_shapes if k[:2] != (family, sl)
-                }
-            except Exception as exc:  # noqa: BLE001 - device may be gone
-                self._record_error("rebuild", exc)
+        s.last_scores = None
+        try:
+            s.scorer.rebuild_runtime()
+            # the rebuilt jit cache recompiles every shape: reset the
+            # slice's seen-shape set so the compile counter stays true
+            s.seen_shapes.clear()
+        except Exception as exc:  # noqa: BLE001 - device may be gone
+            self._record_error("rebuild", exc)
         # SUSPECT: quarantine the slice (router avoids it, tenants fail
         # over off it, probation probes re-admit it once it heals) —
         # failed-over tenants RETURN to a healed slice instead of the
         # pre-supervision one-way door
-        await self._quarantine_slice(family, sl, reason="scorer-errors")
+        await self._quarantine_slice(s, reason="scorer-errors")
 
     # -- quarantine & probation (slice re-adoption) ------------------------
-    async def _quarantine_slice(
-        self, family: str, sl: int, reason: str
-    ) -> None:
+    async def _quarantine_slice(self, s: SliceRuntime, reason: str) -> None:
         """Mark one (family, mesh-slice) SUSPECT: the router routes
         around it, its tenants fail over to healthy slices (those that
         can't — fleet at capacity — degrade to unscored pass-through on
         the quarantined slice), and a background probe re-dispatches
         synthetic flushes until ``probation_probes`` consecutive
         landings re-admit it. Idempotent per (family, slice)."""
-        key = (family, sl)
-        if key in self._quarantined:
+        family, sl = s.family, s.sl
+        if not s.enter_quarantine(
+            reason, self._family_ft(family).probe_interval_s
+        ):
             return
-        ft = self._family_ft(family)
-        self._quarantined[key] = {
-            "reason": reason,
-            "since_ms": time.time() * 1000.0,
-            "ok_probes": 0,
-            "next_probe": time.monotonic() + ft.probe_interval_s,
-        }
         self.metrics.counter("tpu_inference.quarantined").inc()
-        self.metrics.gauge("tpu_inference_quarantined_slices").set(
-            len(self._quarantined)
-        )
+        self._quarantine_gauge()
         self.router.quarantine(family, sl)
         if self.flightrec is not None:
             self.flightrec.record(
@@ -2779,7 +2281,7 @@ class TpuInferenceService(MultitenantService):
         if stranded and not moved:
             healthy = [
                 s2 for s2 in range(self.router.n_shards)
-                if (family, s2) in self.scorers
+                if (family, s2) in self._slices
                 and s2 not in self.router.quarantined(family)
             ]
             if not healthy:
@@ -2802,17 +2304,12 @@ class TpuInferenceService(MultitenantService):
         probation — the operator-lifecycle escape hatch (engine
         (re)start), mirroring the breaker resets it rides beside."""
         n = 0
-        for key in [k for k in self._quarantined if k[0] == family]:
-            self._quarantined.pop(key, None)
-            self.router.readmit(family, key[1])
-            task = self._probing.pop(key, None)
-            if task is not None:
-                task.cancel()
-            n += 1
+        for s in self._family_slices(family):
+            if s.clear_quarantine():
+                self.router.readmit(family, s.sl)
+                n += 1
         if n:
-            self.metrics.gauge("tpu_inference_quarantined_slices").set(
-                len(self._quarantined)
-            )
+            self._quarantine_gauge()
         return n
 
     async def host_probe(self, n: int = 1) -> int:
@@ -2829,10 +2326,10 @@ class TpuInferenceService(MultitenantService):
         nothing to be wedged."""
         ok = 0
         for _ in range(max(1, int(n))):
-            landed = not self.scorers
-            for (family, sl), scorer in sorted(self.scorers.items()):
+            landed = not self._slices
+            for _key, s in sorted(self._slices.items()):
                 try:
-                    landed = await self._dispatch_probe(scorer, family, sl)
+                    landed = await self._dispatch_probe(s)
                 except asyncio.CancelledError:
                     raise
                 except Exception as exc:  # noqa: BLE001 - a probe fault
@@ -2854,55 +2351,51 @@ class TpuInferenceService(MultitenantService):
         probes for quarantined slices whose probe interval elapsed.
         Probes defer while live traffic is under overload pressure —
         recovery bookkeeping never contends with shedding traffic."""
-        if not self._quarantined:
-            return
-        now = time.monotonic()
-        for key, qs in list(self._quarantined.items()):
-            if key in self._probing or now < qs["next_probe"]:
+        for s in self._slices.values():
+            qs = s.quarantine
+            if qs is None or s.probing is not None:
+                continue
+            now = time.monotonic()
+            if now < qs["next_probe"]:
                 continue
             if self.overload is not None and self.overload.any_pressure():
-                qs["next_probe"] = now + self._family_ft(
-                    key[0]
-                ).probe_interval_s
+                ft = self._family_ft(s.family)
+                qs["next_probe"] = now + ft.probe_interval_s
                 continue
             task = asyncio.get_running_loop().create_task(
-                self._probe_slice(key)
+                self._probe_slice(s)
             )
-            self._probing[key] = task
+            s.probing = task
 
-            def _done(t: asyncio.Task, k=key) -> None:
-                if self._probing.get(k) is t:
-                    del self._probing[k]
+            def _done(t: asyncio.Task, s: SliceRuntime = s) -> None:
+                if s.probing is t:
+                    s.probing = None
                 if not t.cancelled() and t.exception() is not None:
                     self._record_error("probe", t.exception())
 
             task.add_done_callback(_done)
 
-    async def _probe_slice(self, key: Tuple[str, int]) -> None:
+    async def _probe_slice(self, s: SliceRuntime) -> None:
         """One probation probe: a synthetic prewarmed-shape flush on the
         quarantined slice, supervised by its own deadline. N consecutive
         landings re-admit the slice; any failure restarts the count."""
-        family, sl = key
-        ft = self._family_ft(family)
-        scorer = self.scorers.get(key)
-        ok = False
-        if scorer is not None:
-            try:
-                ok = await self._dispatch_probe(scorer, family, sl)
-            except asyncio.CancelledError:
-                raise
-            except Exception as exc:  # noqa: BLE001 - a probe fault IS
-                # the verdict, never a crash
-                self._record_error("probe", exc)
-                ok = False
-        qs = self._quarantined.get(key)
+        ft = self._family_ft(s.family)
+        try:
+            ok = await self._dispatch_probe(s)
+        except asyncio.CancelledError:
+            raise
+        except Exception as exc:  # noqa: BLE001 - a probe fault IS
+            # the verdict, never a crash
+            self._record_error("probe", exc)
+            ok = False
+        qs = s.quarantine
         if qs is None:
             return  # re-admitted/cleared while the probe was in flight
         if ok:
             qs["ok_probes"] += 1
             self.metrics.counter("tpu_inference.probe_flushes").inc()
             if qs["ok_probes"] >= max(1, ft.probation_probes):
-                await self._readmit_slice(family, sl)
+                await self._readmit_slice(s)
                 return
         else:
             qs["ok_probes"] = 0
@@ -2927,9 +2420,7 @@ class TpuInferenceService(MultitenantService):
             )
         return pool
 
-    async def _dispatch_probe(
-        self, scorer, family: str, sl: int
-    ) -> bool:
+    async def _dispatch_probe(self, s: SliceRuntime) -> bool:
         """Run one zero-row synthetic flush through the REAL wire
         (staging → step → gather → materialization) with its own
         deadline, entirely ON the probe thread — a quarantined slice's
@@ -2941,13 +2432,11 @@ class TpuInferenceService(MultitenantService):
         tenant would see."""
         import numpy as _np
 
+        family, sl, scorer = s.family, s.sl, s.scorer
         t, d = scorer.n_slots, scorer.mm.n_data_shards
         # smallest shape the slice already compiled; a wiped cache
         # (failover rebuild) recompiles on the probe thread
-        seen = sorted(
-            k[2] for k in self._seen_shapes
-            if k[:2] == (family, sl) and isinstance(k[2], int)
-        )
+        seen = sorted(b for b in s.seen_shapes if isinstance(b, int))
         b = seen[0] if seen else 64
         ids = _np.zeros((t, d * b), scorer.ids_np_dtype)
         vals = _np.zeros((t, d * b), scorer.vals_np_dtype)
@@ -2969,7 +2458,7 @@ class TpuInferenceService(MultitenantService):
                 out = plan.wrap(out, family, sl, "probe")
             return np.asarray(out)
 
-        deadline = self._flush_deadline_s(family, sl) or (
+        deadline = s.flush_deadline_s(self._family_ft(family)) or (
             self.deliver_drain_timeout_s
         )
         fut = asyncio.get_running_loop().run_in_executor(
@@ -2984,23 +2473,17 @@ class TpuInferenceService(MultitenantService):
             return False
         return True
 
-    async def _readmit_slice(self, family: str, sl: int) -> None:
+    async def _readmit_slice(self, s: SliceRuntime) -> None:
         """Probation passed: the slice rejoins the router, its breaker
         and escalation history clear, the family unparks, and tenants
         REBALANCE BACK through the same FIFO-preserving fences every
         slice move rides."""
-        key = (family, sl)
-        self._quarantined.pop(key, None)
-        self.metrics.gauge("tpu_inference_quarantined_slices").set(
-            len(self._quarantined)
-        )
+        family, sl = s.family, s.sl
+        s.readmit()
+        self._quarantine_gauge()
         self.router.readmit(family, sl)
-        self._consec_errors.pop(key, None)
         self._failover_rounds.pop(family, None)
         self._parked.discard(family)
-        breaker = self.breakers.get(key)
-        if breaker is not None:
-            breaker.reset()
         self.metrics.counter("tpu_inference.readmitted").inc()
         if self.flightrec is not None:
             self.flightrec.record(
@@ -3042,39 +2525,26 @@ class TpuInferenceService(MultitenantService):
 
         tenant = engine.tenant
         family = engine.config.model
-        old_scorer = self.scorers.get((family, old_p.shard))
+        old = self._slices[(family, old_p.shard)]
         params = None
-        if old_scorer is not None:
-            try:  # live params may be unreachable on a sick slice
-                params = host_copy_params(old_scorer.slot_params(old_p.slot))
-            except Exception:  # noqa: BLE001
-                if self.checkpoints is not None:
-                    try:
-                        params = (
-                            await asyncio.get_running_loop().run_in_executor(
-                                None, self.checkpoints.load_params,
-                                tenant, family,
-                            )
-                        )
-                    except Exception as exc:  # noqa: BLE001
-                        self._record_error("failover-params", exc)
-            try:
-                old_scorer.reset_slot(old_p.slot)
-            except Exception as exc:  # noqa: BLE001 - slice may be dead
-                self._record_error("failover-reset", exc)
-        # the tenant's pending TRAIN rows stay keyed to the OLD
-        # (slot, data-shard): drop them (droppable history — the store
-        # re-feeds) or the next tenant placed on that slot would train
-        # on THIS tenant's replayed data; its cadence tick goes with it
-        # (a recycled slot must not inherit a mature tick either)
-        tl = self._train_lanes.get((family, old_p.shard))
-        if tl is not None:
-            for key in [k for k in tl if k[0] == old_p.slot]:
-                tl.pop(key)
-            self._train_rows_gauge(family, old_p.shard)
-        self._train_ticks.get((family, old_p.shard), {}).pop(
-            old_p.slot, None
-        )
+        try:  # live params may be unreachable on a sick slice
+            params = host_copy_params(old.scorer.slot_params(old_p.slot))
+        except Exception:  # noqa: BLE001
+            if self.checkpoints is not None:
+                try:
+                    params = await asyncio.get_running_loop().run_in_executor(
+                        None, self.checkpoints.load_params, tenant, family,
+                    )
+                except Exception as exc:  # noqa: BLE001
+                    self._record_error("failover-params", exc)
+        try:
+            old.scorer.reset_slot(old_p.slot)
+        except Exception as exc:  # noqa: BLE001 - slice may be dead
+            self._record_error("failover-reset", exc)
+        # the tenant's pending TRAIN rows and cadence tick stay keyed to
+        # the OLD (slot, data-shard): they go, or the slot's next tenant
+        # would train on THIS tenant's replayed data
+        self._forget_slot_training(old, old_p.slot)
         engine.placement = new_p
         new_scorer = self.scorer_for_slice(family, new_p.shard, engine.config)
         new_scorer.activate(
@@ -3107,30 +2577,22 @@ class TpuInferenceService(MultitenantService):
             # gates them — only the landing target changes
             fence.new_sl, fence.new_slot = new_p.shard, new_p.slot
             return
-        old_lanes = self._lanes.get((family, old_p.shard), {})
-        pending = list(self._reap.get((family, old_p.shard), ()))
+        old = self._slices[(family, old_p.shard)]
+        pending = list(old.reap)
         if old_p.shard == new_p.shard:
             # same-slice slot move: FIFO is already guaranteed by the
-            # single slice queue — re-key lanes in place
-            for d in range(self.mm.n_data_shards):
-                lane = old_lanes.pop((old_p.slot, d), None)
-                if lane is not None and lane.count:
-                    dst = old_lanes.get((new_p.slot, d))
-                    if dst is None:
-                        old_lanes[(new_p.slot, d)] = lane
-                    else:
-                        li, lv, ls, lr = lane.pop(lane.count)
-                        dst.push(li, lv, ls, lr)
+            # single slice queue — re-key the rows in place
+            for d, li, lv, ls, lr in old.drain_lanes(old_p.slot):
+                old.lane(new_p.slot, d, max(4096, len(ls))).push(
+                    li, lv, ls, lr
+                )
             return
         self.metrics.counter("tpu_inference.slice_moves").inc()
         fence = _SliceFence(
             tenant, family, pending, new_p.shard, new_p.slot
         )
-        for d in range(self.mm.n_data_shards):
-            lane = old_lanes.pop((old_p.slot, d), None)
-            if lane is not None and lane.count:
-                li, lv, ls, lr = lane.pop(lane.count)
-                fence.park(d, li, lv, ls, lr)
+        for d, li, lv, ls, lr in old.drain_lanes(old_p.slot):
+            fence.park(d, li, lv, ls, lr)
         if not pending and not fence.depth():
             return  # nothing in flight, nothing parked — no fence needed
         self._fences[tenant] = fence
@@ -3151,25 +2613,18 @@ class TpuInferenceService(MultitenantService):
             if not fence.ready():
                 continue
             del self._fences[tenant]
-            lanes = self._lanes.get((fence.family, fence.new_sl))
-            if lanes is None:
-                lanes = self._lanes[(fence.family, fence.new_sl)] = {}
+            s = self._slices[(fence.family, fence.new_sl)]
             moved = 0
             for d, ring in sorted(fence.stash.items()):
                 if not ring.count:
                     continue
                 li, lv, ls, lr = ring.pop(ring.count)
-                dst = lanes.get((fence.new_slot, d))
-                if dst is None:
-                    dst = lanes[(fence.new_slot, d)] = _LaneRing(
-                        max(64, ring.capacity)
-                    )
-                dst.push(li, lv, ls, lr)
+                s.lane(fence.new_slot, d, max(64, ring.capacity)).push(
+                    li, lv, ls, lr
+                )
                 moved += len(ls)
             if moved:
-                key = (fence.family, fence.new_sl)
-                if key not in self._first_pending_ts:
-                    self._first_pending_ts[key] = time.monotonic()
+                s.mark_pending()
         self.metrics.gauge("tpu_inference_fences").set(len(self._fences))
 
     async def apply_rebalance(self, family: Optional[str] = None) -> int:
@@ -3196,7 +2651,7 @@ class TpuInferenceService(MultitenantService):
         a real serving slice of the family (preferring healthy ones) so
         stream→data-shard routing and fence parking have a home, but no
         physical slot is held — a page-in claims one later."""
-        slices = sorted(s for (f, s) in self.scorers if f == family)
+        slices = sorted(s.sl for s in self._family_slices(family))
         avoid = self.router.quarantined(family)
         healthy = [s for s in slices if s not in avoid]
         shard = (healthy or slices or [0])[0]
@@ -3230,7 +2685,8 @@ class TpuInferenceService(MultitenantService):
         p = engine.placement
         tenant = engine.tenant
         family = engine.config.model
-        scorer = self.scorers[(family, p.shard)]
+        s = self._slices[(family, p.shard)]
+        scorer = s.scorer
         trainable = bool(engine.config.training.enabled)
         cached = self.pager.cache.get(tenant)
         if not trainable and cached is not None:
@@ -3248,17 +2704,11 @@ class TpuInferenceService(MultitenantService):
         # pending TRAIN rows are droppable history (the store re-feeds —
         # PR 12 round-4 rule), but COUNTED: a paging storm that starves
         # training must be visible
-        tl = self._train_lanes.get((family, p.shard))
-        if tl is not None:
-            dropped = 0
-            for key in [k for k in tl if k[0] == p.slot]:
-                dropped += tl.pop(key).count
-            if dropped:
-                self.metrics.counter(
-                    "tpu_paging.train_rows_dropped", family=family
-                ).inc(dropped)
-            self._train_rows_gauge(family, p.shard)
-        self._train_ticks.get((family, p.shard), {}).pop(p.slot, None)
+        dropped = self._forget_slot_training(s, p.slot)
+        if dropped:
+            self.metrics.counter(
+                "tpu_paging.train_rows_dropped", family=family
+            ).inc(dropped)
         # serve rows still pending re-park behind a paging fence, FIFO
         # behind the old slice's in-flight flushes — the same ordering
         # machinery as a failover move, targetless until the next
@@ -3266,26 +2716,21 @@ class TpuInferenceService(MultitenantService):
         fence = self._fences.get(tenant)
         if fence is None:
             fence = self._fences[tenant] = _SliceFence(
-                tenant, family,
-                list(self._reap.get((family, p.shard), ())), None, None,
+                tenant, family, list(s.reap), None, None,
             )
             self.metrics.gauge(
                 "tpu_inference_fences"
             ).set(len(self._fences))
         else:
             fence.new_sl, fence.new_slot = None, None
-        lanes = self._lanes.get((family, p.shard), {})
-        for d in range(self.mm.n_data_shards):
-            lane = lanes.pop((p.slot, d), None)
-            if lane is not None and lane.count:
-                li, lv, ls, lr = lane.pop(lane.count)
-                fence.park(d, li, lv, ls, lr)
-                # eviction raced these batches' rows: key them out of the
-                # hot-path latency columns like any fence-parked arrival
-                for seq in np.unique(ls):
-                    entry = self._batches.get(int(seq))
-                    if entry is not None and "paged" not in entry[0].trace:
-                        entry[0].mark("paged")
+        for d, li, lv, ls, lr in s.drain_lanes(p.slot):
+            fence.park(d, li, lv, ls, lr)
+            # eviction raced these batches' rows: key them out of the
+            # hot-path latency columns like any fence-parked arrival
+            for seq in np.unique(ls):
+                entry = self._batches.get(int(seq))
+                if entry is not None and "paged" not in entry[0].trace:
+                    entry[0].mark("paged")
         # score-health: free the slot binding WITHOUT touching the
         # frozen reference or PSI window history — they survive
         # residency gaps exactly like failover re-maps
@@ -3327,9 +2772,10 @@ class TpuInferenceService(MultitenantService):
         best = busy_best = None
         best_score = busy_score = -1.0
         for (fam, sl), pager in self.pager.pagers.items():
-            if fam != family or (fam, sl) in self._quarantined:
+            home = self._slices.get((fam, sl))
+            if fam != family or home is None or home.quarantine is not None:
                 continue
-            lanes = self._lanes.get((fam, sl), {})
+            lanes = home.lanes
             for tenant in pager.residents():
                 if tenant in pager.pinned or tenant in self._fences:
                     continue
@@ -3499,8 +2945,7 @@ class TpuInferenceService(MultitenantService):
         task.add_done_callback(_done)
 
     def _train_tick(
-        self, family: str, sl: int, scorer: ShardedScorer,
-        engine_cfgs: Dict[int, TenantEngineConfig],
+        self, s: SliceRuntime, engine_cfgs: Dict[int, TenantEngineConfig],
     ) -> int:
         """Per-flush training cadence bookkeeping, two regimes:
 
@@ -3523,6 +2968,7 @@ class TpuInferenceService(MultitenantService):
         }
         if not enabled:
             return 0
+        family, sl, scorer = s.family, s.sl, s.scorer
         if getattr(scorer.spec, "loss", None) is None:
             # a tenant opted into training on a family with no loss
             # contract: it would silently never train — surface it
@@ -3533,7 +2979,7 @@ class TpuInferenceService(MultitenantService):
         lane_on = bool(getattr(scorer, "train_lane", False))
         # per-TENANT cadence: each slot matures on its own every_n_flushes
         # (and trains at its own lr — see ShardedScorer.slot_lr)
-        ticks = self._train_ticks.setdefault((family, sl), {})
+        ticks = s.train_ticks
         mature = []
         for slot, tc in enabled.items():
             if lane_on and tc.train_lane:
@@ -3558,18 +3004,15 @@ class TpuInferenceService(MultitenantService):
                 raise
         mask = np.zeros((scorer.n_slots,), bool)
         mask[mature] = True
-        self.last_train_losses[(family, sl)] = scorer.train_resident(mask)
+        s.last_train_losses = scorer.train_resident(mask)
         self.metrics.counter("tpu_inference.train_steps").inc()
-        if (
-            getattr(scorer, "train_lane", False)
-            and self._lane_swap.get((family, sl), 0) > 0
-        ):
+        if getattr(scorer, "train_lane", False) and s.lane_swap > 0:
             # MIXED stack (inline + lane tenants): train_resident just
             # invalidated the shared sidecar, which publishes the lane
             # tenants' in-flight uncommitted weights to serving too —
             # that IS a commit, so it must arm the canary and count as
             # a swap instead of silently bypassing the swap contract
-            self._lane_swap[(family, sl)] = 0
+            s.lane_swap = 0
             scorer.arm_canary()
             self.metrics.counter(
                 "tpu_train_swaps_total", family=family
@@ -3597,7 +3040,7 @@ class TpuInferenceService(MultitenantService):
         return ov is None or not ov.under_pressure(tenant)
 
     async def _consume_train_feed(
-        self, tenant: str, engine: "TpuInferenceEngine"
+        self, tenant: str, engine: "TpuInferenceEngine", s: SliceRuntime
     ) -> None:
         """Low-priority intake from the tenant's replay-train-feed topic
         into the train lane rings. Bounded: past the lane watermark
@@ -3608,19 +3051,16 @@ class TpuInferenceService(MultitenantService):
         out the pressure. The feed topic is EXCLUDED from the overload
         credit signal (runtime.overload._tenant_lag), so a parked train
         backlog can never throttle the tenant's serve path."""
-        family = engine.config.model
-        sl = engine.placement.shard
-        scorer = self.scorers.get((family, sl))
-        if scorer is None or not getattr(scorer, "train_lane", False):
+        family = s.family
+        if not getattr(s.scorer, "train_lane", False):
             return
         if not self._train_admit(tenant):
             return
         pin = self._family_cfg.get(family, engine.config).training
         micro = max(1, int(getattr(pin, "replay_microbatch", 1024)))
-        tlanes = self._train_lanes.setdefault((family, sl), {})
         slot = engine.placement.slot
         depth = sum(
-            r.count for (s, _d), r in tlanes.items() if s == slot
+            r.count for (t, _d), r in s.train_lanes.items() if t == slot
         )
         if depth >= 2 * micro:
             self.metrics.counter(
@@ -3640,12 +3080,12 @@ class TpuInferenceService(MultitenantService):
             return  # stopped mid-consume: training rows are droppable
         for b in items:
             if isinstance(b, MeasurementBatch):
-                self._enqueue_train_batch(engine, b, tlanes)
-        self._train_rows_gauge(family, sl)
+                self._enqueue_train_batch(engine, b, s)
+        self._train_rows_gauge(family)
 
     def _enqueue_train_batch(
         self, engine: "TpuInferenceEngine", batch: MeasurementBatch,
-        tlanes: Dict[Tuple[int, int], _TrainLaneRing],
+        s: SliceRuntime,
     ) -> None:
         """Route one replayed batch's rows into the train lane rings —
         the train twin of ``_enqueue_batch``, minus every delivery
@@ -3666,30 +3106,37 @@ class TpuInferenceService(MultitenantService):
             sel = np.nonzero(dshards == d)[0]
             if sel.size == 0:
                 continue
-            lane = tlanes.get((slot, d))
-            if lane is None:
-                lane = tlanes[(slot, d)] = _TrainLaneRing(4096)
             # seq/row bookkeeping is vestigial on the train lane (rows
             # never resolve back into a batch) — seq broadcasts 0
-            lane.push(locals_[sel], batch.values[sel], 0, sel)
+            s.train_lane(slot, d).push(
+                locals_[sel], batch.values[sel], 0, sel
+            )
 
-    def _train_rows_gauge(self, family: str, _sl: int = 0) -> None:
+    def _train_rows_gauge(self, family: str) -> None:
         # the gauge is FAMILY-labeled, so it must sum every slice's
         # rings — a per-slice sum would let slices of one family
         # overwrite each other's depth (the last_train_losses keying
         # lesson from the multi-chip review, applied to the gauge)
         depth = sum(
             r.count
-            for (f, _s), lanes in self._train_lanes.items()
-            if f == family
-            for r in lanes.values()
+            for s in self._family_slices(family)
+            for r in s.train_lanes.values()
         )
         self.metrics.gauge("tpu_inference_train_rows", family=family).set(
             depth
         )
 
+    def _forget_slot_training(self, s: SliceRuntime, slot: int) -> int:
+        """``SliceRuntime.forget_slot_training``, and the family's depth
+        gauge after it where the slice had train rings at all."""
+        had_rings = bool(s.train_lanes)
+        dropped = s.forget_slot_training(slot)
+        if had_rings:
+            self._train_rows_gauge(s.family)
+        return dropped
+
     async def _train_lane_tick(
-        self, fam_cfgs: Dict[Tuple[str, int], Dict[int, TenantEngineConfig]]
+        self, fam_cfgs: Dict[SliceRuntime, Dict[int, TenantEngineConfig]]
     ) -> int:
         """One pass of the async low-priority train lane: for each
         (family, slice) whose scorer carries the fused lane, dispatch at
@@ -3702,12 +3149,12 @@ class TpuInferenceService(MultitenantService):
         as ``lane="train"``, so its completion, teardown drain, and
         queue-depth accounting are the serve path's own machinery."""
         steps = 0
-        for (family, sl), cfgs in fam_cfgs.items():
-            scorer = self.scorers.get((family, sl))
-            if scorer is None or not getattr(scorer, "train_lane", False):
+        for s, cfgs in fam_cfgs.items():
+            family, scorer = s.family, s.scorer
+            if not getattr(scorer, "train_lane", False):
                 continue
             lane_cfgs = {
-                s: c for s, c in cfgs.items()
+                t: c for t, c in cfgs.items()
                 if c.training.enabled and c.training.train_lane
             }
             if not lane_cfgs:
@@ -3723,7 +3170,7 @@ class TpuInferenceService(MultitenantService):
             ).training
             micro = max(1, int(getattr(pin, "replay_microbatch", 1024)))
             admitted = {
-                s: c for s, c in lane_cfgs.items()
+                t: c for t, c in lane_cfgs.items()
                 if self._train_admit(c.tenant)
             }
             throttled = len(lane_cfgs) - len(admitted)
@@ -3734,21 +3181,18 @@ class TpuInferenceService(MultitenantService):
                         reason="throttled",
                     ).inc(throttled)
                 continue
-            ticks = self._train_ticks.get((family, sl), {})
-            tlanes = self._train_lanes.get((family, sl), {})
             feed_rows = sum(
-                r.count for (s, _d), r in tlanes.items() if s in admitted
+                r.count for (t, _d), r in s.train_lanes.items()
+                if t in admitted
             )
             mature = [
-                s for s, c in admitted.items()
-                if ticks.get(s, 0) >= c.training.every_n_flushes
+                t for t, c in admitted.items()
+                if s.train_ticks.get(t, 0) >= c.training.every_n_flushes
             ]
             replay = feed_rows >= micro
             if not replay and not mature:
                 continue
-            if replay and mature and (
-                self._lane_last_source.get((family, sl)) == "replay"
-            ):
+            if replay and mature and s.lane_last_source == "replay":
                 # both sources pending: ALTERNATE. A long replay
                 # backfill holding feed_rows ≥ micro for hours must not
                 # starve a co-tenant's mature resident cadence (the
@@ -3761,9 +3205,8 @@ class TpuInferenceService(MultitenantService):
                     "tpu_train_skipped_total", family=family,
                     reason="throttled",
                 ).inc(throttled)
-            sem = self._inflight_sem((family, sl))
-            q = self._reap.get((family, sl))
-            if sem.locked() or (q and any(p.lane != "train" for p in q)):
+            q = s.reap
+            if s.permits.locked() or any(p.lane != "train" for p in q):
                 # the slice is busy SERVING — in-flight flushes hold the
                 # window (or every permit): training yields and waits
                 # for a genuinely idle gap. "Idle headroom" is literal:
@@ -3783,12 +3226,12 @@ class TpuInferenceService(MultitenantService):
                 # signal operators read as serve pressure
                 continue
             steps += await self._dispatch_train(
-                family, sl, scorer, admitted, mature, replay, pin,
+                s, admitted, mature, replay, pin,
             )
         return steps
 
     def _pack_train(
-        self, family: str, sl: int, scorer, admitted: Dict[int, object],
+        self, s: SliceRuntime, admitted: Dict[int, object],
     ) -> Tuple[int, List[int]]:
         """Pack the admitted slots' pending train rows into a rotating
         staging set (the SAME per-slice pool and wire dtypes as scoring
@@ -3799,15 +3242,15 @@ class TpuInferenceService(MultitenantService):
         step, which would drift its weights on stale momentum and skew
         its bias-correction count). The ingest dispatch is async and
         precedes the train step on the device queue."""
-        tlanes = self._train_lanes.get((family, sl), {})
+        family, scorer, tlanes = s.family, s.scorer, s.train_lanes
         mbcfg = self._family_cfg[family].microbatch
         pending = max(
-            (r.count for (s, _d), r in tlanes.items() if s in admitted),
+            (r.count for (t, _d), r in tlanes.items() if t in admitted),
             default=0,
         )
         if pending == 0:
             return 0, []
-        b_lane = self._pick_bucket(
+        b_lane = s.pick_bucket(
             pending, tuple(mbcfg.buckets), mbcfg.max_batch
         )
         scratch = self._train_scratch
@@ -3819,7 +3262,7 @@ class TpuInferenceService(MultitenantService):
                 np.empty((max(b_lane, mbcfg.max_batch),), np.int32),
             )
         sc_seqs, sc_rows = scratch
-        st = self._staging_set(family, sl, scorer, b_lane)
+        st = s.staging_set(b_lane)
         ids, vals, counts = st.ids, st.vals, st.counts
         counts[:] = 0
         moved = 0
@@ -3837,7 +3280,7 @@ class TpuInferenceService(MultitenantService):
             counts[slot, dshard] = k
             fed.add(slot)
             moved += k
-        self._train_rows_gauge(family, sl)
+        self._train_rows_gauge(family)
         if moved == 0:
             return 0, []
         staged = scorer.stage_inputs(ids, vals, counts)
@@ -3855,7 +3298,7 @@ class TpuInferenceService(MultitenantService):
         return moved, sorted(fed)
 
     async def _dispatch_train(
-        self, family: str, sl: int, scorer, admitted: Dict[int, object],
+        self, s: SliceRuntime, admitted: Dict[int, object],
         mature: List[int], replay: bool, pin,
     ) -> int:
         """Dispatch one train-lane step and enqueue its completion on the
@@ -3864,7 +3307,8 @@ class TpuInferenceService(MultitenantService):
         window exactly like flushes, which is what keeps them off the
         serve critical path (a full window defers training, never
         scoring)."""
-        sem = self._inflight_sem((family, sl))
+        family, sl, scorer = s.family, s.sl, s.scorer
+        sem = s.permits
         # locked() was False with no await since: acquire returns now
         await sem.acquire()
         enqueued = False
@@ -3880,27 +3324,23 @@ class TpuInferenceService(MultitenantService):
                         reason="optimizer_init",
                     ).inc()
                     return 0
-            shape_key = (family, sl, "train")
-            compiling = shape_key not in self._seen_shapes
+            compiling = "train" not in s.seen_shapes
             rows_moved = 0
             source = "resident"
-            ticks = self._train_ticks.setdefault((family, sl), {})
             if replay:
                 source = "replay"
-                rows_moved, trained = self._pack_train(
-                    family, sl, scorer, admitted
-                )
+                rows_moved, trained = self._pack_train(s, admitted)
             else:
                 trained = sorted(mature)
             # EVERY trained slot's cadence resets — a replay step IS the
             # slot's training for this interval, so a feed oscillating
             # around the microbatch threshold must not double the
             # configured cadence with a back-to-back resident step
-            for s in trained:
-                ticks[s] = 0
+            for t in trained:
+                s.train_ticks[t] = 0
             if not trained:
                 return 0
-            self._lane_last_source[(family, sl)] = source
+            s.lane_last_source = source
             mask = np.zeros((scorer.n_slots,), bool)
             mask[trained] = True
             if self.faultplan is not None:
@@ -3920,18 +3360,18 @@ class TpuInferenceService(MultitenantService):
             except Exception:  # noqa: BLE001 - test doubles
                 pass
             if compiling:
-                self._seen_shapes.add(shape_key)
+                s.seen_shapes.add("train")
                 self.metrics.counter("tpu_inference.compiles").inc()
             self.metrics.counter("tpu_inference.train_steps").inc()
-            for s in trained:
+            for t in trained:
                 self.metrics.counter(
-                    "tpu_train_steps_total", tenant=admitted[s].tenant
+                    "tpu_train_steps_total", tenant=admitted[t].tenant
                 ).inc()
             # zero-stall hot-swap cadence: every swap_every lane steps
             # the master weights commit to the serving kernel view (the
             # activate(params=...) tail — sidecar re-derive + canary
             # arm); between commits scoring runs the previous weights
-            swaps = self._lane_swap.get((family, sl), 0) + 1
+            swaps = s.lane_swap + 1
             swap_every = max(1, int(getattr(pin, "swap_every", 8)))
             if swaps >= swap_every:
                 swaps = 0
@@ -3947,7 +3387,7 @@ class TpuInferenceService(MultitenantService):
                         steps=swap_every,
                         canary_armed=bool(scorer.canary_active()),
                     )
-            self._lane_swap[(family, sl)] = swaps
+            s.lane_swap = swaps
             rec = None
             if self.flightrec is not None:
                 rec = self.flightrec.record(
@@ -3967,7 +3407,7 @@ class TpuInferenceService(MultitenantService):
                 flops=float(flops_fn()) if flops_fn is not None else 0.0,
                 rec=rec, sl=sl, lane="train",
             )
-            dl = self._flush_deadline_s(family, sl)
+            dl = s.flush_deadline_s(self._family_ft(family))
             if dl is not None:
                 pf.deadline = pf.t_dispatch + dl
             if not hasattr(losses_dev, "copy_to_host_async"):
@@ -3989,7 +3429,7 @@ class TpuInferenceService(MultitenantService):
 
     def _deliver_gauge(self) -> None:
         self.metrics.gauge("tpu_inference_deliver_inflight").set(
-            sum(len(q) for q in self._reap.values())
+            sum(len(s.reap) for s in self._slices.values())
         )
         # labeled variants beside the legacy aggregate: the reap queues
         # are PER-(family, slice), so per-family depth is where a wedged
@@ -4000,11 +3440,11 @@ class TpuInferenceService(MultitenantService):
         fam_depth: Dict[str, int] = {}
         dev_depth: Dict[str, int] = {}
         multi = self.mm.n_devices > 1
-        for (family, sl), q in self._reap.items():
-            fam_depth[family] = fam_depth.get(family, 0) + len(q)
+        for (family, sl), s in self._slices.items():
+            fam_depth[family] = fam_depth.get(family, 0) + len(s.reap)
             if multi:
                 lbl = self.mm.slice_device_label(sl)
-                dev_depth[lbl] = dev_depth.get(lbl, 0) + len(q)
+                dev_depth[lbl] = dev_depth.get(lbl, 0) + len(s.reap)
         for family, depth in fam_depth.items():
             self.metrics.gauge(
                 "tpu_inference_deliver_inflight_family", family=family
@@ -4018,26 +3458,7 @@ class TpuInferenceService(MultitenantService):
     def _mfu_account(self, family: str):
         acc = self._mfu.get(family)
         if acc is None:
-            from sitewhere_tpu.runtime.metrics import MfuAccount
-
             acc = self._mfu[family] = MfuAccount(self.metrics, family)
-        return acc
-
-    def _mfu_device_account(self, family: str, sl: int):
-        """Per-(family, mesh-slice) MFU account under the DEVICE-labeled
-        names (MfuAccount.DEVICE_NAMES): chip-level utilization so an
-        idle or skewed slice is visible instead of averaged away by the
-        family aggregate. Cardinality is mesh-bounded."""
-        acc = self._mfu_dev.get((family, sl))
-        if acc is None:
-            from sitewhere_tpu.runtime.metrics import MfuAccount
-
-            f_name, s_name, g_name = MfuAccount.DEVICE_NAMES
-            acc = self._mfu_dev[(family, sl)] = MfuAccount(
-                self.metrics, family,
-                flops_name=f_name, secs_name=s_name, gauge_name=g_name,
-                device=self.mm.slice_device_label(sl),
-            )
         return acc
 
     def refresh_mfu(self) -> None:
@@ -4047,8 +3468,9 @@ class TpuInferenceService(MultitenantService):
         not its last busy value)."""
         for acc in self._mfu.values():
             acc.refresh()
-        for acc in self._mfu_dev.values():
-            acc.refresh()
+        for s in self._slices.values():
+            if s.mfu is not None:
+                s.mfu.refresh()
         # same tick drives the score-health time-based window rotation:
         # a slow stream must still rotate its drift windows instead of
         # waiting hours to fill window_rows
@@ -4074,8 +3496,8 @@ class TpuInferenceService(MultitenantService):
             # a family with a resolve in flight is ineligible: its next
             # head must wait its turn (per-tenant FIFO)
             heads = [
-                q[0] for f, q in self._reap.items()
-                if q and f not in self._resolving
+                s.reap[0] for s in self._slices.values()
+                if s.reap and s.resolving is None
             ]
             if not heads:
                 # clear-then-wait is race-free on the single-threaded
@@ -4137,7 +3559,7 @@ class TpuInferenceService(MultitenantService):
     def _spawn_resolve(self, pf: _PendingFlush) -> None:
         """Resolve one landed flush in a per-family task. At most one
         resolve runs per family (the loop skips families in
-        ``_resolving``), which preserves per-tenant in-order delivery;
+        ``resolving``), which preserves per-tenant in-order delivery;
         separate tasks restore the cross-family isolation the old
         per-flush deliver tasks had — a full scored topic only stalls
         its own family, and only until ``max_inflight`` backpressures
@@ -4147,11 +3569,12 @@ class TpuInferenceService(MultitenantService):
             # the loop ledger charges a task by its name's prefix
             name=f"tpu-inference-resolve[{pf.family}/{pf.sl}]",
         )
-        self._resolving[pf.key] = task
+        s = self._slices[pf.key]
+        s.resolving = task
 
-        def _done(t: asyncio.Task, key: Tuple[str, int] = pf.key) -> None:
-            if self._resolving.get(key) is t:
-                del self._resolving[key]
+        def _done(t: asyncio.Task) -> None:
+            if s.resolving is t:
+                s.resolving = None
             if not t.cancelled() and t.exception() is not None:
                 # _resolve_flush handles its own failures; anything
                 # escaping would otherwise vanish with the task
@@ -4202,6 +3625,7 @@ class TpuInferenceService(MultitenantService):
         jit output nothing ever donates — unlike param trees, whose
         buffers later loop-thread calls donate (see
         ``checkpoint.host_copy_params`` for the full invariant)."""
+        s = self._slices[pf.key]
         _slots, _cols, seqs, rows = pf.taken
         scattered = False  # did the (possibly unscored) write-back start?
         # flush supervision: every materialization await below is bounded
@@ -4229,13 +3653,13 @@ class TpuInferenceService(MultitenantService):
                 now = time.perf_counter()
                 # a train step holds the slice's device queue like a
                 # serve flush: the next flush's service time starts here
-                self._last_landed[pf.key] = now
-                self.last_train_losses[pf.key] = losses_np
+                s.last_landed = now
+                s.last_train_losses = losses_np
                 device_s = max(0.0, now - pf.t_dispatch)
                 # train steps feed the same deadline history as serve
                 # flushes (they share the in-flight window): mixing only
                 # RAISES the p99-derived deadline — conservative-safe
-                self._note_device_s(pf.key, device_s)
+                s.note_device_s(device_s)
                 self.metrics.histogram(
                     "tpu_inference.train_step", unit="s"
                 ).record(device_s)
@@ -4270,11 +3694,8 @@ class TpuInferenceService(MultitenantService):
             # the transfer has landed: the in-flight interval ends and
             # ``resolve`` begins, on one stamp
             now = time.perf_counter()
-            service_s = max(
-                0.0,
-                now - max(pf.t_dispatch, self._last_landed.get(pf.key, 0.0)),
-            )
-            self._last_landed[pf.key] = now
+            service_s = max(0.0, now - max(pf.t_dispatch, s.last_landed))
+            s.last_landed = now
             # cumulative wait: from the FIRST time the reaper waited on
             # this flush (race rounds included), not just the last await
             waited_s = now - pf.t_wait if pf.t_wait is not None else now - t0
@@ -4351,7 +3772,7 @@ class TpuInferenceService(MultitenantService):
             self.metrics.histogram("tpu_inference.inflight", unit="s").record(
                 device_s
             )
-            self._note_device_s(pf.key, device_s)
+            s.note_device_s(device_s)
             # the service time (taken at the landing, above): the slice's
             # device queue is FIFO, so this flush had the device from the
             # later of its own dispatch and the previous landing. These
@@ -4363,17 +3784,14 @@ class TpuInferenceService(MultitenantService):
             )
             if pf.flops:
                 self._mfu_account(pf.family).record(pf.flops, service_s)
-                if self.mm.n_devices > 1:
+                if s.mfu is not None:
                     # per-chip utilization beside the family aggregate:
                     # each slice's flushes feed ITS device's account
-                    self._mfu_device_account(pf.family, pf.sl).record(
-                        pf.flops, service_s
-                    )
+                    s.mfu.record(pf.flops, service_s)
             d2h_labels = {"family": pf.family}
             if self.mm.n_devices > 1:
-                scorer = self.scorers.get(pf.key)
                 d2h_labels["device"] = getattr(
-                    scorer, "device_label", "device:?"
+                    s.scorer, "device_label", "device:?"
                 )
             self.metrics.counter(
                 "tpu_inference_d2h_bytes_total", **d2h_labels
@@ -4402,11 +3820,9 @@ class TpuInferenceService(MultitenantService):
                 self.metrics.counter("tpu_inference.d2h_plane_bytes").inc(
                     pf.plane_nbytes
                 )
-            self._consec_errors.pop(pf.key, None)  # healthy again
+            s.consec_errors = 0  # healthy again
             self._failover_rounds.pop(pf.family, None)
-            breaker = self.breakers.get(pf.key)
-            if breaker is not None:
-                breaker.record_success()
+            s.breaker.record_success()
         except asyncio.CancelledError:
             # cancelled mid-flight (forced teardown): the rows were already
             # popped from lanes, so resolve them unscored or they're lost.
@@ -4458,8 +3874,8 @@ class TpuInferenceService(MultitenantService):
                     if pf.retried:
                         # same-chip second strike: chip-attributed —
                         # the rows leave unscored, unmarked
-                        for s in np.unique(seqs).tolist():
-                            self._retried_seqs.discard(int(s))
+                        for q in np.unique(seqs).tolist():
+                            self._retried_seqs.discard(int(q))
                     await self._resolve_rows(
                         seqs, rows, None, family=pf.family
                     )
@@ -4472,20 +3888,13 @@ class TpuInferenceService(MultitenantService):
                 # downstream bus hiccup double-pace failover/parking;
                 # train-lane faults are best-effort and must not pace
                 # breaker/failover either (serve flushes own that signal)
-                breaker = self.breakers.get(pf.key)
-                if breaker is not None:
-                    breaker.record_failure()
-                    if (
-                        self.flightrec is not None
-                        and breaker.state == "open"
-                    ):
-                        self.flightrec.snapshot(
-                            f"breaker:{pf.family}", family=pf.family,
-                            trace_id=(
-                                pf.rec.get("trace_id") if pf.rec else None
-                            ),
-                        )
-                await self._note_scorer_error(pf.family, pf.sl)
+                s.breaker.record_failure()
+                if self.flightrec is not None and s.breaker.state == "open":
+                    self.flightrec.snapshot(
+                        f"breaker:{pf.family}", family=pf.family,
+                        trace_id=pf.rec.get("trace_id") if pf.rec else None,
+                    )
+                await self._note_scorer_error(s)
         finally:
             # the head leaves the queue only once its resolution is DONE
             # (either way) — queue length and the deliver_inflight gauge
@@ -4493,21 +3902,17 @@ class TpuInferenceService(MultitenantService):
             # can't miss a flush the reaper was cancelled inside, and
             # slice-move fences wait on exactly this flag
             pf.resolved = True
-            q = self._reap.get(pf.key)
-            if q and q[0] is pf:
-                q.popleft()
+            if s.reap and s.reap[0] is pf:
+                s.reap.popleft()
             self._deliver_gauge()
             if pf.owns_permit:
-                self._inflight_sem(pf.key).release()
-            if (
-                self._last_scores.get(pf.key) is pf.scores
-                and not self._reap.get(pf.key)
-            ):
+                s.permits.release()
+            if s.last_scores is pf.scores and not s.reap:
                 # slice idle: the overlap probe must not pin this
                 # flush's device scores until the next (maybe never)
                 # flush — by now the probe is ready, so dropping it
                 # can't change the next overlap verdict
-                self._last_scores.pop(pf.key, None)
+                s.last_scores = None
 
     async def _on_flush_timeout(
         self, pf: _PendingFlush, scattered: bool
@@ -4522,6 +3927,7 @@ class TpuInferenceService(MultitenantService):
         ``_resolve_flush``'s try — its ``finally`` still pops the queue
         head and releases the permit exactly once."""
         family, sl = pf.key
+        s = self._slices[pf.key]
         self.metrics.counter(
             "tpu_flush_timeout_total", family=family, slice=str(sl)
         ).inc()
@@ -4548,16 +3954,14 @@ class TpuInferenceService(MultitenantService):
         if poison:
             await self._eject_poison(family, seqs, err)
             return
-        breaker = self.breakers.get(pf.key)
-        if breaker is not None:
-            breaker.trip()
-        await self._quarantine_slice(family, sl, reason="flush-timeout")
+        s.breaker.trip()
+        await self._quarantine_slice(s, reason="flush-timeout")
         if scattered or pf.lane == "train":
             return  # no rows to salvage (train) / already written back
         if pf.retried:
             # same-chip (or fleet-sick) second timeout: chip-attributed
-            for s in np.unique(seqs).tolist():
-                self._retried_seqs.discard(int(s))
+            for q in np.unique(seqs).tolist():
+                self._retried_seqs.discard(int(q))
             await self._force_resolve(pf)
         elif (
             pf.retry_rows is not None
@@ -4616,7 +4020,7 @@ class TpuInferenceService(MultitenantService):
             iters.inc()
             moved = 0
             holding = False
-            fam_cfgs: Dict[str, Dict[int, TenantEngineConfig]] = {}
+            fam_cfgs: Dict[SliceRuntime, Dict[int, TenantEngineConfig]] = {}
             # weighted fair queuing: every pass replenishes each tenant's
             # deficit (quantum × weight); a tenant that overdrew sits out
             # until its deficit refills, so sustained intake converges to
@@ -4627,10 +4031,9 @@ class TpuInferenceService(MultitenantService):
                 # slice moves in flight: release any whose old-slice
                 # snapshot fully resolved (parked rows re-enter lanes)
                 self._lift_fences()
-            if self._quarantined:
-                # probation: launch due probes for quarantined slices
-                # (no-op dict check on the healthy path)
-                self._probe_quarantined()
+            # probation: launch due probes for quarantined slices (a
+            # walk over a handful of slices on the healthy path)
+            self._probe_quarantined()
             if self.pager is not None:
                 # weight paging: issue prefetches for rising-lag ghost
                 # tenants, then service ≤ 1 queued page-in — all device
@@ -4640,22 +4043,29 @@ class TpuInferenceService(MultitenantService):
                 if engine.state is not LifecycleState.STARTED:
                     continue
                 assert isinstance(engine, TpuInferenceEngine)
+                s = self._slices.get(
+                    (engine.config.model, engine.placement.shard)
+                )
+                if s is None:
+                    # its slice's scorer never built (a move onto a dead
+                    # chip): its rows stay on the bus, where lag shows
+                    continue
                 if engine.placement is not None and engine.placement.slot >= 0:
                     # register for flush even when throttled below: lanes
                     # already holding this tenant's rows must still drain.
                     # Ghost (paged-out, slot=-1) tenants register nothing:
                     # their rows park behind the paging fence and no slot
                     # of theirs exists to flush or train
-                    fam_cfgs.setdefault(
-                        (engine.config.model, engine.placement.shard), {}
-                    )[engine.placement.slot] = engine.config
+                    fam_cfgs.setdefault(s, {})[
+                        engine.placement.slot
+                    ] = engine.config
                     tc = engine.config.training
                     if tc.enabled and tc.train_lane:
                         # replay-fed continual learning: low-priority
                         # intake from the train feed topic into the
                         # train lane rings (bounded + credit-gated —
                         # never charged against the serve fair budget)
-                        await self._consume_train_feed(tenant, engine)
+                        await self._consume_train_feed(tenant, engine, s)
                 budget = self.fair.budget(tenant)
                 if budget <= 0:
                     throttled.inc()
@@ -4665,9 +4075,7 @@ class TpuInferenceService(MultitenantService):
                 # gauge, lag drives the credit signal, and retention
                 # bounds memory) instead of buffering unboundedly in
                 # lanes. 2× max_batch keeps the next flush fed.
-                lanes_now = self._lanes.get(
-                    (engine.config.model, engine.placement.shard), {}
-                )
+                lanes_now = s.lanes
                 slot_now = engine.placement.slot
                 pending_rows = sum(
                     l.count for (s, _d), l in lanes_now.items()
@@ -4728,18 +4136,14 @@ class TpuInferenceService(MultitenantService):
                             self.bus, topic, ev, metrics=self.metrics
                         )
                     moved += len(objects)
-            for (family, sl), cfgs in fam_cfgs.items():
-                if (family, sl) not in self.scorers:
-                    continue
+            for s, cfgs in fam_cfgs.items():
                 mb = next(iter(cfgs.values())).microbatch
-                lanes = self._lanes[(family, sl)]
-                full = any(l.count >= mb.max_batch for l in lanes.values())
-                if full or self._deadline_reached((family, sl), mb.deadline_ms):
-                    if self._flush_held((family, sl), lanes, mb):
+                if s.due(mb):
+                    if s.held(mb, s.family in self._parked):
                         held.inc()
                         holding = True
                         continue
-                    moved += await self._flush_slice(cfgs, family, sl)
+                    moved += await self._flush_slice(cfgs, s)
             if fam_cfgs:
                 # the async train lane runs AFTER serve flushes, off the
                 # flush critical path: at most one low-priority train
@@ -4783,58 +4187,6 @@ class TpuInferenceService(MultitenantService):
                 self.bus.publish_nowait(topic, item)
             raise
 
-    def _deadline_reached(self, key: Tuple[str, int], deadline_ms: float) -> bool:
-        first = self._first_pending_ts.get(key)
-        return first is not None and (time.monotonic() - first) * 1000.0 >= deadline_ms
-
-    def _in_flight(self, key: Tuple[str, int]) -> List[_PendingFlush]:
-        """This (family, slice)'s entries that are dispatched and have
-        not landed: what its device is still working on. A landed head
-        that only waits for its resolve to publish is not among them —
-        the device is free from the landing on — nor is a poisoned
-        (host-only) entry, which lands by construction."""
-        return [
-            p for p in self._reap.get(key, ())
-            if not p.resolved and not p.landed()
-        ]
-
-    def _flush_held(self, key: Tuple[str, int], lanes: dict, mb) -> bool:
-        """THE flush policy's second half (the first is ``full`` or
-        ``_deadline_reached``: the flush is DUE). A due flush waits for
-        the one in flight unless some lane already holds the smallest
-        compiled bucket:
-
-          hold ⇔ due ∧ a serve flush of this slice is in flight
-                     ∧ every lane's count < buckets[0]
-
-        (a train-lane step in flight holds nothing: serving has the
-        right of way, and the lane only ever enters an empty window).
-
-        Below the smallest bucket a bigger flush runs the SAME program in
-        the same device time, so holding costs no throughput and saves a
-        whole step of device queue: the rows stay on the lanes (counted
-        by the lane watermark as ever) and ride out together when the
-        in-flight flush lands. From the smallest bucket up, coalescing
-        further would move to a larger program, and pipelining up to
-        ``max_inflight`` deep — which hides h2d and assembly under
-        compute for full flushes — applies unchanged.
-
-        Evaluated afresh every pass from the reap queue, per (family,
-        slice): whatever takes the in-flight flush out of the queue
-        (landing, the supervisor's force-resolve, teardown) lifts the
-        hold, and where ``_flush_slice`` would not dispatch at all (the
-        family parked, the slice quarantined, the breaker open: rows
-        pass through unscored) there is nothing to wait for."""
-        if not any(p.lane == "serve" for p in self._in_flight(key)):
-            return False
-        if key[0] in self._parked or key in self._quarantined:
-            return False
-        breaker = self.breakers.get(key)
-        if breaker is not None and breaker.state == "open":
-            return False
-        smallest = min(mb.buckets[0], mb.max_batch)
-        return all(l.count < smallest for l in lanes.values())
-
     def prewarm(self) -> None:
         """Compile every active family's bucket shapes (see
         ShardedScorer.prewarm). Call after tenants are added, before
@@ -4863,15 +4215,14 @@ class TpuInferenceService(MultitenantService):
                     (engine.config.model, engine.placement.shard)
                 )
         for key, sizes in wanted.items():
-            scorer = self.scorers.get(key)
-            if scorer is not None:
+            s = self._slices.get(key)
+            if s is not None:
+                scorer = s.scorer
                 scorer.prewarm(sorted(sizes))
                 # every bucket's step is compiled now: its first real
                 # flush must not report a (false) compile either — same
                 # rule as the train lane below
-                self._seen_shapes.update(
-                    (key[0], key[1], b) for b in sizes
-                )
+                s.seen_shapes.update(sizes)
                 if key in lane_keys and getattr(
                     scorer, "train_lane", False
                 ):
@@ -4885,7 +4236,7 @@ class TpuInferenceService(MultitenantService):
                     # real dispatch must not report a (false) compile —
                     # that would fire the steady_state_recompile
                     # watchdog the moment a replay train job starts
-                    self._seen_shapes.add((key[0], key[1], "train"))
+                    s.seen_shapes.add("train")
 
     def params_source(self, tenant: str):
         """A zero-arg callable yielding the tenant's CURRENT slot params
@@ -4960,7 +4311,8 @@ class TpuInferenceService(MultitenantService):
                 f"{fam}@{sl}": {
                     k: v for k, v in qs.items() if k != "next_probe"
                 }
-                for (fam, sl), qs in sorted(self._quarantined.items())
+                for (fam, sl), s in sorted(self._slices.items())
+                for qs in [s.quarantine] if qs is not None
             },
             "families": {
                 f"{fam}@{sl}": {

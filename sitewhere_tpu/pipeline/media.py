@@ -408,7 +408,7 @@ class MediaClassificationPipeline(LifecycleComponent):
 
     def _classify_deadline_s(self) -> Optional[float]:
         """The current classify completion budget (None = supervision
-        off): the media twin of TpuInferenceService._flush_deadline_s."""
+        off): the media twin of SliceRuntime.flush_deadline_s."""
         floor = self.flush_deadline_ms / 1000.0
         if floor <= 0:
             return None

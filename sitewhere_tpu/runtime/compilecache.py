@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point that enables the cache (``chip_smoke.py``,
-``bench.py``, ``tests/conftest.py``): when ``JAX_COMPILATION_CACHE_DIR``
+``tests/conftest.py``): when ``JAX_COMPILATION_CACHE_DIR``
 is set, JAX reads it itself and nothing is set in code, so whoever
 launches the process places the cache; otherwise the cache goes to a
 fixed directory inside the checkout. The path is part of the cache's key,

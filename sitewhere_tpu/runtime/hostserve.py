@@ -115,7 +115,7 @@ class HostServer(LifecycleComponent):
         self._prev_flushes, self._prev_timeouts = flushes, timeouts
         return {
             "flush_timeout_rate": (dt / df) if df > 0 else (1.0 if dt > 0 else 0.0),
-            "quarantined_slices": len(self.inst.inference._quarantined),
+            "quarantined_slices": self.inst.inference.quarantined_slices(),
             "overload_credit": self._fam_sum("overload_credit"),
             "probes_ok": self.probes_ok,
             "tenants": sorted(self.inst.tenants),

@@ -37,7 +37,7 @@ from sitewhere_tpu.runtime.loopledger import LoopLedger
 D2H_OVERLAP_EPS_S = 1e-3
 
 # Published bf16 peak FLOP/s of one chip, keyed by the ``device_kind`` JAX
-# reports — the MFU denominator bench.py and chip_smoke.py look up. A
+# reports — the MFU denominator chip_smoke.py looks up. A
 # device that is not here is an error, not a default. Sources:
 #   "TPU v5 lite": Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16)
 PEAK_FLOPS_BF16_BY_KIND: Dict[str, float] = {"TPU v5 lite": 197e12}
@@ -357,10 +357,6 @@ class MfuAccount:
       sliding window ÷ ``peak`` × 100. The window rate reuses MeterRate,
       so the gauge is honest right after startup and decays to 0 when
       the family goes idle (refresh on read via :meth:`refresh`).
-
-    ``bench.py`` computes its engine MFU from the SAME per-row flops
-    functions over wall time, so the live gauge and the bench agree by
-    construction (the 5% acceptance bar is slack for window edges).
     """
 
     __slots__ = ("family", "peak", "_flops_c", "_secs_c", "_gauge", "_meter")

@@ -202,7 +202,9 @@ async def test_device_fault_matrix_accounting_latency_and_healing():
             sl0 = e0.placement.shard
             timeouts0 = _fam_sum(inst.metrics, "tpu_flush_timeout_total")
             nan0 = _fam_sum(inst.metrics, "tpu_scores_nan_total")
-            deadline_s = svc._flush_deadline_s("lstm_ad", sl0)
+            deadline_s = svc._slices[("lstm_ad", sl0)].flush_deadline_s(
+                svc._family_ft("lstm_ad")
+            )
             plan = DeviceFaultPlan(DeviceFault(
                 kind, families=("lstm_ad",), slices=(sl0,),
                 lanes=("serve",), **kw,
@@ -263,7 +265,7 @@ async def test_device_fault_matrix_accounting_latency_and_healing():
             # back -> scored delivery resumes on the healed slice
             plan.clear()
             assert await _wait_for(
-                lambda: not svc._quarantined, 40.0
+                lambda: not svc.quarantined_slices(), 40.0
             ), f"{kind}: probation never re-admitted the slice"
             if expects_quarantine:
                 assert await _wait_for(
@@ -348,7 +350,7 @@ async def test_poison_batch_run_on_live_mesh():
         ), "scoring did not continue after the ejection"
         # probation heals the original slice; rebalance-back returns
         # c0; its subsequent batches score normally THERE
-        assert await _wait_for(lambda: not svc._quarantined, 40.0)
+        assert await _wait_for(lambda: not svc.quarantined_slices(), 40.0)
         assert await _wait_for(
             lambda: e0.placement.shard == sl0, 40.0
         ), "tenant never returned to its original slice"

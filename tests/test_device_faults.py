@@ -410,7 +410,9 @@ async def test_hung_transfer_force_resolves_and_probation_readmits():
             lanes=("serve",), first_n=1,
         ))
         svc.faultplan = plan
-        deadline_s = svc._flush_deadline_s("lstm_ad", sl0)
+        deadline_s = svc._slices[("lstm_ad", sl0)].flush_deadline_s(
+            svc._family_ft("lstm_ad")
+        )
         assert deadline_s is not None
         t0 = time.monotonic()
         await _publish(inst, "acme", fleets["acme"], 10)
@@ -443,7 +445,7 @@ async def test_hung_transfer_force_resolves_and_probation_readmits():
         # fault clears -> probation probes land -> slice re-admitted
         plan.clear()
         assert await _wait_for(
-            lambda: not svc._quarantined
+            lambda: not svc.quarantined_slices()
             and inst.metrics.counter("tpu_inference.readmitted").value >= 1,
             30.0,
         ), "probation never re-admitted the healed slice"
@@ -478,7 +480,7 @@ async def test_capacity_fleet_degrades_unscored_and_recovers():
                 sent += _ROWS
         assert await _wait_for(lambda: scored.value >= sent)
 
-        await svc._quarantine_slice("lstm_ad", sa, reason="test")
+        await svc._quarantine_slice(svc._slices[("lstm_ad", sa)], reason="test")
         # stranded: nowhere to go (capb's slice is full), NOT parked
         # (the other slice is healthy), placement unchanged
         assert ea.placement.shard == sa
@@ -501,7 +503,7 @@ async def test_capacity_fleet_degrades_unscored_and_recovers():
         # the slice is healthy (no faultplan): probation re-admits it
         # and capa's SCORED delivery resumes in place
         assert await _wait_for(
-            lambda: not svc._quarantined, 30.0
+            lambda: not svc.quarantined_slices(), 30.0
         ), "probation never re-admitted"
         before = scored.value
         for r in range(3):
@@ -578,7 +580,7 @@ async def test_poison_batch_ejects_to_dlq_and_tenant_keeps_serving():
         # and rebalance-back brings pa home; subsequent batches score
         # normally on the ORIGINAL slice
         assert await _wait_for(
-            lambda: not svc._quarantined, 30.0
+            lambda: not svc.quarantined_slices(), 30.0
         ), "probation never re-admitted the original slice"
         assert await _wait_for(
             lambda: ea.placement.shard == sa, 30.0

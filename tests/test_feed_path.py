@@ -8,7 +8,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from sitewhere_tpu.pipeline.inference import _LaneRing, _StagingSet
+from sitewhere_tpu.pipeline.slices import _LaneRing, _StagingSet
 from sitewhere_tpu.pipeline.media import _FrameRing
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 
@@ -240,7 +240,9 @@ async def test_flush_uses_staging_and_records_feed_metrics():
         assert hist.summary()["count"] >= 1
         # staging sets exist and rotated for the family
         svc = inst.inference
-        assert any(k[0] == "lstm_ad" for k in svc._staging)
+        assert any(
+            s.family == "lstm_ad" and s.staging for s in svc._slices.values()
+        )
         # ...and the result path reaped the flush through the device-side
         # gather: d2h volume is rows-sized, never MORE than the slice's
         # T×lane plane (with per-slice serving the plane itself is small

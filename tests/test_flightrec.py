@@ -7,8 +7,7 @@ flush's timing record with its trace_id linked; (c) live MFU accounting
 matches a hand-computed FLOP count for a known LSTM config within 5%;
 (d) watchdog rules fire the alert counter, force trace retention, and
 snapshot the recorder; (e) metrics-history ring wrap + downsampling;
-(f) the check_bench comparator's per-kind tolerances; (g) OpenMetrics
-EOF + label-cardinality lint additions."""
+(g) OpenMetrics EOF + label-cardinality lint additions."""
 
 import asyncio
 import importlib.util
@@ -51,7 +50,6 @@ def _load_tool(name: str):
     return mod
 
 
-check_bench = _load_tool("check_bench")
 check_metrics = _load_tool("check_metrics")
 
 
@@ -583,85 +581,6 @@ def test_history_wrap_and_downsampling():
     hist.sample()
     vb = hist.values('overload_credit{tenant="b"}')
     assert np.isnan(vb[:-1]).all() and vb[-1] == 7.0
-
-
-# -- (f) check_bench comparator ------------------------------------------
-
-
-def test_check_bench_classify_and_tolerances():
-    assert check_bench.classify("value") == "throughput"
-    assert check_bench.classify("e2e_ev_s") == "throughput"
-    assert check_bench.classify("vit_fps") == "throughput"
-    assert check_bench.classify("h2d_mbps") == "throughput"
-    assert check_bench.classify("e2e_paced_p99_ms") == "p99"
-    assert check_bench.classify("tenants32_mfu_pct") == "info"
-    assert check_bench.classify("platform") == "info"
-
-    base = {
-        "value": 1000.0, "e2e_ev_s": 500.0, "e2e_paced_p99_ms": 100.0,
-        "tenants32_mfu_pct": 0.04, "platform": "tpu", "e2e_drained": True,
-        "rtt_ms": 100.0, "deepar_fc_s": 0.0,
-    }
-    # within tolerance: -9% throughput, +20% p99 → clean
-    fresh_ok = dict(base, value=910.0, e2e_ev_s=455.0,
-                    e2e_paced_p99_ms=120.0, tenants32_mfu_pct=1.2)
-    rows, regs = check_bench.compare(fresh_ok, base)
-    assert regs == []
-    status = {r["key"]: r["status"] for r in rows}
-    assert status["value"] == "ok"
-    assert status["e2e_paced_p99_ms"] == "ok"
-    # info keys NEVER gate, even on wild swings (MFU accounting changes)
-    assert status["tenants32_mfu_pct"] == "info"
-    # non-numeric / bool / zero-baseline / missing keys report n/a
-    assert status["platform"] == "n/a"
-    assert status["e2e_drained"] == "n/a"
-    assert status["deepar_fc_s"] == "n/a"
-
-    # regressions: -15% throughput and +30% p99
-    fresh_bad = dict(base, value=850.0, e2e_paced_p99_ms=130.0)
-    rows, regs = check_bench.compare(fresh_bad, base)
-    assert {r["key"] for r in regs} == {"value", "e2e_paced_p99_ms"}
-    table = check_bench.format_table(rows)
-    assert "REGRESSION" in table and "value" in table
-
-    # a NEW key in fresh (absent from baseline) must not gate
-    rows, regs = check_bench.compare(dict(base, new_ev_s=1.0), base)
-    assert regs == []
-
-
-def test_check_bench_gates_paging_keys():
-    """ISSUE 19 bench keys: the zipf512 density row's latency columns
-    gate as p99 (a doctored +50% cold-activation p99 must FAIL), the
-    acceptance ratio gates by name, throughput by suffix — while the
-    hit-rate / prefetch-accuracy companions stay info-class."""
-    assert check_bench.classify("zipf512_ev_s") == "throughput"
-    assert check_bench.classify("p99_zipf512_ms") == "p99"
-    assert check_bench.classify("cold_activation_p99_ms") == "p99"
-    assert check_bench.classify("zipf512_p99_ratio") == "p99"
-    assert check_bench.classify("zipf512_hit_rate") == "info"
-    assert check_bench.classify("zipf512_prefetch_acc") == "info"
-
-    base = {
-        "zipf512_ev_s": 10_000.0, "p99_zipf512_ms": 40.0,
-        "zipf512_p99_ratio": 1.1, "cold_activation_p99_ms": 20.0,
-        "zipf512_hit_rate": 0.9, "zipf512_prefetch_acc": 0.5,
-    }
-    # doctored regressions: +50% cold-activation p99, ratio 1.1 → 1.65
-    fresh = dict(base, cold_activation_p99_ms=30.0, zipf512_p99_ratio=1.65)
-    _, regs = check_bench.compare(fresh, base)
-    assert {r["key"] for r in regs} == {
-        "cold_activation_p99_ms", "zipf512_p99_ratio"
-    }
-    # -16% Zipf throughput gates; a hit-rate collapse reports info only
-    _, regs = check_bench.compare(
-        dict(base, zipf512_ev_s=8_400.0, zipf512_hit_rate=0.2), base
-    )
-    assert {r["key"] for r in regs} == {"zipf512_ev_s"}
-    # within tolerance: +20% on both latency keys stays clean
-    _, regs = check_bench.compare(
-        dict(base, p99_zipf512_ms=48.0, cold_activation_p99_ms=24.0), base
-    )
-    assert regs == []
 
 
 # -- (g) exposition lint additions ---------------------------------------

@@ -192,11 +192,11 @@ async def _two_flushes_in_flight():
         t0 = time.perf_counter()
         await _publish(inst, b1)
         assert await _wait_for(
-            lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1)
+            lambda: len(svc._slices[("lstm_ad", 0)].reap) == 1)
         await asyncio.sleep(0.25)
         await _publish(inst, b2)
         assert await _wait_for(
-            lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 2)
+            lambda: len(svc._slices[("lstm_ad", 0)].reap) == 2)
         await asyncio.sleep(0.15)
         for g in gates:
             g.set()

@@ -2,7 +2,7 @@
 unless a lane has filled its smallest bucket.
 
 ``_scoring_loop`` flushes a (family, slice) when a lane is full or the
-collect deadline is reached — and then ``_flush_held`` decides whether
+collect deadline is reached — and then ``SliceRuntime.held`` decides whether
 the flush leaves now. These tests drive the rule through a live
 instance with a gated scorer (a score plane whose materialization, and
 so its landing, waits on an event): small batches ride out together
@@ -22,7 +22,7 @@ import pytest
 
 from sitewhere_tpu.core.batch import MeasurementBatch
 from sitewhere_tpu.instance import SiteWhereInstance
-from sitewhere_tpu.pipeline.inference import _PendingFlush, _empty_taken
+from sitewhere_tpu.pipeline.slices import _PendingFlush, _empty_taken
 from sitewhere_tpu.runtime.config import (
     FaultTolerancePolicy,
     InstanceConfig,
@@ -140,11 +140,13 @@ def _count(inst, name: str) -> float:
 
 
 def _reap_len(svc, key=KEY) -> int:
-    return len(svc._reap.get(key, ()))
+    s = svc._slices.get(key)   # None before its birth and after the stop
+    return len(s.reap) if s is not None else 0
 
 
 def _lane_rows(svc, key=KEY) -> int:
-    return sum(l.count for l in svc._lanes.get(key, {}).values())
+    s = svc._slices.get(key)
+    return sum(l.count for l in s.lanes.values()) if s is not None else 0
 
 
 def _open(gates) -> None:
@@ -247,19 +249,21 @@ async def test_hold_lifts_on_the_landing_not_on_the_publish():
         await inst.bus.publish(topic, _batch("acme", toks, 1, 0.0))
         await _publish(inst, "acme", toks, SMALL, 100.0)
         assert await _wait_for(
-            lambda: KEY in svc._resolving and _reap_len(svc) == 1)
-        head = svc._reap[KEY][0]
+            lambda: svc._slices[KEY].resolving is not None
+            and _reap_len(svc) == 1)
+        head = svc._slices[KEY].reap[0]
         assert head.landed() and not head.resolved
         await _publish(inst, "acme", toks, SMALL, 200.0)
         assert await _wait_for(lambda: _count(inst, "flushes") == 2), (
             "a landed flush still held the next one")
-        assert svc._reap[KEY][0] is head and not head.resolved
+        assert svc._slices[KEY].reap[0] is head and not head.resolved
         assert _count(inst, "inflight_depth_sum") == 0
         assert _count(inst, "flush_pipelined") == 0
         tp.retention = 65536
         inst.bus.unsubscribe(topic, "stall")
         assert await _wait_for(
-            lambda: not svc._resolving and not _reap_len(svc))
+            lambda: svc._slices[KEY].resolving is None
+            and not _reap_len(svc))
         assert _count(inst, "scored_total") >= 2 * SMALL
     finally:
         inst.bus.unsubscribe(topic, "stall")
@@ -297,8 +301,9 @@ async def test_hold_lifts_when_the_flush_in_flight_is_force_resolved():
         ).value == 1
         assert svc.engines["acme"].placement.shard != 0
         assert "acme" not in svc._fences
-        assert not any(_lane_rows(svc, k) for k in svc._lanes)
-        assert not any(svc._reap.values()) and not svc._batches
+        assert not any(_lane_rows(svc, k) for k in svc._slices)
+        assert not any(_reap_len(svc, k) for k in svc._slices)
+        assert not svc._batches
     finally:
         plan.clear()
         await inst.terminate()
@@ -315,8 +320,9 @@ async def _open_breaker(svc, key) -> None:
 async def _quarantine(svc, key) -> None:
     # the fleet fills every slot, so the tenant cannot fail over and
     # degrades to pass-through on the quarantined slice
-    await svc._quarantine_slice(*key, reason="test")
-    assert key in svc._quarantined and key[0] not in svc._parked
+    await svc._quarantine_slice(svc._slices[key], reason="test")
+    assert svc._slices[key].quarantine is not None
+    assert key[0] not in svc._parked
 
 
 @pytest.mark.parametrize("degrade", [_park, _open_breaker, _quarantine])
@@ -364,8 +370,9 @@ async def test_teardown_strands_neither_the_flush_in_flight_nor_the_held_rows():
         await inst.terminate()
         _open(gates)   # free the executor thread
     assert _count(inst, "scored_total") >= 2 * SMALL
-    assert not svc._batches and not any(svc._reap.values())
-    assert _lane_rows(svc) == 0
+    # the stop resolved every batch, and no slice (its reap queue, its
+    # lanes) outlives the service
+    assert not svc._batches and not svc._slices
 
 
 async def test_two_slices_hold_independently():
@@ -435,7 +442,7 @@ async def test_train_step_in_flight_does_not_hold_a_serve_flush():
     try:
         # a train-lane step in flight on the slice, as _dispatch_train
         # leaves one: its own permit, the reap FIFO, lane="train"
-        await svc._inflight_sem(KEY).acquire()
+        await svc._slices[KEY].permits.acquire()
         pf = _PendingFlush(
             "lstm_ad", GatedScores(np.zeros((4,), np.float32), gate),
             _empty_taken(), 0, False, 0, 0, lane="train",

@@ -471,30 +471,6 @@ def test_check_fusion_catches_per_slot_loop(monkeypatch):
     assert missing and "stale" in missing[0]
 
 
-# ---------------------------------------------------- bench gate wiring
-def test_check_bench_gates_fused_keys():
-    """mfu_32t_pct / fused_speedup_32t classify as gated
-    higher-is-better keys; they report n/a against pre-fusion baselines
-    and regress when they drop >10% against a baseline that has them."""
-    _cb = importlib.util.spec_from_file_location(
-        "check_bench",
-        Path(__file__).resolve().parent.parent / "tools" / "check_bench.py",
-    )
-    cb = importlib.util.module_from_spec(_cb)
-    _cb.loader.exec_module(cb)
-    assert cb.classify("mfu_32t_pct") == "throughput"
-    assert cb.classify("fused_speedup_32t") == "throughput"
-    assert cb.classify("tenants32_mfu_pct") == "info"  # legacy key untouched
-    _rows, reg = cb.compare(
-        {"mfu_32t_pct": 1.5, "fused_speedup_32t": 2.4}, {"value": 1.0}
-    )
-    assert not reg
-    _rows, reg = cb.compare(
-        {"fused_speedup_32t": 1.0}, {"fused_speedup_32t": 2.4}
-    )
-    assert [r["key"] for r in reg] == ["fused_speedup_32t"]
-
-
 # ------------------------------------------------- flightrec attribution
 async def test_flightrec_records_kernel_variant():
     """Per-flush blackbox records carry k_steps/param_dtype so incident

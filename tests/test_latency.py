@@ -12,8 +12,7 @@ the flight-recorder stride without resetting it; (f) the
 ``tpu_flush_latency_p99_ms`` live gauge + history allowlist wiring;
 (g) trace/priority stamp propagation through replay-published batches,
 DLQ entries and requeue, and retry continuity; (h) the check_metrics
-queue-wait-twin lint; (i) the check_bench latency key class and its
-gate (doctored +30% ``p99_e2e_ms`` exits 1); and (j) the live REST
+queue-wait-twin lint; and (j) the live REST
 acceptance — ``/api/latency`` decomposition reconciling with the
 measured e2e p99 within 15% on a driven instance."""
 
@@ -70,7 +69,6 @@ def _load_tool(name: str):
     return mod
 
 
-check_bench = _load_tool("check_bench")
 check_metrics = _load_tool("check_metrics")
 
 
@@ -403,12 +401,12 @@ def test_forced_tail_stage_records_beat_the_stride_without_resetting_it():
 
 # ------------------------- (f) flush-latency gauge + history allowlist
 def test_flush_latency_gauge_and_history_wiring():
-    from sitewhere_tpu.pipeline.inference import TpuInferenceService
+    from sitewhere_tpu.pipeline.slices import SliceRuntime
 
     reg = MetricsRegistry()
-    svc = types.SimpleNamespace(_flush_p99={}, metrics=reg)
+    home = SliceRuntime("lstm_ad", 0, None, None, reg, 2, 2)
     for _ in range(10):
-        TpuInferenceService._note_device_s(svc, ("lstm_ad", 0), 0.005)
+        home.note_device_s(0.005)
     g = reg.gauge("tpu_flush_latency_p99_ms", family="lstm_ad", slice="0")
     assert g.value == pytest.approx(5.0, rel=0.02)
     # the history sampler keeps the attribution families by default, and
@@ -556,50 +554,6 @@ def test_check_metrics_queue_wait_twin_rule():
                   stage="outbound").record(0.01)
     errs = check_metrics.lint_exposition(reg.prometheus_text())
     assert len(errs) == 1 and 't2' in errs[0] and "outbound" in errs[0]
-
-
-# ----------------------- (i) check_bench latency key class and the gate
-def test_check_bench_latency_class_and_gate_exit(tmp_path):
-    assert check_bench.classify("p99_e2e_ms") == "p99"
-    assert check_bench.classify("p99_lane_wait_ms") == "p99"
-    assert check_bench.classify("p99_flush_assembly_ms") == "p99"
-    # the info keys stay info: residual and overhead report, never gate
-    assert check_bench.classify("latency_residual_ms") == "info"
-    assert check_bench.classify("latency_overhead_pct") == "info"
-
-    base = {
-        "metric": "e2e", "value": 1000.0, "p99_e2e_ms": 20.0,
-        "p99_lane_wait_ms": 8.0, "latency_residual_ms": 1.0,
-        "latency_overhead_pct": 0.1,
-    }
-    rows, regs = check_bench.compare(dict(base), base)
-    assert regs == []  # self-baseline is clean
-    doctored = dict(base, p99_e2e_ms=26.0)  # +30%, past the 25% gate
-    rows, regs = check_bench.compare(doctored, base)
-    assert [r["key"] for r in regs] == ["p99_e2e_ms"]
-    # info keys never gate, even on wild swings
-    rows, regs = check_bench.compare(
-        dict(base, latency_residual_ms=50.0, latency_overhead_pct=9.0),
-        base,
-    )
-    assert regs == []
-    # new paced columns against an old baseline read n/a, not a gate
-    old = {k: v for k, v in base.items() if not k.startswith("p99_")}
-    rows, regs = check_bench.compare(base, old)
-    assert regs == []
-    status = {r["key"]: r["status"] for r in rows}
-    assert status["p99_e2e_ms"] == "n/a"
-    assert status["p99_lane_wait_ms"] == "n/a"
-
-    # CLI contract: self-baseline exits 0, doctored +30% exits 1
-    bp = tmp_path / "BENCH_r001.json"
-    bp.write_text(json.dumps(base))
-    sp = tmp_path / "self.json"
-    sp.write_text(json.dumps(base))
-    fp = tmp_path / "doctored.json"
-    fp.write_text(json.dumps(doctored))
-    assert check_bench.main([str(sp), "--baseline", str(bp)]) == 0
-    assert check_bench.main([str(fp), "--baseline", str(bp)]) == 1
 
 
 # ------------------------------------------ (j) live REST reconciliation

@@ -216,11 +216,9 @@ async def test_mesh_slices_flush_concurrently_with_own_staging():
         fleets = await _build(inst)
         await _drive(inst, fleets)
         svc = inst.inference
-        staged_slices = {k[1] for k in svc._staging}
-        assert staged_slices == {0, 1, 2, 3}, svc._staging.keys()
-        assert {k for k in svc._reap} == {
-            ("lstm_ad", sl) for sl in range(4)
-        }
+        staged_slices = {s.sl for s in svc._slices.values() if s.staging}
+        assert staged_slices == {0, 1, 2, 3}, staged_slices
+        assert set(svc._slices) == {("lstm_ad", sl) for sl in range(4)}
         # per-device deliver gauges exported (zero when drained)
         for sl in range(4):
             g = inst.metrics.gauge(

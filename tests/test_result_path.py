@@ -117,6 +117,16 @@ class PoisonScores:
         raise RuntimeError("poisoned d2h transfer (chaos)")
 
 
+def _slice(svc, family: str, sl: int = 0):
+    """The (family, slice)'s SliceRuntime, or None before its birth."""
+    return svc._slices.get((family, sl))
+
+
+def _reap_len(svc, family: str, sl: int = 0) -> int:
+    s = _slice(svc, family, sl)
+    return len(s.reap) if s is not None else 0
+
+
 def _gate_family(svc, family: str) -> threading.Event:
     scorer = svc.scorers[family]
     gate = threading.Event()
@@ -206,11 +216,11 @@ async def test_out_of_order_across_families():
         await inst.bus.publish(
             inst.bus.naming.inbound_events("slowt"), _batch("slowt", toks_s, 16)
         )
-        assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1)
+        assert await _wait_for(lambda: _reap_len(svc, "lstm_ad") == 1)
         await inst.bus.publish(
             inst.bus.naming.inbound_events("fastt"), _batch("fastt", toks_f, 16)
         )
-        assert await _wait_for(lambda: len(svc._reap.get(("deepar", 0), [])) == 1)
+        assert await _wait_for(lambda: _reap_len(svc, "deepar") == 1)
         gate_fast.set()  # only the NEWER family's transfer lands
         got_fast: list = []
 
@@ -220,7 +230,7 @@ async def test_out_of_order_across_families():
 
         assert await _poll(fast_arrived), "fast family blocked behind slow"
         # the slow family is STILL in flight — nothing delivered for it
-        assert len(svc._reap.get(("lstm_ad", 0), [])) == 1
+        assert _reap_len(svc, "lstm_ad") == 1
         assert not await drain_slow()
         gate_slow.set()
         got_slow: list = []
@@ -272,14 +282,14 @@ async def test_in_order_per_tenant_within_family():
             inst.bus.naming.inbound_events("acme"),
             _batch("acme", toks, 8, base=100.0),
         )
-        assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1)
+        assert await _wait_for(lambda: _reap_len(svc, "lstm_ad") == 1)
         # a lane at the smallest bucket (32) does not wait for the flush
         # in flight: the second flush joins it through the policy's exit
         await inst.bus.publish(
             inst.bus.naming.inbound_events("acme"),
             _batch("acme", toks, MB.buckets[0], base=200.0),
         )
-        assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 2)
+        assert await _wait_for(lambda: _reap_len(svc, "lstm_ad") == 2)
         assert len(gates) == 2
         gates[1].set()  # flush 2 lands first...
         await asyncio.sleep(0.3)
@@ -326,14 +336,14 @@ async def test_failed_dispatch_stays_fifo_per_tenant():
             inst.bus.naming.inbound_events("acme"),
             _batch("acme", toks, 8, base=100.0),
         )
-        assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1)
+        assert await _wait_for(lambda: _reap_len(svc, "lstm_ad") == 1)
         # (a full smallest bucket, so it dispatches beside the gated one)
         await inst.bus.publish(
             inst.bus.naming.inbound_events("acme"),
             _batch("acme", toks, MB.buckets[0], base=200.0),
         )
         # the failed flush queues as a poisoned entry BEHIND the gated one
-        assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 2)
+        assert await _wait_for(lambda: _reap_len(svc, "lstm_ad") == 2)
         await asyncio.sleep(0.3)
         assert not await drain(), "failed flush overtook the in-flight one"
         gate.set()
@@ -385,8 +395,8 @@ async def test_blocked_publish_does_not_stall_other_families():
         # the resolve task is now blocked INSIDE its publish: the flush
         # stays at the head of its queue (it only leaves on resolution)
         assert await _wait_for(
-            lambda: ("lstm_ad", 0) in svc._resolving
-            and len(svc._reap.get(("lstm_ad", 0), [])) == 1
+            lambda: _slice(svc, "lstm_ad").resolving is not None
+            and _reap_len(svc, "lstm_ad") == 1
         )
         await asyncio.sleep(0.2)  # give a head-of-line bug time to wedge
         await inst.bus.publish(
@@ -403,7 +413,7 @@ async def test_blocked_publish_does_not_stall_other_families():
             "healthy family stalled behind another family's full "
             "scored topic"
         )
-        assert ("lstm_ad", 0) in svc._resolving, (
+        assert _slice(svc, "lstm_ad").resolving is not None, (
             "slow family resolved despite its wedged topic"
         )
         # unwedge: the pinned group leaves → the publish unblocks and the
@@ -411,7 +421,8 @@ async def test_blocked_publish_does_not_stall_other_families():
         tp.retention = 65536
         inst.bus.unsubscribe(topic_s, "stall")
         assert await _wait_for(
-            lambda: not svc._resolving and not svc._reap.get(("lstm_ad", 0))
+            lambda: _slice(svc, "lstm_ad").resolving is None
+            and not _reap_len(svc, "lstm_ad")
         )
         assert inst.metrics.counter("tpu_inference.scored_total").value >= 32
     finally:
@@ -453,7 +464,9 @@ async def test_poisoned_transfer_resolves_unscored():
             "breaker never saw the transfer failure"
         )
         assert not svc._batches, "stranded batch registry entries"
-        assert not any(svc._reap.values()), "reap queue left non-empty"
+        assert not any(s.reap for s in svc._slices.values()), (
+            "reap queue left non-empty"
+        )
     finally:
         await inst.terminate()
 
@@ -473,7 +486,7 @@ async def test_teardown_with_stuck_transfer_loses_nothing():
         await inst.bus.publish(
             inst.bus.naming.inbound_events("acme"), _batch("acme", toks, 10)
         )
-        assert await _wait_for(lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1)
+        assert await _wait_for(lambda: _reap_len(svc, "lstm_ad") == 1)
         assert scored.value == 0
     finally:
         await inst.terminate()
@@ -483,8 +496,9 @@ async def test_teardown_with_stuck_transfer_loses_nothing():
         "stuck-transfer rows vanished at teardown"
     )
     assert not svc._batches
-    assert not any(svc._reap.values())
-    assert svc._last_scores == {}, "teardown left device scores pinned"
+    # no slice outlives the service: no reap queue left non-empty, no
+    # device scores left pinned
+    assert svc._slices == {}, "teardown left a slice (and what it pins)"
 
 
 async def test_result_path_metrics_flow():
@@ -514,7 +528,7 @@ async def test_result_path_metrics_flow():
         # the probe holds nothing once the family went idle (no leak of
         # a full flush of device score memory)
         assert await _wait_for(
-            lambda: ("lstm_ad", 0) not in inst.inference._last_scores
+            lambda: _slice(inst.inference, "lstm_ad").last_scores is None
         )
     finally:
         await inst.terminate()

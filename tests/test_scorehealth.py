@@ -11,6 +11,7 @@ score_drift while the healthy tenants stay quiet."""
 import asyncio
 import importlib.util
 import json
+import time
 from contextlib import asynccontextmanager
 from pathlib import Path
 
@@ -586,10 +587,23 @@ async def test_e2e_drift_fires_watchdog_healthy_tenants_quiet():
     async with _drift_instance() as (inst, tenants):
         rng = np.random.default_rng(3)
         ticks = {t: 0 for t in tenants}
+        scored = inst.metrics.counter("tpu_inference.scored_total")
+        sent = 0
+
+        async def until(cond, timeout_s=30.0, tick=0.01) -> bool:
+            t_end = time.monotonic() + timeout_s
+            while not cond() and time.monotonic() < t_end:
+                await asyncio.sleep(tick)
+            return bool(cond())
 
         async def burst(drifting=None):
             # one event per device per tenant → every stream contributes
-            # one row per flush (paced traffic, not a replay burst)
+            # one row per flush (paced traffic, not a replay burst): the
+            # next burst waits until this one is scored, so that a host
+            # shared with five other test workers cannot pile two rows
+            # of a stream into one flush (where they would share the
+            # newest window's score and PSI would read noise)
+            nonlocal sent
             for t in tenants:
                 j = ticks[t]
                 ticks[t] += 1
@@ -609,16 +623,24 @@ async def test_e2e_drift_fires_watchdog_healthy_tenants_quiet():
                             "name": "temperature", "value": v,
                         }).encode(),
                     )
-            await asyncio.sleep(0.015)
+                    sent += 1
+            await until(lambda: scored.value >= sent, 5.0, tick=0.003)
+            await asyncio.sleep(0.01)
 
         for _ in range(160):             # phase 1: references freeze
             await burst()
-        await asyncio.sleep(0.6)
-        for t in tenants:
-            assert inst.scorehealth.health_report(t)["reference_rows"] > 0
+        assert await until(lambda: all(
+            inst.scorehealth.health_report(t)["reference_rows"] > 0
+            for t in tenants
+        )), "a tenant's drift reference never froze"
         for _ in range(100):             # phase 2: drifty regime-changes
             await burst(drifting="drifty")
-        await asyncio.sleep(0.8)
+        # the alert needs PSI sustained over drift_window watchdog ticks
+        # after the verdict turns: wait for the end of the chain
+        assert await until(lambda: any(
+            s["reason"] == "watchdog:score_drift"
+            for s in inst.flightrec.snapshot_summaries()
+        )), inst.tenant_health_report("drifty")
 
         rep = inst.tenant_health_report("drifty")
         assert rep["verdict"] == "drifting" and rep["psi"] > 1.0

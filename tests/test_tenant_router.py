@@ -162,7 +162,7 @@ async def test_failover_slice_move_keeps_per_tenant_fifo():
             _batch("acme", toks, 8, base=100.0),
         )
         assert await _wait_for(
-            lambda: len(svc._reap.get(("lstm_ad", 0), [])) == 1
+            lambda: len(svc._slices[("lstm_ad", 0)].reap) == 1
         )
         # the move: slice 0 → slice 1 with batch 1 still unresolved
         assert await svc._failover_tenant(engine)
@@ -192,7 +192,7 @@ async def test_failover_slice_move_keeps_per_tenant_fifo():
             "post-move rows were not scored on the new slice"
         )
         assert "acme" not in svc._fences
-        assert not svc._reap.get(("lstm_ad", 0))
+        assert not svc._slices[("lstm_ad", 0)].reap
     finally:
         gate.set()
         await inst.terminate()

@@ -30,6 +30,7 @@ from sitewhere_tpu.runtime.config import (
     TrainingConfig,
 )
 from sitewhere_tpu.sim import DeviceSimulator, SimProfile
+from tests._drive import drive_rounds
 
 _spec = importlib.util.spec_from_file_location(
     "check_fusion_tl",
@@ -37,13 +38,6 @@ _spec = importlib.util.spec_from_file_location(
 )
 check_fusion = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(check_fusion)
-
-_cb_spec = importlib.util.spec_from_file_location(
-    "check_bench_tl",
-    Path(__file__).resolve().parent.parent / "tools" / "check_bench.py",
-)
-check_bench = importlib.util.module_from_spec(_cb_spec)
-_cb_spec.loader.exec_module(check_bench)
 
 W, HID = 8, 8
 
@@ -133,10 +127,30 @@ def test_fused_vs_legacy_grad_parity_other_families(family):
         s.activate(0, trainable=True)
         _warm(s)
         s.init_optimizer()
+    paths = [
+        jax.tree_util.keystr(k)
+        for k, _ in jax.tree_util.tree_flatten_with_path(a.params)[0]
+    ]
+    before = [x.copy() for x in _leaves(a.params)]
     la = np.asarray(a.train_lane_step())
     lb = np.asarray(b.train_resident())
     np.testing.assert_allclose(la, lb, rtol=1e-4, atol=1e-5)
-    for x, y in zip(_leaves(a.params), _leaves(b.params)):
+    for path, p0, x, y in zip(
+        paths, before, _leaves(a.params), _leaves(b.params)
+    ):
+        if path.endswith("['wk']['b']"):
+            # attention's KEY bias has no gradient: one bias on every key
+            # shifts all of a query's logits alike, and softmax cancels
+            # it. What either program computes there is rounding noise
+            # of a few 1e-8 around zero, and Adam's first step — g /
+            # (|g| + 1e-8) at lr 1 — turns noise of that size into an
+            # update anywhere inside (-1, 1): two correct programs differ
+            # there by up to 0.08. A real gradient moves a parameter by
+            # the whole lr; hold both twins to "no real gradient here"
+            # (|g| < 1e-6) instead of to each other.
+            assert np.abs(x - p0).max() < 0.99, path
+            assert np.abs(y - p0).max() < 0.99, path
+            continue
         np.testing.assert_allclose(x, y, rtol=1e-3, atol=1e-4)
 
 
@@ -226,7 +240,9 @@ async def test_kill_switch_service_path_stays_inline(monkeypatch):
         assert m.counter(
             "tpu_train_swaps_total", family="lstm_ad"
         ).value == 0
-        assert not inst.inference._train_lanes
+        assert not any(
+            s.train_lanes for s in inst.inference._slices.values()
+        )
         # losses land via the inline path (device array, not reaper np)
         assert ("lstm_ad", eng.placement.shard) in (
             inst.inference.last_train_losses
@@ -255,12 +271,13 @@ async def test_hot_swap_arms_canary_and_flightrec_lane():
         )
         m = inst.metrics
         swaps = m.counter("tpu_train_swaps_total", family="lstm_ad")
-        for r in range(60):
-            await sim.publish_round(float(r) * 0.5)
-            await asyncio.sleep(0.005)
-            if swaps.value >= 2:
-                break
-        assert await _wait_for(lambda: swaps.value >= 1)
+        assert await drive_rounds(inst, sim, lambda: swaps.value >= 1), (
+            m.counter("tpu_inference.train_steps").value,
+            m.counter(
+                "tpu_train_skipped_total", family="lstm_ad",
+                reason="saturated",
+            ).value,
+        )
         eng = inst.inference.engines["acme"]
         scorer = inst.inference.scorers[("lstm_ad", eng.placement.shard)]
         assert scorer.train_lane
@@ -393,12 +410,10 @@ async def test_saturated_slice_defers_training_without_stalling_siblings():
         scored = m.counter("tpu_inference.scored_total")
         await _wait_for(lambda: scored.value > 0)
         await asyncio.sleep(0.2)
-        sem = svc._inflight_sem(key_a)
+        sem = svc._slices[key_a].permits
         for _ in range(svc.max_inflight):
             await sem.acquire()
-        svc._train_ticks.setdefault(key_a, {})[
-            a_eng.placement.slot
-        ] = 10_000
+        svc._slices[key_a].train_ticks[a_eng.placement.slot] = 10_000
         a_steps0 = m.counter("tpu_train_steps_total", tenant="acme").value
         sat = m.counter(
             "tpu_train_skipped_total", family="lstm_ad",
@@ -603,7 +618,7 @@ async def test_inline_step_on_mixed_stack_commits_pending_lane_steps():
         await inst.bus.publish(topic, _history_batch(128, now, "lane"))
         lane_steps = m.counter("tpu_train_steps_total", tenant="lane")
         assert await _wait_for(lambda: lane_steps.value >= 1)
-        assert await _wait_for(lambda: svc._lane_swap.get(key, 0) > 0)
+        assert await _wait_for(lambda: svc._slices[key].lane_swap > 0)
         swaps = m.counter("tpu_train_swaps_total", family="lstm_ad")
         s0 = swaps.value
         # the inline tenant's cadence fires off serve flushes
@@ -611,7 +626,7 @@ async def test_inline_step_on_mixed_stack_commits_pending_lane_steps():
         assert await _wait_for(lambda: swaps.value > s0), (
             "inline sidecar invalidation bypassed the swap contract"
         )
-        assert svc._lane_swap.get(key, 1) == 0
+        assert svc._slices[key].lane_swap == 0
         rings = inst.flightrec.describe()["rings"]
         srecs = [
             r for v in rings.get("swap", {}).values()
@@ -643,18 +658,16 @@ async def test_slice_move_drops_stale_train_rows():
             "tpu_inference_train_rows", family=eng.config.model
         )
         assert await _wait_for(lambda: gauge.value >= 256)
-        svc._train_ticks.setdefault(key_old, {})[old_p.slot] = 9_999
+        old = svc._slices[key_old]
+        old.train_ticks[old_p.slot] = 9_999
         assert await svc._failover_tenant(eng)
         assert eng.placement.shard != old_p.shard or (
             eng.placement.slot != old_p.slot
         )
-        stale = [
-            k for k in svc._train_lanes.get(key_old, {})
-            if k[0] == old_p.slot
-        ]
+        stale = [k for k in old.train_lanes if k[0] == old_p.slot]
         assert not stale, "train rows survived the slice move"
         assert gauge.value == 0
-        assert old_p.slot not in svc._train_ticks.get(key_old, {}), (
+        assert old_p.slot not in old.train_ticks, (
             "stale cadence tick survived the move"
         )
     finally:
@@ -844,26 +857,3 @@ def test_train_fusion_lint_catches_stale_registry():
     assert findings and "loss_stacked" in findings[0]
     findings = check_fusion.lint_train_fusion({"no_such_family": {}})
     assert findings and "not in MODEL_REGISTRY" in findings[0]
-
-
-def test_check_bench_train_keys_classify_and_gate():
-    """train_ev_s gates as throughput (suffix rule); the p99 delta ratio
-    gates lower-is-better by name; both report n/a against baselines
-    that predate the lane."""
-    assert check_bench.classify("train_ev_s") == "throughput"
-    assert check_bench.classify("serve_p99_train_delta") == "p99"
-    base = {"metric": "x", "train_ev_s": 1000.0,
-            "serve_p99_train_delta": 1.0}
-    fresh_ok = {"metric": "x", "train_ev_s": 950.0,
-                "serve_p99_train_delta": 1.08}
-    _rows, reg = check_bench.compare(fresh_ok, base)
-    assert not reg
-    fresh_bad = {"metric": "x", "train_ev_s": 500.0,
-                 "serve_p99_train_delta": 1.5}
-    _rows, reg = check_bench.compare(fresh_bad, base)
-    assert {r["key"] for r in reg} == {
-        "train_ev_s", "serve_p99_train_delta"
-    }
-    # new keys vs a pre-lane baseline: n/a, never gates
-    _rows, reg = check_bench.compare(fresh_bad, {"metric": "x"})
-    assert not reg

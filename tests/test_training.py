@@ -17,6 +17,7 @@ from sitewhere_tpu.runtime.config import (
     TrainingConfig,
 )
 from sitewhere_tpu.sim import DeviceSimulator, SimProfile
+from tests._drive import drive_rounds
 
 
 async def _training_instance(every_n=2):
@@ -52,21 +53,20 @@ async def test_pipeline_trains_and_model_adapts():
                        noise=0.01, period_s=4.0),
             topic_pattern="sitewhere/input/{device}",
         )
-        scored = inst.metrics.counter("tpu_inference.scored_total")
         trains = inst.metrics.counter("tpu_inference.train_steps")
         first_loss = None
-        for r in range(120):
-            await sim.publish_round(float(r) * 0.5)
-            await asyncio.sleep(0.005)
+
+        def note_first_loss():
+            nonlocal first_loss
             if first_loss is None and "lstm_ad" in inst.inference.last_train_losses:
                 first_loss = float(np.asarray(
                     inst.inference.last_train_losses["lstm_ad"]
                 ).max())
-        for _ in range(200):
-            if scored.value >= sim.sent:
-                break
-            await asyncio.sleep(0.02)
-        assert trains.value > 3, "training cadence never fired"
+
+        assert await drive_rounds(
+            inst, sim, lambda: trains.value > 3, rounds=120,
+            each=note_first_loss,
+        ), f"training cadence never fired: {trains.value} train steps"
         # params measurably diverged from the pristine base
         engine = inst.inference.engines["acme"]
         scorer = inst.inference.scorers[
@@ -102,15 +102,10 @@ async def test_udf_uses_live_tenant_params():
                        noise=0.01, period_s=4.0),
             topic_pattern="sitewhere/input/{device}",
         )
-        for r in range(80):
-            await sim.publish_round(float(r) * 0.5)
-            await asyncio.sleep(0.005)
         trains = inst.metrics.counter("tpu_inference.train_steps")
-        for _ in range(100):
-            if trains.value >= 3:
-                break
-            await asyncio.sleep(0.05)
-        assert trains.value >= 3
+        assert await drive_rounds(
+            inst, sim, lambda: trains.value >= 3, rounds=80
+        ), f"training cadence never fired: {trains.value} train steps"
         cfg = {"hidden": 16, "window": 16}
         live = ModelUdf("lstm_ad", cfg).bind_params_source(
             inst.inference.params_source("acme")

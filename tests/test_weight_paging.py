@@ -255,22 +255,17 @@ async def test_eviction_drops_pending_train_rows_counted():
         await _add(svc, bus, "ts")
         eng = svc.engines["tr"]
         p = eng.placement
-        from sitewhere_tpu.pipeline.inference import _TrainLaneRing
-
-        ring = _TrainLaneRing(64)
         n = 12
-        ring.push(
+        home = svc._slices[("lstm_ad", p.shard)]
+        home.train_lane(p.slot, 0).push(
             np.zeros((n,), np.int32), np.ones((n,), np.float32),
             np.int64(-1), np.full((n,), -1, np.int32),
         )
-        svc._train_lanes.setdefault(("lstm_ad", p.shard), {})[
-            (p.slot, 0)
-        ] = ring
         svc._page_out(eng)
         assert svc.metrics.counter(
             "tpu_paging.train_rows_dropped", family="lstm_ad"
         ).value == n
-        assert not svc._train_lanes.get(("lstm_ad", p.shard))
+        assert not home.train_lanes
         blob = svc.pager.cache.get("tr")
         assert blob is not None and blob[1] is True, (
             "train-lane page-out must write back dirty"
@@ -291,7 +286,9 @@ async def test_quarantine_slice_with_paged_out_tenants():
         ghost = svc.engines["qc"]
         assert ghost.placement.slot < 0
         sick = ghost.placement.shard
-        await svc._quarantine_slice("lstm_ad", sick, "test-kill")
+        await svc._quarantine_slice(
+            svc._slices[("lstm_ad", sick)], "test-kill"
+        )
         assert ghost.placement.slot < 0, "ghost must stay virtual"
         assert ghost.placement.shard != sick, "ghost still on dead slice"
         assert svc.metrics.counter(

@@ -2,7 +2,7 @@
 """Run every analyzer in ``tools/`` as one suite: one table, one JSON
 findings document, one exit code.
 
-The seven analyzers (docs/STATIC_ANALYSIS.md has the full catalog):
+The six analyzers (docs/STATIC_ANALYSIS.md has the full catalog):
 
 ===============  ====================================================
 check_async      five async-safety rules over the package call graph
@@ -11,7 +11,6 @@ check_queues     bounded-queue depth/shed observability registry
 check_supervised deadline supervision on device awaits
 check_fusion     fused-kernel lowering invariants (jaxpr traces)
 check_metrics    Prometheus exposition conformance (live scrape)
-check_bench      bench headline regression gate (post-bench only)
 ===============  ====================================================
 
 Modes:
@@ -22,11 +21,8 @@ Modes:
 - ``python tools/lint_all.py --fast`` — the pure-AST/regex analyzers
   only (async, hotpath, queues, supervised): ~1 s cold (the package
   parse + call-graph build), sub-second once the shared ``astlib``
-  parse cache is warm; this is what tier-1 and bench.py run.
+  parse cache is warm; this is what tier-1 runs.
 - ``--json PATH`` — machine-readable findings (``-`` = stdout).
-- ``--bench-headline PATH`` — also run the check_bench gate against a
-  fresh headline (otherwise it reports ``skipped``: the gate is a
-  post-bench driver step, not a source lint).
 
 Exit code: 1 iff any non-skipped analyzer produced findings (or
 crashed — an analyzer that cannot run is a failure, not a skip).
@@ -38,7 +34,7 @@ import json
 import os
 import sys
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List
 
 _TOOLS_DIR = os.path.dirname(os.path.abspath(__file__))
 if _TOOLS_DIR not in sys.path:
@@ -122,36 +118,11 @@ _RUNNERS: Dict[str, Callable[[], List[dict]]] = {
 }
 
 
-def _run_bench_gate(headline_path: str) -> List[dict]:
-    import check_bench
-
-    fresh = check_bench.load_headline(headline_path)
-    base_path = check_bench.newest_baseline_path()
-    if base_path is None:
-        return []
-    baseline = check_bench.load_headline(base_path)
-    _rows, regressions = check_bench.compare(fresh, baseline)
-    return [
-        {
-            "tool": "check_bench",
-            "msg": (
-                f"{r['key']}: {r['baseline']} -> {r['fresh']} "
-                f"({r['delta_pct']:+.1f}%) vs "
-                f"{os.path.basename(base_path)}"
-            ),
-        }
-        for r in regressions
-    ]
-
-
-def run_all(
-    fast: bool = False,
-    bench_headline: Optional[str] = None,
-) -> List[Dict]:
+def run_all(fast: bool = False) -> List[Dict]:
     """Run the suite; returns one report row per analyzer:
     ``{"tool", "status": "ok"|"fail"|"error"|"skipped", "findings",
     "wall_s", "note"}``. ``fast`` limits to the pure-AST analyzers
-    (the tier-1 / bench configuration)."""
+    (the tier-1 configuration)."""
     reports: List[Dict] = []
     for tool in (*FAST_TOOLS, *SLOW_TOOLS):
         if fast and tool in SLOW_TOOLS:
@@ -174,28 +145,6 @@ def run_all(
         reports.append({
             "tool": tool, "status": status, "findings": findings,
             "wall_s": round(time.perf_counter() - t0, 3), "note": note,
-        })
-    t0 = time.perf_counter()
-    if bench_headline:
-        try:
-            findings = _run_bench_gate(bench_headline)
-            reports.append({
-                "tool": "check_bench",
-                "status": "ok" if not findings else "fail",
-                "findings": findings,
-                "wall_s": round(time.perf_counter() - t0, 3), "note": "",
-            })
-        except Exception as exc:  # noqa: BLE001
-            reports.append({
-                "tool": "check_bench", "status": "error", "findings": [],
-                "wall_s": round(time.perf_counter() - t0, 3),
-                "note": repr(exc),
-            })
-    else:
-        reports.append({
-            "tool": "check_bench", "status": "skipped", "findings": [],
-            "wall_s": 0.0,
-            "note": "post-bench gate (pass --bench-headline)",
         })
     return reports
 
@@ -221,12 +170,9 @@ def main(argv=None) -> int:
                     help="pure-AST analyzers only (tier-1 configuration)")
     ap.add_argument("--json", default="",
                     help="write findings JSON to PATH ('-' = stdout)")
-    ap.add_argument("--bench-headline", default="",
-                    help="fresh bench headline to gate with check_bench")
     args = ap.parse_args(argv)
 
-    reports = run_all(fast=args.fast,
-                      bench_headline=args.bench_headline or None)
+    reports = run_all(fast=args.fast)
     print(format_table(reports), file=sys.stderr)
     for r in reports:
         for f in r["findings"]:

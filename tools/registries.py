@@ -62,8 +62,6 @@ HOT_PATHS: Dict[str, List[str]] = {
         "TpuInferenceService._pack_train",
         "TpuInferenceService._train_lane_tick",
         "TpuInferenceService._dispatch_train",
-        "_LaneRing.push",
-        "_LaneRing.pop_into",
         "_SliceFence.park",
         # weight paging: the evict path runs synchronously ON the event
         # loop (no await may split the commit section) and the per-pass
@@ -72,6 +70,17 @@ HOT_PATHS: Dict[str, List[str]] = {
         # single loop-thread host_copy the donation hazard requires
         "TpuInferenceService._page_out",
         "TpuInferenceService._paging_tick",
+    ],
+    # what a (family, mesh slice) is made of: the lane rings take every
+    # row at enqueue and give it up at flush; the slice's own rules run
+    # per scoring-loop pass (due/held) and per flush (the staging set)
+    "pipeline/slices.py": [
+        "_LaneRing.push",
+        "_LaneRing.pop_into",
+        "SliceRuntime.due",
+        "SliceRuntime.held",
+        "SliceRuntime.in_flight",
+        "SliceRuntime.staging_set",
     ],
     # the weight-paging bookkeeping runs per enqueue (touch/hit-rate) and
     # per page-in/evict: pure dict/deque ops, no per-row Python, no
@@ -202,13 +211,22 @@ QUEUE_REGISTRY: Dict[Tuple[str, str], Dict[str, str]] = {
         "backpressure_counter": "media.decode_backpressure",
     },
     ("pipeline/inference.py", r"_LaneRing\("): {
+        "queue": "slice-move fence stash (_SliceFence: a re-placed or "
+                 "paged-out tenant's parked rows per data shard)",
+        # open fences; the rows they hold count against the tenant's
+        # lane watermark, so a long fence backpressures like a full lane
+        "depth_gauge": "tpu_inference_fences",
+        "parked_counter": "tpu_inference.fenced_rows",
+        "backpressure_counter": "tpu_inference.lane_backpressure",
+    },
+    ("pipeline/slices.py", r"_LaneRing\("): {
         "queue": "scoring lane rings (pending rows per (slot, data-shard))",
         "depth_gauge": "tpu_inference_lane_rows",
         # lanes never shed: the per-tenant watermark backpressures intake
         # into the bus (where lag is a gauge and drives overload credit)
         "backpressure_counter": "tpu_inference.lane_backpressure",
     },
-    ("pipeline/inference.py", r"_TrainLaneRing\("): {
+    ("pipeline/slices.py", r"_TrainLaneRing\("): {
         "queue": "continual-learning train lane rings (replay-fed "
                  "training rows per (slot, data-shard); watermark "
                  "2 × replay_microbatch)",
@@ -227,7 +245,7 @@ QUEUE_REGISTRY: Dict[Tuple[str, str], Dict[str, str]] = {
         # scanner through the ring instead of buffering the store
         "backpressure_counter": "replay.ring_backpressure",
     },
-    ("pipeline/inference.py", r"_ReapQueue\("): {
+    ("pipeline/slices.py", r"_ReapQueue\("): {
         "queue": "deliver reap queues (in-flight flush completions per "
                  "(family, mesh slice); bounded by the max_inflight "
                  "semaphore)",
@@ -243,7 +261,7 @@ QUEUE_REGISTRY: Dict[Tuple[str, str], Dict[str, str]] = {
         # completions never shed: a full in-flight window backpressures
         # the NEXT flush at the semaphore (counted before the acquire)
         "backpressure_counter": "tpu_inference.deliver_backpressure",
-        # the flush policy in front of the semaphore (_flush_held): a
+        # the flush policy in front of the semaphore (SliceRuntime.held): a
         # due flush waits for the one in flight while every lane is
         # under the smallest bucket (passes held), and joins an occupied
         # device only from the smallest bucket up (flushes pipelined)
@@ -286,7 +304,7 @@ QUEUE_REGISTRY: Dict[Tuple[str, str], Dict[str, str]] = {
         "depth_gauge": "tpu_paging_pending",
         "shed_counter": "tpu_paging.prefetch_shed",
     },
-    ("pipeline/inference.py", r"\[_StagingSet\("): {
+    ("pipeline/slices.py", r"\[_StagingSet\("): {
         "queue": "per-(family, mesh-slice, bucket) rotating flush "
                  "staging sets (bounded by staging_slots per rotation)",
         "depth_gauge": "tpu_inference_staging_sets",
@@ -587,7 +605,7 @@ THREAD_SHARED: Dict[str, List[Dict[str, object]]] = {
             "locks": ["_decode_lock", "_pool_lock"],
         },
     ],
-    "pipeline/inference.py": [
+    "pipeline/slices.py": [
         {
             "class": "_PendingFlush",
             "executor_fns": ["_PendingFlush._materialize"],
