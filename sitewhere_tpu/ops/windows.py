@@ -21,6 +21,15 @@ Design constraints (why it looks the way it does):
   wants — four passes over the state a step. ``init_window_state``,
   ``_apply_update``, ``gather_windows`` and ``ring_values`` are the only
   places that know the physical shape.
+- **Windows without an element gather.** Where W divides 128 a ring never
+  straddles a row, so ``gather_windows`` fetches each row's 128-lane store
+  row (one row gather) and takes the window out of it with elementwise
+  work only: selects pick the ring's W lanes, log2(W) static rolls, each
+  under one bit of ``pos``, turn it into time order, one more select
+  left-pads a short history. A TPU gathers single elements one at a time
+  — the ``take_along_axis`` this replaced was 10.7 ms of a 27 ms step for
+  a 32 x 1,024 x 32 plane (PERF.md section 6, PR 37). Any other W keeps
+  the element gather.
 - **Duplicate streams per batch.** One micro-batch routinely carries several
   samples of the same series. A plain scatter would be order-ambiguous, so
   we compute each row's *rank among same-stream rows* (sort + segment rank,
@@ -196,10 +205,36 @@ def gather_windows(
     slot = (pos[:, None] + jnp.maximum(col, first_valid_col)) % w  # [B, W]
     base = stream_ids * w                     # [B] flat position of slot 0
     if LANES % w == 0:
-        # a ring never straddles a row: fetch its row, pick its lanes
+        # a ring never straddles a row: fetch its row, then pick its
+        # lanes with selects and static rolls — a TPU gathers single
+        # elements one at a time (10 ns each on the v5e), and the row is
+        # already on chip. Selects pass bits through; the rolls and the
+        # pad run on the bit patterns, so whatever the store holds (-0.0,
+        # a NaN's payload, Inf) comes out as it went in
         rows = state.values[base // LANES]    # [B, 128]
-        lane = (base % LANES)[:, None] + slot
-        windows = jnp.take_along_axis(rows, lane, axis=1)
+        parts = rows.reshape(rows.shape[0], LANES // w, w)
+        part = (base % LANES) // w            # which W lanes of the row
+        ring = parts[:, 0]
+        for k in range(1, LANES // w):
+            ring = jnp.where((part == k)[:, None], parts[:, k], ring)
+        ring = jax.lax.bitcast_convert_type(
+            ring, jnp.dtype(f"uint{ring.dtype.itemsize * 8}")
+        )
+        shift = 1
+        while shift < w:  # rotate left by pos, one bit of pos a stage
+            ring = jnp.where(
+                ((pos & shift) != 0)[:, None],
+                jnp.roll(ring, -shift, axis=1), ring,
+            )
+            shift <<= 1
+        # left-pad: exactly one column is the first valid one, so the
+        # masked max over the columns IS that column
+        first = jnp.max(
+            jnp.where(col == first_valid_col, ring, 0), axis=1, keepdims=True
+        )
+        windows = jax.lax.bitcast_convert_type(
+            jnp.where(col >= first_valid_col, ring, first), rows.dtype
+        )
     else:
         flat = base[:, None] + slot
         windows = state.values[flat // LANES, flat % LANES]

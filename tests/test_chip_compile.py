@@ -231,6 +231,49 @@ def test_step_touches_the_window_state_only_where_it_scatters(
     assert op == "scatter" or " scatter(" in body, (name, op)
 
 
+def element_gathers(hlo: str, n_elems: int) -> list:
+    """Every instruction of the module, fused or not, that gathers SINGLE
+    elements (``slice_sizes`` all 1) into ``n_elems`` or more, or that
+    the program's ``take_along_axis`` became — the row fetch, whose
+    slices are 128 lanes long, is not one."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]*)\]\S* gather\(", line)
+        if not m:
+            continue
+        size = int(np.prod([int(d) for d in m.group(2).split(",") if d]))
+        by_element = re.search(r"slice_sizes=\{1(,1)*\}", line)
+        if size >= n_elems and (by_element or "take_along_axis" in line):
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("b_lane", [1024, 16_384])
+def test_step_picks_window_lanes_without_an_element_gather(
+        one_chip_scorer, topo, b_lane):
+    """At the benchmark cell's size (32 slots, 1,171,875 streams, W 32)
+    and its smallest and largest buckets: the windows come out of the
+    fetched rows by selects and rolls, so no gather of the compiled step
+    yields the T x B x W window plane one element at a time (10.7 of the
+    step's 27 ms on the v5e, PERF.md section 6, PR 37), the state is
+    still updated in place, and step and state fit the chip."""
+    t, w, s = one_chip_scorer.n_slots, one_chip_scorer.window, 1_171_875
+    compiled = lower_step_counts(one_chip_scorer, b_lane, s).compile()
+    hlo = compiled.as_text()
+    assert element_gathers(hlo, t * b_lane * w) == []
+    # the reader still reads this compiler's text: ``pos`` and ``count``
+    # come by element gathers of T x B
+    assert element_gathers(hlo, t * b_lane)
+    state_bytes = t * s * w * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes, mem
+    if b_lane == 1024:
+        assert mem.temp_size_in_bytes < state_bytes // 10, mem
+    # the largest bucket's temporaries are the model's, 2.8 GB with or
+    # without the element gather
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12e9, mem
+
+
 def test_gather_compiles_for_v5e(one_chip_scorer, topo):
     rep = compile_report(
         lower_gather(one_chip_scorer, 2048, 8192, topo.devices[0]))

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from sitewhere_tpu.ops.windows import (
+    LANES,
     gather_windows,
     init_window_state,
     ring_values,
@@ -103,8 +104,8 @@ def test_burst_larger_than_window_keeps_newest():
 class _NumpyRings:
     """The [S, W] store written one row at a time, in batch order."""
 
-    def __init__(self, s, w):
-        self.ring = np.zeros((s, w), np.float32)
+    def __init__(self, s, w, dtype=np.float32):
+        self.ring = np.zeros((s, w), dtype)
         self.pos = np.zeros(s, np.int32)
         self.count = np.zeros(s, np.int32)
         self.samples = {i: [] for i in range(s)}
@@ -116,7 +117,7 @@ class _NumpyRings:
                 self.ring[i, self.pos[i]] = v
                 self.pos[i] = (self.pos[i] + 1) % w
                 self.count[i] += 1
-                self.samples[int(i)].append(float(v))
+                self.samples[int(i)].append(v)
 
 
 def _batch(rng, s, w, burst):
@@ -184,6 +185,98 @@ def test_lane_dense_store_vs_reference(s, w, slots):
             np.asarray(st.pos).reshape(t, s)[k], ref.pos)
         np.testing.assert_array_equal(
             np.asarray(st.count).reshape(t, s)[k], ref.count)
+
+
+# bit patterns a store can hold and arithmetic would not pass through:
+# -0.0, quiet and signalling NaNs with payloads, both infinities, a denormal
+_SPECIAL_BITS = np.array(
+    [0x80000000, 0x7FC00000, 0xFFC12345, 0x7F800001, 0x7F800000, 0xFF800000,
+     0x00000001], np.uint32)
+
+
+def _bit_values(rng, shape):
+    """uint32 patterns, a third of them special; read as f32 they are the
+    values fed to the program, kept as integers they are the reference."""
+    bits = rng.integers(0, 2**32, shape, dtype=np.uint32)
+    special = rng.choice(_SPECIAL_BITS, shape)
+    return np.where(rng.random(shape) < 1 / 3, special, bits)
+
+
+def _state_of(refs):
+    """The device state holding ``refs``' rings: ring slot k of stream s
+    at flat position s*W + k of the 128-lane rows."""
+    st = init_window_state(*refs[0].ring.shape)
+    flat = np.zeros((len(refs), st.values.size), np.uint32)
+    for k, ref in enumerate(refs):
+        flat[k, : ref.ring.size] = ref.ring.reshape(-1)
+    values = flat.view(np.float32).reshape((len(refs),) + st.values.shape)
+    pos = np.stack([ref.pos for ref in refs])
+    count = np.stack([ref.count for ref in refs])
+    return st.__class__(
+        jnp.asarray(values), jnp.asarray(pos), jnp.asarray(count), st.window)
+
+
+@pytest.mark.parametrize("slots", [0, 3], ids=["jit", "vmap-3-slots"])
+@pytest.mark.parametrize("w", [1, 2, 4, 8, 16, 32, 64, 128, 48, 160])
+def test_windows_are_the_reference_rings_bit_for_bit(w, slots):
+    """The select path (W divides 128) over every part of a row, every
+    ``pos``, histories of 0, 1, W-1, W and more samples (the left-pad),
+    duplicates and invalid rows, and values no arithmetic passes through
+    — and the element-gather path (48, 160) beside it: each window equal
+    as uint32 to the last W samples of the row-at-a-time reference."""
+    rng = np.random.default_rng(7000 + w)
+    parts = LANES // w if LANES % w == 0 else 1
+    s = max(2 * parts, w) + 3
+    t = max(slots, 1)
+    refs = [_NumpyRings(s, w, np.uint32) for _ in range(t)]
+    for k, ref in enumerate(refs):  # stream i starts with (i + k) % (W + 3)
+        for i in range(s):          # samples: every pos, n from 0 to past W
+            h = (i + k) % (w + 3)
+            ref.write([i] * h, _bit_values(rng, h), [True] * h)
+    st = _state_of(refs)
+    if not slots:
+        st = jax.tree_util.tree_map(lambda x: x[0], st)
+
+    def check(win, n, ids, valid):
+        win = np.asarray(win).view(np.uint32).reshape(ids.shape + (w,))
+        n = np.asarray(n).reshape(ids.shape)
+        for k, ref in enumerate(refs):
+            for row in np.flatnonzero(valid[k]):
+                i = int(ids[k, row])
+                assert n[k, row] == min(len(ref.samples[i]), w)
+                np.testing.assert_array_equal(
+                    win[k, row],
+                    np.asarray(_np_windows(ref.samples, w, i), np.uint32),
+                )
+
+    # a read of every stream, half of them twice, as the state stands
+    ids = np.stack([
+        rng.permutation(np.concatenate([np.arange(s), np.arange(0, s, 2)]))
+        for _ in refs]).astype(np.int32)
+    gather = jax.vmap(gather_windows) if slots else gather_windows
+    win, n = jax.jit(gather)(st, jnp.asarray(ids if slots else ids[0]))
+    check(win, n, ids, np.ones(ids.shape, bool))
+    seen = np.asarray(n).reshape(-1)
+    assert {0, 1, w - 1, w} <= set(seen.tolist())
+    assert {int(p) for ref in refs for p in ref.pos} == set(range(w))
+    assert {i * w % LANES // w for i in range(s)} >= set(range(parts))
+
+    # then batches that write: duplicates, padded rows, wrap-around
+    step = jax.vmap(update_and_gather) if slots else update_and_gather
+    step = jax.jit(step, donate_argnums=0)
+    for _ in range(3):
+        ids, valid = map(
+            np.stack, zip(*[_batch(rng, s, w, False) for _ in refs]))
+        bits = _bit_values(rng, ids.shape)
+        for k, ref in enumerate(refs):
+            ref.write(ids[k], bits[k], valid[k])
+        args = (ids, bits.view(np.float32), valid)
+        st, win, n = step(
+            st, *(jnp.asarray(a if slots else a[0]) for a in args))
+        check(win, n, ids, valid)
+    rings = np.asarray(ring_values(st)).view(np.uint32).reshape(t, s, w)
+    for k, ref in enumerate(refs):
+        np.testing.assert_array_equal(rings[k], ref.ring)
 
 
 def test_ring_values_of_a_store_split_over_data_shards():
