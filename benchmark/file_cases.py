@@ -1,6 +1,8 @@
 """The benchmark's files agree with one another: what a later PR's
-additions must satisfy before they cost a chip call. Plain JSON and
-``os.path``; imports neither JAX, ``benchmark.run`` nor pytest.
+additions must satisfy before they cost a chip call -- and, since both
+are plain dictionaries too, ``benchmark.sweep``'s one rule reads every
+window of ``sweep_windows.json`` as recorded there. Plain JSON and
+``os.path``; imports neither JAX, numpy, ``benchmark.run`` nor pytest.
 
     python3 -m benchmark.file_cases
 
@@ -16,6 +18,8 @@ import json
 import os
 import sys
 
+from benchmark import sweep
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
@@ -29,10 +33,17 @@ BENCH = _load(ROOT, "BENCHMARK.json")
 CONFIGS = {c["name"]: c for c in BENCH["configs"]}
 CELLS = {w["name"]: w for w in BENCH["workloads"]}
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+WINDOWS = {w["name"]: w for w in _load(HERE, "sweep_windows.json")["windows"]}
 
 
 def _config_file(name: str) -> dict:
     return _load(ROOT, CONFIGS[name]["file"])
+
+
+def _traffic_and_config(cell: str) -> tuple:
+    w = CELLS[cell]
+    return (_load(HERE, "traffic", w["traffic"] + ".json"),
+            _config_file(w["config"]))
 
 
 def _has(*parts: str) -> bool:
@@ -51,14 +62,25 @@ def cell_names_its_files(cell: str) -> None:
 def cell_offers_each_device_at_most_once(cell: str) -> None:
     """Rate x ``run_seconds`` does not exceed the registered devices: a
     device reports once an interval, and no window is longer than one."""
-    w = CELLS[cell]
-    traffic = _load(HERE, "traffic", w["traffic"] + ".json")
-    config = _config_file(w["config"])
+    traffic, config = _traffic_and_config(cell)
     if "rate_ev_s" not in traffic:
         return
     messages = (traffic["rate_ev_s"] * BENCH["run_seconds"]
                 / traffic.get("samples_per_message", 1))
     assert messages <= config["tenants"] * config["devices_per_tenant"]
+
+
+def cell_registers_the_fleet_its_rate_implies(cell: str) -> None:
+    """A re-seat cannot move the rate and leave the fleet: where every
+    device reports once a published interval, the devices registered are
+    rate x interval / tenants, the rule ``sweep.with_value`` applies."""
+    traffic, config = _traffic_and_config(cell)
+    interval = config.get("published", {}).get("report_interval_s")
+    if "rate_ev_s" not in traffic or not interval:
+        return
+    implied = sweep.fleet_per_tenant(
+        traffic["rate_ev_s"], interval, config["tenants"])
+    assert config["devices_per_tenant"] == implied, implied
 
 
 def config_names_its_files(name: str) -> None:
@@ -88,6 +110,22 @@ def metric_lists_cells_that_exist(name: str) -> None:
     assert set(metric.get("workloads", [])) <= set(CELLS)
 
 
+def bound_is_one_to_ten_percent(name: str) -> None:
+    """Under 1% no host clock keeps it; over 10% the contract refuses."""
+    bound = next(m for m in BENCH["end_to_end"] if m["name"] == name)["bound"]
+    assert 0.01 <= bound <= 0.10, bound
+
+
+def sweep_rule_reads_the_window_as_recorded(name: str) -> None:
+    """``sweep.sustained`` on a recorded (or a made-up) window gives the
+    verdict the file records and, where that is no, names the reason."""
+    w = WINDOWS[name]
+    verdict, reasons = sweep.sustained(w["correct"], w["failed"], w["info"])
+    assert verdict == w["sustained"], reasons
+    assert all(any(part in r for r in reasons) for part in w.get("says", []))
+    assert verdict or w.get("says"), "a refusal is recorded with its reason"
+
+
 def at_most_a_quarter_of_the_cells_take_four_chips(_: str) -> None:
     four = sum(w["chips"] == 4 for w in CELLS.values())
     assert four <= max(1, len(CELLS) // 4), four
@@ -96,11 +134,14 @@ def at_most_a_quarter_of_the_cells_take_four_chips(_: str) -> None:
 CASES = (
     [(cell_names_its_files, c) for c in CELLS]
     + [(cell_offers_each_device_at_most_once, c) for c in CELLS]
+    + [(cell_registers_the_fleet_its_rate_implies, c) for c in CELLS]
     + [(config_names_its_files, c) for c in CONFIGS]
     + [(config_says_why_each_key_is_reduced, c) for c in CONFIGS]
     + [(metric_has_a_reader, m["name"]) for m in METRICS]
     + [(metric_lists_cells_that_exist, m["name"]) for m in METRICS]
+    + [(bound_is_one_to_ten_percent, m["name"]) for m in BENCH["end_to_end"]]
     + [(at_most_a_quarter_of_the_cells_take_four_chips, "workloads")]
+    + [(sweep_rule_reads_the_window_as_recorded, w) for w in WINDOWS]
 )
 
 

@@ -247,7 +247,7 @@ def test_sweep_rule_tells_a_window_that_falls_behind():
     # 5 s at the tiny rate: 60 one-event batches a tenant, so a stage that
     # is withheld passes the slack; a device is still due at most once.
     # The half-medians of 60 events on a shared CPU swing by more than the
-    # rule's 5%, so of a real window only the lag clause is held here.
+    # rule allows, so of a real window only the lag clause is held here.
     def behind(res: dict) -> list:
         _ok, reasons = sweep.sustained(
             res["correct"], res["failed"], res["info"])
@@ -261,20 +261,7 @@ def test_sweep_rule_tells_a_window_that_falls_behind():
     late = behind(res)
     assert late and all("outbound-connectors closes" in r for r in late), (
         late, res["info"])
-    # each clause of the rule alone
-    info = {"lag_at_open": {}, "lag_at_close": {"scored-events<rules": 3},
-            "p50_first_half_ms": 50.0, "p50_second_half_ms": 52.0}
-    assert sweep.sustained(True, 0, info) == (True, [])
-    assert not sweep.sustained(False, 0, info)[0]
-    assert not sweep.sustained(True, 1, info)[0]
-    assert not sweep.sustained(True, 0, {**info, "p50_second_half_ms": 53.0})[0]
-    # a median that FALLS by halves is a flip between modes, not a gain
-    assert sweep.sustained(True, 0, {**info, "p50_second_half_ms": 48.0})[0]
-    assert not sweep.sustained(True, 0, {**info, "p50_second_half_ms": 47.0})[0]
-    held = {"scored-events<rules": 3 + sweep.LAG_SLACK_BATCHES + 1}
-    assert not sweep.sustained(True, 0, {**info, "lag_at_close": held})[0]
-    assert sweep.sustained(True, 0, {**info, "lag_at_close": held,
-                                     "lag_at_open": {"scored-events<rules": 4}})[0]
+    # each clause of the rule alone: sweep_windows.json, with the files' cases
 
 
 # ---------------------------------------------------- the files, together

@@ -10,13 +10,14 @@ configuration's ``limits``). One process, one cell, several windows.
 
 The first is the knee sweep ``PERF.md`` section 4 records: every value on
 every seed, and with each window the verdict of ``sustained`` below — the
-one rule by which a rate is or is not sustained. The knee is the highest
-rate that every seed sustains. With ``--control 1`` every window also
-prints the control's checks beside the program's. ``--config key=value``
-runs every window with that key of the configuration file replaced (a
-state size to try before it is written into the file); ``--trace 1``
-prints the per-layer metrics of traced windows in place of the end-to-end
-ones.
+one rule by which a rate is or is not sustained (``sweep_windows.json``
+holds the windows its constants were set on, each with its verdict). The
+knee is the highest rate that every seed sustains. With ``--control 1``
+every window also prints the control's checks beside the program's.
+``--config key=value`` runs every window with that key of the
+configuration file replaced (a state size to try before it is written
+into the file); ``--trace 1`` prints the per-layer metrics of traced
+windows in place of the end-to-end ones.
 
 Where the swept parameter is ``rate_ev_s`` and the configuration
 publishes a report interval, the registered fleet follows the rate as the
@@ -42,20 +43,33 @@ import math
 import time
 from contextlib import redirect_stderr
 
-from benchmark import run
-
 # A consumer group may close this many batches deeper than it opened. One
-# tenant's topic takes at most one batch a flush (20-37 flushes/s), and a
+# tenant's topic takes at most one batch a flush (20-41 flushes/s), and a
 # full collection stops the loop for up to 0.65 s, so a sustained window
 # can close 13-20 batches deep on the topic it stopped on; a group that
 # falls behind grows by more than a batch a second for the whole window.
 LAG_SLACK_BATCHES = 32
-# The second half's median latency may differ from the first half's by
-# this, either way: a median that RISES by halves is a backlog growing, and
-# one that FALLS is the window flipping between the device-queue mode and
-# the host-bound one (near the knee the same tree reads ~200 ms in one and
-# ~100-135 ms in the other, PERF.md section 4) -- no seat for a cell either.
-HALF_MEDIAN_RATIO = 1.05
+# The second half's median latency may lie this far BELOW the first
+# half's: a median that FALLS by halves is the window opening on a slow
+# host (first half 1.5-2.6 x the second, 1 window in 16-24 at the seat,
+# PERF.md section 2) or, on the flush policy before PR 35, flipping
+# between a device-queue mode and a host-bound one (204 -> 135 ms at 800
+# ev/s, PERF.md section 4) -- neither says the rate was kept up with.
+HALF_MEDIAN_FALL = 1.05
+# ... and this far ABOVE it. Since PR 35 the median reads one step plus
+# the host loop's turn, and the turn lengthens with the heap all through a
+# window at EVERY rate: the fourteen 60 s windows of sweep_windows.json at
+# 500-650 ev/s (PR 35's tree and PR 37's), every one correct and none more
+# than 2 batches behind, rise 1.01-1.098 x by halves (the highest: 650 ev/s,
+# 45.0 -> 49.3 ms, loop 90% busy). The four at 700 ev/s rise 1.100, 1.105,
+# 1.140 and 1.206 x with the loop 90-93% busy and a median a sixth over
+# 650's: past the bend, where a per cent of host time is a tenth of the
+# wait. The line stands between the two groups and has no room to spare
+# (1.098 against 1.100), so a rate is judged on both seeds and by its busy
+# share beside (PERF.md section 4). A backlog that no group's lag shows is
+# far past it: 0.2% over capacity adds 30 ms to the first half's median and
+# 90 to the second's.
+HALF_MEDIAN_RISE = 1.10
 
 
 def sustained(correct: bool, failed: int, info: dict) -> tuple:
@@ -64,8 +78,8 @@ def sustained(correct: bool, failed: int, info: dict) -> tuple:
     them. Sustained means: correct, nothing failed, no consumer group
     (the scoring consumer's and every later stage's alike) closing more
     than ``LAG_SLACK_BATCHES`` deeper than it opened, and a second-half
-    median within ``HALF_MEDIAN_RATIO`` of the first half's, above it or
-    below."""
+    median no more than ``HALF_MEDIAN_RISE`` times the first half's and no
+    less than it over ``HALF_MEDIAN_FALL``."""
     reasons = []
     if not correct:
         reasons.append("not correct")
@@ -81,11 +95,22 @@ def sustained(correct: bool, failed: int, info: dict) -> tuple:
     second = info.get("p50_second_half_ms")
     if first is None or second is None:
         reasons.append("no latencies")
-    elif not first / HALF_MEDIAN_RATIO <= second <= HALF_MEDIAN_RATIO * first:
+    elif second > HALF_MEDIAN_RISE * first:
         reasons.append(
             f"p50 by halves {first:.1f} -> {second:.1f} ms "
-            f"(not within {HALF_MEDIAN_RATIO} x either way)")
+            f"(rises by more than {HALF_MEDIAN_RISE} x)")
+    elif second < first / HALF_MEDIAN_FALL:
+        reasons.append(
+            f"p50 by halves {first:.1f} -> {second:.1f} ms "
+            f"(falls by more than {HALF_MEDIAN_FALL} x)")
     return not reasons, reasons
+
+
+def fleet_per_tenant(rate_ev_s: float, interval_s: float, tenants: int) -> int:
+    """The devices a tenant registers where every device reports once an
+    interval and the tenants share the offered rate: Little's law, rounded
+    up (``benchmark/file_cases.py`` holds every cell's files to it)."""
+    return math.ceil(rate_ev_s * interval_s / tenants)
 
 
 def with_value(cell: dict, param: str, value: float) -> dict:
@@ -96,8 +121,8 @@ def with_value(cell: dict, param: str, value: float) -> dict:
     config = cell["config"]
     interval = config.get("published", {}).get("report_interval_s")
     if param == "rate_ev_s" and interval:
-        config["devices_per_tenant"] = math.ceil(
-            value * interval / config["tenants"])
+        config["devices_per_tenant"] = fleet_per_tenant(
+            value, interval, config["tenants"])
     return cell
 
 
@@ -113,6 +138,10 @@ def main() -> None:
     ap.add_argument("--config", action="append", default=[],
                     metavar="KEY=VALUE")
     args = ap.parse_args()
+    # not at the top: the rule above is read by plain file checks and by
+    # tier-1 tests that want neither numpy nor JAX
+    from benchmark import run
+
     cell = run.load_cell(args.workload)
     for pair in args.config:
         key, value = pair.split("=", 1)
