@@ -457,7 +457,10 @@ class SiteWhereInstance(LifecycleComponent):
         # (_on_shared_input) so multi-tenant isolation holds
 
         rules = RuleEngine(tenant, self.bus, [
-            anomaly_score_rule(f"{tenant}-anomaly", min_score=3.0, cooldown_ms=5000),
+            anomaly_score_rule(
+                f"{tenant}-anomaly", min_score=cfg.rule_min_score,
+                cooldown_ms=5000,
+            ),
         ], self.metrics, policy=ft, tracer=self.tracer,
             overload=self.overload)
         connectors = [

@@ -9,6 +9,8 @@ engine resolves them here. Scorer models share one contract:
 
 which is what lets heterogeneous tenants stack along the mesh tenant axis
 as long as they share a model *family* (SURVEY.md §7 "tenants-on-mesh").
+A family whose stream state is not a window (``nemotron_h``) brings the
+stateful contract instead — ``init_state`` / ``advance``, see ``ModelSpec``.
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ from __future__ import annotations
 from dataclasses import dataclass, fields
 from typing import Any, Callable, Dict, Optional, Tuple
 
-from sitewhere_tpu.models import deepar, lstm_ad, transformer, vit
+from sitewhere_tpu.models import deepar, lstm_ad, nemotron_h, transformer, vit
 from sitewhere_tpu.models.common import (
     DEFAULT_SCORE_RANGE,
     deepar_flops_per_row,
@@ -36,6 +38,7 @@ __all__ = [
     "deepar",
     "transformer",
     "vit",
+    "nemotron_h",
 ]
 
 
@@ -69,6 +72,19 @@ class ModelSpec:
     # health & canaries") — the zoo's |error|-in-sigma scorers share the
     # default; a family with different score units overrides it here
     score_range: Tuple[float, float] = DEFAULT_SCORE_RANGE
+    # stateful scorer contract (parallel.streamstate): a family whose
+    # per-stream state is NOT a window of raw values brings
+    #   init_state(cfg, max_streams) -> one slot's state pytree, every
+    #     leaf led by the stream axis;
+    #   advance(params, cfg, state, ids[N], toks[N, L], lens[N],
+    #     one_step=bool) -> (state', scores f32[N, L], counters i32[3])
+    # — score each token from the state stored BEFORE it, then advance.
+    # ``score`` stays None: there is no window to re-scan.
+    #   state_traffic(cfg, streams, tokens) -> (bytes read, written)
+    #     of stream state by calls advancing that many streams / tokens
+    init_state: Optional[Callable] = None
+    advance: Optional[Callable] = None
+    state_traffic: Optional[Callable] = None
 
 
 MODEL_REGISTRY: Dict[str, ModelSpec] = {
@@ -106,6 +122,16 @@ MODEL_REGISTRY: Dict[str, ModelSpec] = {
         forecast=transformer.forecast,
         train_step=transformer.train_step,
         flops_per_row=transformer_flops_per_row,
+    ),
+    "nemotron_h": ModelSpec(
+        name="nemotron_h",
+        config_cls=nemotron_h.NemotronHConfig,
+        init=nemotron_h.init,
+        init_state=nemotron_h.init_state,
+        advance=nemotron_h.advance,
+        state_traffic=nemotron_h.state_traffic,
+        flops_per_row=nemotron_h.flops_per_row,
+        score_range=nemotron_h.SCORE_RANGE,
     ),
     "vit_b16": ModelSpec(
         name="vit_b16",

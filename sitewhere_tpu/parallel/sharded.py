@@ -50,6 +50,7 @@ from sitewhere_tpu.ops.windows import (
     update_windows,
 )
 from sitewhere_tpu.parallel.mesh import AXIS_DATA, AXIS_TENANT, MeshManager
+from sitewhere_tpu.parallel.streamstate import StreamPrograms, plan
 
 Params = Any
 
@@ -105,16 +106,25 @@ def init_stacked_state(
     """Stacked window state, slot-major: every leaf of
     ``init_window_state`` with a leading [T]. S is the *global* stream
     capacity; the stream axis (leaf axis 1) is split ``data_shards``
-    ways inside shard_map, each shard owning whole rows of the store."""
+    ways inside shard_map, each shard owning whole rows of the store.
+    Traceable: ``ShardedScorer._fresh_state`` runs it under ``jit`` with
+    the store's sharding as ``out_shardings``, so the store is written
+    once where it lives — outside a jit each leaf is a one-slot array
+    AND its stacked copy until the former is dropped."""
     st = init_window_state(max_streams, window, shards=data_shards)
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (n_slots,) + x.shape).copy(), st
     )
 
 
-def _zero_slot(state: WindowState, idx: int) -> WindowState:
-    """One slot's rings, cursors and counts back to empty."""
+def _zero_slot(state, idx: int):
+    """One slot's stream state (rings, cursors and counts, or a stateful
+    family's store) back to empty."""
     return jax.tree_util.tree_map(lambda x: x.at[idx].set(0), state)
+
+
+# in place: without donation a reset holds the whole store twice
+_zero_slot_donated = jax.jit(_zero_slot, static_argnums=1, donate_argnums=0)
 
 
 class ShardedScorer:
@@ -138,8 +148,20 @@ class ShardedScorer:
         fuse_k: int = 1,
         param_dtype: str = "f32",
     ) -> None:
-        if spec.score is None:
+        # two kinds of stream state behind one owner: a window of raw
+        # values every flush re-scans (``spec.score``), or a state the
+        # family advances one step an event (``spec.advance`` —
+        # parallel.streamstate)
+        self.stateful = getattr(spec, "advance", None) is not None
+        if spec.score is None and not self.stateful:
             raise ValueError(f"model '{spec.name}' has no scorer contract")
+        if self.stateful and mm.mesh.devices.size != 1:
+            raise ValueError(
+                f"model '{spec.name}' keeps a recurrent stream state: its "
+                f"slice is one device (got a mesh of "
+                f"{mm.mesh.devices.size}); the exchange between chips "
+                f"that share a layer is not built"
+            )
         self.mm = mm
         self.spec = spec
         self.cfg = cfg
@@ -225,12 +247,22 @@ class ShardedScorer:
 
         # identical init per slot; per-tenant training diverges them later
         key = jax.random.PRNGKey(seed)
-        base = spec.init(key, cfg)
-        self._base_params = base  # pristine copy for slot recycling
-        stacked = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (self.n_slots,) + x.shape).copy(),
-            base,
-        )
+        self._base_key = key
+        if self.stateful:
+            # a chip-filling model: the stack is made in place and no
+            # pristine second copy is kept (``_pristine_params`` makes
+            # it again from the key when a slot is recycled)
+            self._base_params = None
+            stacked = self._init_stacked()
+        else:
+            base = spec.init(key, cfg)
+            self._base_params = base  # pristine copy for slot recycling
+            stacked = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(
+                    x[None], (self.n_slots,) + x.shape
+                ).copy(),
+                base,
+            )
         t_shard = mm.tenant_stacked()
         # param placement by PARTITION RULES (parallel.partition — the
         # SNIPPETS [2][3] match_partition_rules pattern): leaf paths map
@@ -285,8 +317,22 @@ class ShardedScorer:
         self.slot_lr = jax.device_put(
             jnp.ones((self.n_slots,), jnp.float32), t_shard
         )
-        self._step = self._build_step()
-        self._step_counts = self._build_step(counts_mode=True)
+        if self.stateful:
+            self._step = self._step_counts = None
+            self.programs = StreamPrograms(
+                spec, cfg, self.n_slots, max_streams,
+                {"f32": jnp.float32, "bf16": jnp.bfloat16,
+                 "f16": jnp.float16}[wire_dtype],
+                self.sketch_edges,
+            )
+            # the plan of the flush ``stage_inputs`` saw last, keyed by
+            # the staged ids it returned; and the last step's counters
+            # (device i32[3], host dict) for the service to publish
+            self._staged_plan = None
+            self.last_stats = None
+        else:
+            self._step = self._build_step()
+            self._step_counts = self._build_step(counts_mode=True)
         # input shardings for the counts wire (ids/vals [T, D*B], counts
         # [T, D] — both tenant×data): stage_inputs puts flush buffers onto
         # these so the jit never reshards and the h2d copy can overlap a
@@ -296,16 +342,44 @@ class ShardedScorer:
         # stage_slot_params — most scorers never page and must not pay
         self._slot_shard_fns = None
 
-    def _fresh_state(self) -> WindowState:
-        """Empty stacked rings on the mesh: slots over the tenant axis,
-        streams over the data axis."""
-        return jax.device_put(
-            init_stacked_state(
-                self.n_slots, self.max_streams, self.window,
-                self.mm.n_data_shards,
-            ),
-            self.mm.sharding(AXIS_TENANT, AXIS_DATA),
+    def _init_stacked(self) -> Params:
+        """Every slot's weights as first initialised, made as one stack
+        (no unstacked original beside it)."""
+        spec, cfg = self.spec, self.cfg
+        return jax.jit(jax.vmap(lambda k: spec.init(k, cfg)))(
+            jnp.stack([self._base_key] * self.n_slots)
         )
+
+    def _fresh_state(self):
+        """The empty stream state on the mesh, written once where it
+        lives (jit + ``out_shardings``: no unstacked original, no
+        default-device copy beside the placed one): slots over the
+        tenant axis, streams over the data axis."""
+        if self.stateful:
+            spec, cfg, s, t = (
+                self.spec, self.cfg, self.max_streams, self.n_slots)
+
+            def make():
+                return jax.tree_util.tree_map(
+                    lambda x: jnp.broadcast_to(x[None], (t,) + x.shape),
+                    spec.init_state(cfg, s),
+                )
+        else:
+            def make():
+                return init_stacked_state(
+                    self.n_slots, self.max_streams, self.window,
+                    self.mm.n_data_shards,
+                )
+        return jax.jit(
+            make, out_shardings=self.mm.sharding(AXIS_TENANT, AXIS_DATA)
+        )()
+
+    @property
+    def state_nbytes(self) -> int:
+        """Bytes of stream state provisioned on the mesh."""
+        return int(sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(self.state)
+        ))
 
     def ring_values(self) -> jnp.ndarray:
         """The serve state's logical rings f32[T, S, W] (ring order)."""
@@ -349,7 +423,41 @@ class ShardedScorer:
         are ready (the service rotates staging buffers to guarantee it).
         Returns (ids, vals, counts) device arrays for ``step_counts``."""
         s = self._wire_sharding
-        return jax.device_put((stream_ids, values, counts), (s, s, s))
+        staged = jax.device_put((stream_ids, values, counts), (s, s, s))
+        if self.stateful:
+            # which program each row rides is decided here, where the
+            # flush is still host memory (its own copies: the staging
+            # buffers are reused)
+            self._staged_plan = (staged[0], self._plan(
+                stream_ids, values, counts))
+        return staged
+
+    def _plan(self, stream_ids, values, counts):
+        import numpy as _np
+
+        return plan(
+            _np.asarray(stream_ids), self.programs.tokens(values),
+            _np.asarray(counts).sum(axis=1), self.programs.chunk,
+            self.max_streams,
+        )
+
+    def _stream_step(self, stream_ids, values, counts) -> jnp.ndarray:
+        """A stateful family's flush: the staged plan's calls, in order,
+        through the one-step and chunked programs."""
+        staged, self._staged_plan = self._staged_plan, None
+        if staged is not None and staged[0] is stream_ids:
+            calls, host_stats = staged[1]
+        else:  # not staged through ``stage_inputs`` (tests, a probe)
+            calls, host_stats = self._plan(stream_ids, values, counts)
+        self.state, scores, hist, dev_stats = self.programs.run(
+            self.params, self.state, calls, stream_ids.shape[1])
+        if self.sketch:
+            self.last_sketch = hist
+        # the counters ride back beside the scores: by the time the
+        # flush lands the service reads them without a wait
+        dev_stats.copy_to_host_async()
+        self.last_stats = (dev_stats, host_stats)
+        return scores
 
     @staticmethod
     def stage_nbytes(staged) -> int:
@@ -637,6 +745,17 @@ class ShardedScorer:
         import numpy as _np
 
         t, d = self.n_slots, self.mm.n_data_shards
+        if self.stateful:
+            # both programs at every shape, on padding rows (the state
+            # is left as it was), placed into every bucket's plane
+            for b in sorted(set(int(x) for x in lane_sizes)):
+                for slot in range(t):
+                    warm = self.programs.warm_calls(slot)
+                    # each shape as a flush's first call and as a later one
+                    for calls in (warm, warm[::-1]):
+                        self.state, s, hist, _st = self.programs.run(
+                            self.params, self.state, calls, d * b)
+                        _np.asarray(hist)
         for b in sorted(set(int(x) for x in lane_sizes)):
             ids = _np.zeros((t, d * b), self.ids_np_dtype)
             vals = _np.zeros((t, d * b), self.vals_np_dtype)
@@ -687,6 +806,9 @@ class ShardedScorer:
         if self.fault_steps > 0:
             self.fault_steps -= 1
             raise RuntimeError("injected scorer fault (chaos)")
+        if self.stateful:
+            raise NotImplementedError(
+                "a stateful family takes the counts wire (step_counts)")
         out = self._step(
             self.kernel_params(), self.state, self.active,
             stream_ids, values, valid,
@@ -710,6 +832,8 @@ class ShardedScorer:
         if self.fault_steps > 0:
             self.fault_steps -= 1
             raise RuntimeError("injected scorer fault (chaos)")
+        if self.stateful:
+            return self._stream_step(stream_ids, values, counts)
         out = self._step_counts(
             self.kernel_params(), self.state, self.active,
             stream_ids, values, counts,
@@ -813,9 +937,19 @@ class ShardedScorer:
         history, trained weights, or Adam momentum."""
         self.deactivate(global_slot)
         self.slot_lr = self.slot_lr.at[global_slot].set(1.0)
-        self.params = set_slot(self.params, global_slot, self._base_params)
+        if self._base_params is None and self.n_slots == 1:
+            # a chip-filling model: two sets of weights do not fit, so
+            # the one slot's stack is dropped before it is made again
+            from sitewhere_tpu.parallel.partition import shard_tree
+
+            self.params = None
+            self.params = shard_tree(
+                self._init_stacked(), self._param_shard_fns)
+        else:
+            self.params = set_slot(
+                self.params, global_slot, self._pristine_params())
         self._invalidate_kernel()
-        self.state = _zero_slot(self.state, global_slot)
+        self.state = _zero_slot_donated(self.state, global_slot)
         if getattr(self, "_opt_state", None) is not None:
             self._opt_state = jax.tree_util.tree_map(
                 lambda s, f: s.at[global_slot].set(f.astype(s.dtype)),
@@ -828,6 +962,14 @@ class ShardedScorer:
             self._train_feed_state = _zero_slot(
                 self._train_feed_state, global_slot
             )
+
+    def _pristine_params(self) -> Params:
+        """One slot's weights as first initialised: the kept copy, or —
+        where the model fills the chip and none is kept — made again
+        from the key."""
+        if self._base_params is not None:
+            return self._base_params
+        return self.spec.init(self._base_key, self.cfg)
 
     def slot_params(self, global_slot: int) -> Params:
         return unstack_slot(self.params, global_slot)
@@ -850,7 +992,12 @@ class ShardedScorer:
             )
 
             specs = unstacked_specs(
-                self.partition_rules, self._base_params, self.mm.mesh
+                self.partition_rules,
+                jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                    self.params,
+                ),
+                self.mm.mesh,
             )
             self._slot_shard_fns, _ = make_shard_and_gather_fns(
                 self.mm.mesh, specs
@@ -916,7 +1063,7 @@ class ShardedScorer:
                 lambda x: jnp.broadcast_to(
                     x[None], (self.n_slots,) + x.shape
                 ).copy(),
-                self._base_params,
+                self._pristine_params(),
             )
             return shard_tree(stacked, self._param_shard_fns)
 
@@ -938,8 +1085,12 @@ class ShardedScorer:
             ),
         )
         self.state = self._fresh_state()
-        self._step = self._build_step()
-        self._step_counts = self._build_step(counts_mode=True)
+        if self.stateful:
+            self.programs._advance.clear()
+            self._staged_plan = self.last_stats = None
+        else:
+            self._step = self._build_step()
+            self._step_counts = self._build_step(counts_mode=True)
         self._kernel_params = None   # may reference dead buffers
         self._kernel_dirty = True
         self._quantize_jit = None
@@ -1013,7 +1164,7 @@ class ShardedScorer:
             self.mm.mesh, self._opt_specs
         )
         self._opt_state = shard_tree(opt_state, opt_shard_fns)
-        self._fresh_opt = optimizer.init(self._base_params)  # for reset_slot
+        self._fresh_opt = optimizer.init(self._pristine_params())  # for reset_slot
         self._lr_sign = lr_sign
         self._train = self._build_train_step(optimizer, lr_sign)
         if self.train_lane:

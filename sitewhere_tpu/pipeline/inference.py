@@ -859,6 +859,12 @@ class TpuInferenceService(MultitenantService):
                 flops_name=f_name, secs_name=s_name, gauge_name=g_name,
                 device=self.mm.slice_device_label(sl),
             )
+        if scorer.stateful:
+            # the second kind of stream state: provisioned for
+            # max_streams at tenant start, like the rings
+            self.metrics.gauge(
+                "tpu_inference_stream_state_bytes", family=family
+            ).set(scorer.state_nbytes)
         # THE birth of a slice, whole
         self._slices[(family, sl)] = SliceRuntime(
             family, sl, scorer, breaker, self.metrics,
@@ -1752,6 +1758,7 @@ class TpuInferenceService(MultitenantService):
             flush_id=flush_id, t_dispatch=t_dispatched,
         )
         pf.slot_override = slot_override
+        pf.stream_stats = getattr(scorer, "last_stats", None)
         # flush supervision: the completion deadline the reaper races
         # (family p99-derived, floored by flush_deadline_ms; None = off)
         ft = self._family_ft(family)
@@ -3594,6 +3601,26 @@ class TpuInferenceService(MultitenantService):
     # scores, so rank stability there matters more than mean delta
     CANARY_TOPK = 64
 
+    def _count_stream_step(self, pf: _PendingFlush, scorer) -> None:
+        """A stateful family's step, counted (docs/OBSERVABILITY.md
+        "Stream-state families"): which program each row rode, the
+        expert layers' routing, the state the step moved. The device
+        counters left the programs the scores left, so they have landed."""
+        from sitewhere_tpu.parallel.streamstate import DEVICE_STATS
+
+        dev, host = pf.stream_stats
+        counts = dict(zip(DEVICE_STATS, np.asarray(dev).tolist()), **host)
+        traffic = scorer.spec.state_traffic
+        if traffic is not None:
+            counts["state_read_bytes"], counts["state_written_bytes"] = (
+                traffic(scorer.cfg, host["streams_advanced"],
+                        host["rows_one_step"] + host["rows_chunked"]))
+        for name, value in counts.items():
+            if value:
+                self.metrics.counter(f"tpu_inference.stream_{name}").inc(value)
+        if pf.rec is not None:
+            pf.rec["stream"] = counts
+
     def _canary_compare(
         self, pf: _PendingFlush, picks: np.ndarray, shadow_np: np.ndarray
     ) -> None:
@@ -3702,6 +3729,8 @@ class TpuInferenceService(MultitenantService):
             self.metrics.histogram("tpu_inference.d2h_wait", unit="s").record(
                 waited_s
             )
+            if pf.stream_stats is not None:
+                self._count_stream_step(pf, s.scorer)
             d2h_overlapped = (
                 pf.t_wait is None and waited_s < self.D2H_OVERLAP_EPS_S
             )

@@ -218,7 +218,7 @@ class _PendingFlush:
         "t_dispatch", "nbytes", "plane_nbytes", "host_future", "t_wait",
         "poisoned", "flops", "rec", "sketch", "shadow", "slot_override",
         "resolved", "lane", "deadline", "retried", "retry_rows",
-        "retry_from", "owns_permit", "flush_id",
+        "retry_from", "owns_permit", "flush_id", "stream_stats",
     )
 
     def __init__(
@@ -267,6 +267,10 @@ class _PendingFlush:
         # have long since followed — no extra round-trip.
         self.sketch = sketch
         self.shadow = shadow
+        # a stateful family's step counters (``ShardedScorer.last_stats``:
+        # device i32 vector, host dict) — out of the same programs as the
+        # scores, so landed when they have
+        self.stream_stats = None
         # the single-used-slot fallback slice zeroes the pack-order slot
         # indices (rows then index row 0 of the slice); this remembers
         # the real slot so NaN attribution survives that path

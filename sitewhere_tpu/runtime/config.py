@@ -237,6 +237,10 @@ class TenantEngineConfig:
     # ALSO scored through the legacy f32 step and the divergence reported
     # as score_canary_* metrics. 0 (default) disables shadow scoring.
     canary_frac: float = 0.0
+    # threshold of the tenant's anomaly-score rule, in the family's score
+    # units: sigmas for the window scorers (3.0), nats of surprisal for a
+    # token scorer (ln vocab and up)
+    rule_min_score: float = 3.0
     # streaming-media classification leg (chunks → ViT → events); tiny
     # uses the test-sized ViT so CI exercises the full flow cheaply
     media_pipeline: bool = False
@@ -337,6 +341,13 @@ TENANT_TEMPLATES: Dict[str, Dict[str, Any]] = {
         "model": "lstm_ad",
         "model_config": {"hidden": 64},
         "datasets": ["temperature-sensors"],
+    },
+    "sensor-tokens": {
+        # raw sensor counts scored as tokens by a stream-state family
+        # (models/nemotron_h.py): the model_config carries the widths
+        "model": "nemotron_h",
+        "model_config": {},
+        "datasets": ["empty"],
     },
     "forecasting": {
         "model": "deepar",

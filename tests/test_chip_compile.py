@@ -344,6 +344,57 @@ async def test_smoke_chips4_phase_tiny_on_virtual_devices():
     assert line["serving_collectives"] == 0 and line["train_all_reduces"] >= 1
 
 
+@pytest.mark.parametrize("one_step, rows", [(True, 32), (False, 8)])
+def test_stream_state_programs_compile_for_v5e_at_published_widths(
+        topo, one_step, rows, monkeypatch):
+    """The stateful family's two programs at the published widths and the
+    provisioned store (shapes only): they fit beside 6.4 GB of weights
+    and 5.5 GB of state, the store is updated in place, the grouped
+    expert product is a kernel, and nothing as large as a layer's expert
+    weights is copied (an ``up`` matrix whose minor dimension is not
+    whole lane tiles was, every step, until it was stored padded)."""
+    from sitewhere_tpu.models.common import sketch_edges
+    from sitewhere_tpu.ops import moe
+    from sitewhere_tpu.parallel.streamstate import StreamPrograms
+
+    # the backend here is the CPU; the chip's branch is what compiles
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    spec = get_model("nemotron_h")
+    cfg = make_config("nemotron_h", {})
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                (1,) + x.shape, x.dtype, sharding=one), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: spec.init(jax.random.PRNGKey(0), cfg)))
+    state = placed(jax.eval_shape(lambda: spec.init_state(cfg, 512)))
+    progs = StreamPrograms(spec, cfg, 1, 512, jnp.float32,
+                           sketch_edges(1.0, 64.0, 64))
+    length = 1 if one_step else cfg.chunk_size
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    compiled = progs.program(one_step, 0).lower(
+        params, state, arg(rows, 2 + 2 * length)).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(state))
+    assert mem.alias_size_in_bytes == state_bytes       # donated, in place
+    assert mem.temp_size_in_bytes < 1.2e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 0.85 * 16 * 2**30)
+    text = compiled.as_text()
+    assert text.count('kernel_name = "gmm"') >= 8 or text.count(
+        "gmm") >= 8                                      # 4 layers x up, down
+    assert "ragged-dot" not in text
+    assert not re.search(r"bf16\[1,64,\d+,\d+\]\S* copy\(", text)
+
+
 def test_smoke_script_refuses_a_cpu():
     proc = subprocess.run(
         [sys.executable, str(REPO / "chip_smoke.py")],
