@@ -45,7 +45,6 @@ from sitewhere_tpu.ops.windows import (
     gather_windows,
     init_window_state,
     ring_values,
-    update_and_gather,
     update_gather_ranked,
     update_windows,
 )
@@ -662,31 +661,22 @@ class ShardedScorer:
                 )
             else:
                 m = validity
+            # the window scatter/gather over every slot of the stacked
+            # state (memory ops, no matmuls; per slot inside, the
+            # counters written once for all slots)
+            st2, w, n, later = update_gather_ranked(
+                state, ids.astype(jnp.int32), vals.astype(jnp.float32), m)
             if not fused:
-                def one(p, st, act, i, v, m1):
-                    i = i.astype(jnp.int32)
-                    v = v.astype(jnp.float32)
-                    st2, w, n = update_and_gather(st, i, v, m1)
-                    s1 = spec.score(p, cfg, w, n)
-                    return st2, jnp.where(act & m1, s1, 0.0).astype(
-                        score_dtype
-                    )
+                def one(p, act, w1, n1, m1):
+                    s1 = spec.score(p, cfg, w1, n1)
+                    return jnp.where(act & m1, s1, 0.0).astype(score_dtype)
 
-                st2, s = jax.vmap(one)(params, state, active, ids, vals, m)
+                s = jax.vmap(one)(params, active, w, n, m)
             else:
-                # fused megabatch path: the window scatter/gather (memory
-                # ops, no matmuls) stays vmapped per slot, but scoring
-                # runs ONE weight-stacked kernel over the whole
-                # [T_loc, B_loc] tenant plane (spec.score_stacked — a
-                # single wide einsum per gate contraction instead of
-                # T_loc small matmuls)
-                def upd(st, i, v, m1):
-                    i = i.astype(jnp.int32)
-                    v = v.astype(jnp.float32)
-                    st2, w, n, later = update_gather_ranked(st, i, v, m1)
-                    return st2, w, n, later
-
-                st2, w, n, later = jax.vmap(upd)(state, ids, vals, m)
+                # fused megabatch path: scoring runs ONE weight-stacked
+                # kernel over the whole [T_loc, B_loc] tenant plane
+                # (spec.score_stacked — a single wide einsum per gate
+                # contraction instead of T_loc small matmuls)
                 sk = spec.score_stacked(params, cfg, w, n, k=k_steps)
                 if k_steps > 1:
                     # per-row timestep resolution: a row with ``later``
@@ -1376,12 +1366,8 @@ class ShardedScorer:
                 < validity
             )
 
-            def upd(st, i, v, m1):
-                return update_windows(
-                    st, i.astype(jnp.int32), v.astype(jnp.float32), m1
-                )
-
-            return jax.vmap(upd)(state, ids, vals, m)
+            return update_windows(
+                state, ids.astype(jnp.int32), vals.astype(jnp.float32), m)
 
         smapped = shard_map(
             local_ingest,

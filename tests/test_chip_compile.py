@@ -182,14 +182,22 @@ def test_step_counts_compiles_for_v5e(one_chip_scorer, topo):
     assert rep["temp_mb"] < 8_000, rep  # fits a 16 GB chip with room
 
 
-def whole_state_ops(hlo: str, n_elems: int) -> list:
-    """(name, opcode, called computation's text) of every instruction of
-    the entry computation that produces an array of ``n_elems`` elements
-    or more by doing work — parameters, bitcasts and tuple plumbing move
-    nothing and are left out."""
-    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M).group(1)
+def computation(hlo: str, name: str = "ENTRY") -> str:
+    """The body of the computation ``name`` (``%region_3.12``), or of
+    the entry computation."""
+    head = r"^ENTRY [^\n]*" if name == "ENTRY" else r"^" + re.escape(name) + " [^\n]*"
+    return re.search(head + r"\{\n(.*?)^\}", hlo, re.S | re.M).group(1)
+
+
+def whole_state_ops(hlo: str, n_elems: int, name: str = "ENTRY",
+                    exact: bool = False) -> list:
+    """(name, opcode, called computation's text, result type) of every
+    instruction of the computation ``name`` (the entry one by default)
+    that produces an array of ``n_elems`` elements or more (exactly
+    that many with ``exact``) by doing work — parameters, bitcasts and
+    tuple plumbing move nothing and are left out."""
     found = []
-    for line in entry.splitlines():
+    for line in computation(hlo, name).splitlines():
         m = re.match(
             r"\s*(?:ROOT )?(\S+) = (\(.*?\)|\S+) ([\w-]+)\(", line)
         if not m or m.group(3) in (
@@ -199,14 +207,10 @@ def whole_state_ops(hlo: str, n_elems: int) -> list:
             int(np.prod([int(d) for d in dims.split(",") if d]))
             for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2))
         ]
-        if max(sizes, default=0) >= n_elems:
+        if (n_elems in sizes) if exact else max(sizes, default=0) >= n_elems:
             callee = re.search(r"calls=(%[\w.-]+)", line)
-            body = ""
-            if callee:
-                body = re.search(
-                    r"^" + re.escape(callee.group(1)) + r" [^\n]*\{\n(.*?)^\}",
-                    hlo, re.S | re.M).group(1)
-            found.append((m.group(1), m.group(3), body))
+            body = computation(hlo, callee.group(1)) if callee else ""
+            found.append((m.group(1), m.group(3), body, line))
     return found
 
 
@@ -226,8 +230,8 @@ def test_step_touches_the_window_state_only_where_it_scatters(
     assert mem.temp_size_in_bytes < state_bytes // 10, mem
     assert mem.alias_size_in_bytes >= state_bytes, mem
     ops = whole_state_ops(compiled.as_text(), t * max_streams * w)
-    assert len(ops) == 1, [(name, op) for name, op, _ in ops]
-    name, op, body = ops[0]
+    assert len(ops) == 1, [(name, op) for name, op, _, _ in ops]
+    name, op, body, _ = ops[0]
     assert op == "scatter" or " scatter(" in body, (name, op)
 
 
@@ -272,6 +276,50 @@ def test_step_picks_window_lanes_without_an_element_gather(
     # the largest bucket's temporaries are the model's, 2.8 GB with or
     # without the element gather
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12e9, mem
+
+
+def test_step_writes_the_counters_in_place_at_the_batch_streams(
+        one_chip_scorer, topo):
+    """At the benchmark cell's size (32 slots, 1,171,875 streams, W 32,
+    bucket 1,024): ``pos`` and ``count`` are read at the batch's ids and
+    written back there. The TPU's scatter writes only a linear array, and
+    the [32, 1171875] counters are tiled (8, 128): every scatter into
+    them went through a copy to a flat s32[37500000] and back, in a
+    ``while`` (5.1 of the step's 16.4 ms on the v5e, PERF.md section 5).
+    Outside one conditional nothing of the step yields an array of the
+    counters' size; the branch a batch of up to ``T * S //
+    COUNTERS_PER_WRITE`` streams (961 here) takes holds no flat copy and
+    writes the counters where they lie, one ``dynamic-update-slice`` a
+    stream; the store stays aliased."""
+    from sitewhere_tpu.ops.windows import COUNTERS_PER_WRITE
+
+    t, w, s = one_chip_scorer.n_slots, one_chip_scorer.window, 1_171_875
+    assert 0 < t * s // COUNTERS_PER_WRITE < t * 1024  # a conditional
+    compiled = lower_step_counts(one_chip_scorer, 1024, s).compile()
+    hlo = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= t * s * (w + 2) * 4, mem
+    entry = whole_state_ops(hlo, t * s, exact=True)
+    assert [op for _, op, _, _ in entry] == ["conditional"], [
+        (name, op) for name, op, _, _ in entry]
+    flat = f"s32[{t * s}]"
+    assert not [line for line in computation(hlo).splitlines()
+                if " while(" in line and flat in line]
+    branches = re.search(
+        r"branch_computations=\{([^}]*)\}", entry[0][3]).group(1).split(", ")
+    in_place = []
+    for branch in branches:
+        ops = whole_state_ops(hlo, t * s, branch, exact=True)
+        if any(flat in line for _, _, _, line in ops):
+            continue  # the whole-array branch
+        for name, op, _, line in ops:
+            assert op == "while" and f"s32[{t},{s}]" in line, (name, op)
+            body = re.search(r"body=(%[\w.-]+)", line).group(1)
+            writes = whole_state_ops(hlo, t * s, body, exact=True)
+            assert {op for _, op, _, _ in writes} == {
+                "dynamic-update-slice"}, [(n, o) for n, o, _, _ in writes]
+            in_place.append(name)
+    assert len(in_place) == 1, (branches, in_place)
 
 
 def test_gather_compiles_for_v5e(one_chip_scorer, topo):

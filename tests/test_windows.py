@@ -5,12 +5,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from sitewhere_tpu.ops import windows as windows_mod
 from sitewhere_tpu.ops.windows import (
     LANES,
     gather_windows,
     init_window_state,
     ring_values,
     update_and_gather,
+    update_gather_ranked,
     update_windows,
 )
 
@@ -123,10 +125,7 @@ class _NumpyRings:
 def _batch(rng, s, w, burst):
     """One padded batch: every stream up to W//2 + 1 rows (duplicates),
     ``burst`` rows more than W for stream 0, three padded rows whose ids
-    mean nothing; shuffled, so same-stream rows are apart. (The burst
-    sits on the lowest id, and no other stream passes W in a batch:
-    ``_segment_ranks`` gives a run the largest total of the runs sorted
-    after it — PERF.md section 7, 1 — and would drop their rows.)"""
+    mean nothing; shuffled, so same-stream rows are apart."""
     cap = w // 2 + 1
     b = s * cap + w + 6
     counts = rng.integers(0, cap + 1, s)
@@ -185,6 +184,173 @@ def test_lane_dense_store_vs_reference(s, w, slots):
             np.asarray(st.pos).reshape(t, s)[k], ref.pos)
         np.testing.assert_array_equal(
             np.asarray(st.count).reshape(t, s)[k], ref.count)
+
+
+def _mixed_batch(rng, streams, cap, w):
+    """Rows for the ids ``streams`` of a store of ``cap``: duplicates up
+    to W//2 + 1 rows a stream, bursts of W + 1 to W + 9 rows on two
+    streams picked at random (any id, so a burst may sort after a shorter
+    run), padding rows with ids in range and past it; shuffled."""
+    counts = rng.integers(0, w // 2 + 2, len(streams))
+    counts[rng.choice(len(streams), 2, replace=False)] = rng.integers(
+        w + 1, w + 10, 2)
+    ids = np.repeat(streams, counts)
+    pad = np.concatenate(
+        [rng.integers(0, cap, 5), cap + rng.integers(0, 9, 2)])
+    valid = np.concatenate([np.ones(len(ids), bool), np.zeros(len(pad), bool)])
+    order = rng.permutation(len(valid))
+    return np.concatenate([ids, pad])[order].astype(np.int32), valid[order]
+
+
+def _later(ids, valid):
+    """Valid rows of the same stream after each row (0 for padding)."""
+    later = np.zeros(len(ids), np.int32)
+    for row in np.flatnonzero(valid):
+        after = valid[row + 1:] & (ids[row + 1:] == ids[row])
+        later[row] = after.sum()
+    return later
+
+
+# a store of 7 streams, every one written: its counters are written whole
+# (7 x 3 counters cost less than one stream written in place); a store of
+# 3 x COUNTERS_PER_WRITE streams a slot with 3 of them written: in place,
+# one stream at a time (W 160 is left out of it: 75 MB of rings a slot)
+_SMALL, _LARGE = "7-streams-whole", "3-in-117k-one-by-one"
+
+
+@pytest.mark.parametrize("slots", ["one", "stacked-3", "vmap-3"])
+@pytest.mark.parametrize("w,store", [
+    (32, _SMALL), (48, _SMALL), (160, _SMALL), (32, _LARGE), (48, _LARGE)])
+def test_ranked_update_vs_sequential_rings(w, store, slots):
+    """Every output of ``update_gather_ranked`` — the store, ``pos``,
+    ``count``, each row's window, ``n`` and ``later`` — against the
+    row-at-a-time reference, over batches with duplicates, padding and
+    bursts longer than W on any stream: one slot, three slots stacked as
+    the step passes them, three under ``vmap``; the counters written
+    whole in a small store and one stream at a time in a large one."""
+    rng = np.random.default_rng(40_000 + w)
+    t = 1 if slots == "one" else 3
+    if store == _SMALL:
+        s, streams = 7, [np.arange(7)] * t
+    else:
+        s = 3 * windows_mod.COUNTERS_PER_WRITE
+        streams = [rng.choice(s, 3, replace=False) for _ in range(t)]
+    refs = [_NumpyRings(s, w) for _ in range(t)]
+    st = init_window_state(s, w)
+    fn = update_gather_ranked
+    if t > 1:
+        st = jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x[None], (t,) + x.shape).copy(), st)
+        if slots == "vmap-3":
+            fn = jax.vmap(fn)
+    fn = jax.jit(fn, donate_argnums=0)
+    for _ in range(4):
+        batches = [_mixed_batch(rng, ids, s, w) for ids in streams]
+        b = max(len(ids) for ids, _ in batches)
+        ids = np.stack([np.pad(i, (0, b - len(i))) for i, _ in batches])
+        valid = np.stack([np.pad(v, (0, b - len(v))) for _, v in batches])
+        vals = rng.normal(size=ids.shape).astype(np.float32)
+        args = (ids, vals, valid) if t > 1 else (ids[0], vals[0], valid[0])
+        st, win, n, later = fn(st, *map(jnp.asarray, args))
+        win = np.asarray(win).reshape(ids.shape + (w,))
+        n = np.asarray(n).reshape(ids.shape)
+        later = np.asarray(later).reshape(ids.shape)
+        for k, ref in enumerate(refs):
+            ref.write(ids[k], vals[k], valid[k])
+            np.testing.assert_array_equal(
+                later[k], _later(ids[k], valid[k]))
+            for row in np.flatnonzero(valid[k]):
+                i = int(ids[k, row])
+                assert n[k, row] == min(len(ref.samples[i]), w)
+                np.testing.assert_array_equal(
+                    win[k, row],
+                    np.asarray(_np_windows(ref.samples, w, i), np.float32))
+        rings = np.asarray(ring_values(st)).reshape(t, s, w)
+        for k, ref in enumerate(refs):
+            np.testing.assert_array_equal(rings[k], ref.ring)
+            np.testing.assert_array_equal(
+                np.asarray(st.pos).reshape(t, s)[k], ref.pos)
+            np.testing.assert_array_equal(
+                np.asarray(st.count).reshape(t, s)[k], ref.count)
+
+
+@pytest.mark.parametrize("cap", [4, 2 * windows_mod.COUNTERS_PER_WRITE],
+                         ids=["whole", "one-by-one"])
+def test_a_longer_run_on_a_higher_id_leaves_a_shorter_one_whole(cap):
+    """Streams 0 and 1 with 8 and 40 rows in one batch, W 32 (PERF.md
+    section 7, 1: a reversed cummax gave stream 0 the total 40, so none
+    of its rows was the newest W and none was written): stream 0 keeps
+    all 8, stream 1 its newest 32, and both counters are exact — in a
+    store of 4 (counters written whole) and of 78,000 (in place)."""
+    w = 32
+    ids = np.array([0] * 8 + [1] * 40, np.int32)
+    order = np.random.default_rng(8).permutation(len(ids))
+    ids = ids[order]
+    vals = np.arange(len(ids), dtype=np.float32)
+    st, win, n, later = jax.jit(update_gather_ranked)(
+        init_window_state(cap, w), jnp.asarray(ids), jnp.asarray(vals),
+        jnp.ones(len(ids), bool))
+    want0 = vals[ids == 0]
+    want1 = vals[ids == 1][-w:]
+    np.testing.assert_array_equal(np.asarray(st.count)[:4], [8, 40, 0, 0])
+    np.testing.assert_array_equal(np.asarray(st.pos)[:4], [8, 40 % w, 0, 0])
+    assert not np.asarray(st.count)[2:].any()
+    rows = np.asarray(win)
+    np.testing.assert_array_equal(
+        rows[np.flatnonzero(ids == 0)[-1]],
+        np.concatenate([np.full(w - 8, want0[0]), want0]))
+    np.testing.assert_array_equal(rows[np.flatnonzero(ids == 1)[-1]], want1)
+    np.testing.assert_array_equal(np.asarray(n), np.where(ids == 0, 8, w))
+    np.testing.assert_array_equal(np.asarray(later), _later(ids, ids >= 0))
+
+
+@pytest.mark.parametrize("slots", [1, 3])
+@pytest.mark.parametrize("room", ["none", "one", "all-but-one", "all"])
+def test_the_counters_shape_picks_how_they_are_written(room, slots):
+    """``update_windows`` over batches of 8 rows a slot, the last of them
+    padding, so 2 to 7 streams a slot are written: the counters' size
+    alone decides how. A store whose size over ``COUNTERS_PER_WRITE``
+    leaves room to write ``most`` streams in place builds only the
+    whole-array add where that is 0 (``none``), only the in-place loop
+    where every row of the batch fits (``all``), and a conditional
+    between the two otherwise: with room for ``one`` every batch takes
+    the whole add, with room for ``all-but-one`` every batch the loop.
+    ``pos``, ``count`` and the rings are exact either way."""
+    b, w = 8, 4
+    most = {"none": 0, "one": 1, "all-but-one": slots * b - 1,
+            "all": slots * b}[room]
+    built = {"none": "whole", "all": "one-by-one"}.get(room, "cond")
+    s = -(-most * windows_mod.COUNTERS_PER_WRITE // slots) if most else 7
+    rng = np.random.default_rng(most * 10 + slots)
+    refs = [_NumpyRings(s, w) for _ in range(slots)]
+    st = init_window_state(s, w)
+    if slots > 1:
+        st = jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x[None], (slots,) + x.shape).copy(),
+            st)
+    streams = rng.choice(s, 5, replace=False)
+    for step in range(3):
+        ids = rng.choice(streams, (slots, b)).astype(np.int32)
+        ids[0, :2] = streams[:2]  # two streams or more a batch
+        valid = np.arange(b) < b - 1
+        valid = np.broadcast_to(valid, ids.shape)
+        vals = rng.normal(size=ids.shape).astype(np.float32)
+        args = [jnp.asarray(a if slots > 1 else a[0])
+                for a in (ids, vals, valid)]
+        if step == 0:
+            text = str(jax.make_jaxpr(update_windows)(st, *args))
+            assert ("cond[" in text) == (built == "cond"), text
+            assert ("while[" in text) == (built != "whole"), text
+        st = jax.jit(update_windows, donate_argnums=0)(st, *args)
+        for k, ref in enumerate(refs):
+            ref.write(ids[k], vals[k], valid[k])
+    for k, ref in enumerate(refs):
+        np.testing.assert_array_equal(
+            np.asarray(st.pos).reshape(slots, s)[k], ref.pos)
+        np.testing.assert_array_equal(
+            np.asarray(st.count).reshape(slots, s)[k], ref.count)
+        np.testing.assert_array_equal(
+            np.asarray(ring_values(st)).reshape(slots, s, w)[k], ref.ring)
 
 
 # bit patterns a store can hold and arithmetic would not pass through:
