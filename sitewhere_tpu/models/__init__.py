@@ -18,7 +18,14 @@ from __future__ import annotations
 from dataclasses import dataclass, fields
 from typing import Any, Callable, Dict, Optional, Tuple
 
-from sitewhere_tpu.models import deepar, lstm_ad, nemotron_h, transformer, vit
+from sitewhere_tpu.models import (
+    afmoe,
+    deepar,
+    lstm_ad,
+    nemotron_h,
+    transformer,
+    vit,
+)
 from sitewhere_tpu.models.common import (
     DEFAULT_SCORE_RANGE,
     deepar_flops_per_row,
@@ -39,6 +46,7 @@ __all__ = [
     "transformer",
     "vit",
     "nemotron_h",
+    "afmoe",
 ]
 
 
@@ -85,6 +93,12 @@ class ModelSpec:
     init_state: Optional[Callable] = None
     advance: Optional[Callable] = None
     state_traffic: Optional[Callable] = None
+    # a family whose store also holds leaves led by a PAGE axis (a pool
+    # the slot's streams share, handed out by the host) brings
+    #   page_pool(cfg) -> parallel.streamstate.PageLayout
+    # and its ``advance`` takes ``pages=i32[N, 1 + table]``: each row's
+    # first logical page held, then its physical pages in order
+    page_pool: Optional[Callable] = None
 
 
 MODEL_REGISTRY: Dict[str, ModelSpec] = {
@@ -132,6 +146,16 @@ MODEL_REGISTRY: Dict[str, ModelSpec] = {
         state_traffic=nemotron_h.state_traffic,
         flops_per_row=nemotron_h.flops_per_row,
         score_range=nemotron_h.SCORE_RANGE,
+    ),
+    "afmoe": ModelSpec(
+        name="afmoe",
+        config_cls=afmoe.AfmoeConfig,
+        init=afmoe.init,
+        init_state=afmoe.init_state,
+        advance=afmoe.advance,
+        page_pool=afmoe.page_pool,
+        flops_per_row=afmoe.flops_per_row,
+        score_range=afmoe.SCORE_RANGE,
     ),
     "vit_b16": ModelSpec(
         name="vit_b16",

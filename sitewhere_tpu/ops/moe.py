@@ -19,6 +19,11 @@ weight tiles: a step that hits 35 experts a layer with one or two rows
 each spent 25 of its 30 ms in 11,000 32 KB tile fetches a product — my
 chip run, PR 39.) Router scores and gates are f32; the expert products
 are bf16 with f32 accumulation.
+
+Two expert forms: ``W_down relu(W_up u)^2`` (Nemotron-H) and the gated
+``W_down (silu(W_gate u) * W_up u)`` (SwiGLU; afmoe), whose gate and up
+matrices are one stored matrix ``[gate | up]`` so that a step multiplies
+each held expert once on the way up.
 """
 
 from __future__ import annotations
@@ -90,6 +95,12 @@ def relu2(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.square(jax.nn.relu(x))
 
 
+def swiglu(h: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``silu(gate) * up`` of a product with ``[gate | up]``: the first
+    ``width`` columns are the gate's, the next ``width`` the up's."""
+    return jax.nn.silu(h[..., :width]) * h[..., width:2 * width]
+
+
 def held_experts(
     u: jnp.ndarray,            # [N, H] rows (normed hidden)
     idx: jnp.ndarray,          # i32[N, k] expert picked, over ALL experts
@@ -98,6 +109,7 @@ def held_experts(
     w_up: jnp.ndarray,         # [E_held, H, I (+ zero columns to a tile)]
     w_down: jnp.ndarray,       # [E_held, I (+ zero rows alike), H]
     held_lo: int,
+    gated: bool = False,       # w_up is [E_held, H, 2 I]: [gate | up]
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' share ``sum_e g_e E_e(u)`` f32[N, H], and the
     step's counters i32[3]: (row, expert) pairs routed, pairs that fell
@@ -113,8 +125,9 @@ def held_experts(
     sizes = jnp.zeros((e_held + 1,), jnp.int32).at[group].add(1)[:e_held]
     xs = u[row].astype(w_up.dtype)
     h = grouped_product(xs, w_up, sizes)
-    y = grouped_product(
-        relu2(h[:, : w_down.shape[1]]).astype(w_down.dtype), w_down, sizes)
+    width = w_down.shape[1]
+    act = swiglu(h, width) if gated else relu2(h[:, :width])
+    y = grouped_product(act.astype(w_down.dtype), w_down, sizes)
     g = jnp.where(here, gates, 0.0).reshape(-1)[order]
     # rows past the last group belong to no expert: whatever the grouped
     # product left there is dropped, not scaled
@@ -135,4 +148,15 @@ def dense_relu2(
     h = jnp.dot(u.astype(w_up.dtype), w_up,
                 preferred_element_type=jnp.float32)
     return jnp.dot(relu2(h).astype(w_down.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+
+
+def dense_swiglu(
+    u: jnp.ndarray, w_gate_up: jnp.ndarray, w_down: jnp.ndarray
+) -> jnp.ndarray:
+    """A shared expert or a dense MLP of the gated form:
+    W_down (silu(W_gate u) * W_up u), ``w_gate_up`` = [gate | up]."""
+    h = jnp.dot(u.astype(w_gate_up.dtype), w_gate_up,
+                preferred_element_type=jnp.float32)
+    return jnp.dot(swiglu(h, w_down.shape[0]).astype(w_down.dtype), w_down,
                    preferred_element_type=jnp.float32)

@@ -49,7 +49,7 @@ from sitewhere_tpu.ops.windows import (
     update_windows,
 )
 from sitewhere_tpu.parallel.mesh import AXIS_DATA, AXIS_TENANT, MeshManager
-from sitewhere_tpu.parallel.streamstate import StreamPrograms, plan
+from sitewhere_tpu.parallel.streamstate import PagePool, StreamPrograms, plan
 
 Params = Any
 
@@ -329,6 +329,8 @@ class ShardedScorer:
             # (device i32[3], host dict) for the service to publish
             self._staged_plan = None
             self.last_stats = None
+            # a paged family's pool, handed out by the host
+            self.pager = self._fresh_pager()
         else:
             self._step = self._build_step()
             self._step_counts = self._build_step(counts_mode=True)
@@ -340,6 +342,13 @@ class ShardedScorer:
         # lazy per-slot (unstacked) shard fns for weight paging's
         # stage_slot_params — most scorers never page and must not pay
         self._slot_shard_fns = None
+
+    def _fresh_pager(self):
+        """Every page of every slot's pool free (None: no pool)."""
+        if self.spec.page_pool is None:
+            return None
+        return PagePool(self.n_slots, self.max_streams,
+                        self.spec.page_pool(self.cfg))
 
     def _init_stacked(self) -> Params:
         """Every slot's weights as first initialised, made as one stack
@@ -437,7 +446,7 @@ class ShardedScorer:
         return plan(
             _np.asarray(stream_ids), self.programs.tokens(values),
             _np.asarray(counts).sum(axis=1), self.programs.chunk,
-            self.max_streams,
+            self.max_streams, self.programs.extra,
         )
 
     def _stream_step(self, stream_ids, values, counts) -> jnp.ndarray:
@@ -448,8 +457,18 @@ class ShardedScorer:
             calls, host_stats = staged[1]
         else:  # not staged through ``stage_inputs`` (tests, a probe)
             calls, host_stats = self._plan(stream_ids, values, counts)
-        self.state, scores, hist, dev_stats = self.programs.run(
-            self.params, self.state, calls, stream_ids.shape[1])
+        if self.pager is not None:
+            # the pages each row's run reaches, granted now: a plan that
+            # was staged but never dispatched took none
+            host_stats.update(self.pager.grant(calls))
+        try:
+            self.state, scores, hist, dev_stats = self.programs.run(
+                self.params, self.state, calls, stream_ids.shape[1])
+        except Exception:
+            # the host's token counts follow what reached the device
+            if self.pager is not None:
+                self.pager.undo()
+            raise
         if self.sketch:
             self.last_sketch = hist
         # the counters ride back beside the scores: by the time the
@@ -940,6 +959,8 @@ class ShardedScorer:
                 self.params, global_slot, self._pristine_params())
         self._invalidate_kernel()
         self.state = _zero_slot_donated(self.state, global_slot)
+        if getattr(self, "pager", None) is not None:
+            self.pager.release(global_slot)
         if getattr(self, "_opt_state", None) is not None:
             self._opt_state = jax.tree_util.tree_map(
                 lambda s, f: s.at[global_slot].set(f.astype(s.dtype)),
@@ -1078,6 +1099,7 @@ class ShardedScorer:
         if self.stateful:
             self.programs._advance.clear()
             self._staged_plan = self.last_stats = None
+            self.pager = self._fresh_pager()
         else:
             self._step = self._build_step()
             self._step_counts = self._build_step(counts_mode=True)

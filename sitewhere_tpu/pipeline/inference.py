@@ -3606,10 +3606,14 @@ class TpuInferenceService(MultitenantService):
         "Stream-state families"): which program each row rode, the
         expert layers' routing, the state the step moved. The device
         counters left the programs the scores left, so they have landed."""
-        from sitewhere_tpu.parallel.streamstate import DEVICE_STATS
+        from sitewhere_tpu.parallel.streamstate import DEVICE_STATS, PAGE_GAUGES
 
         dev, host = pf.stream_stats
         counts = dict(zip(DEVICE_STATS, np.asarray(dev).tolist()), **host)
+        for name in PAGE_GAUGES:
+            if name in counts:
+                self.metrics.gauge(f"tpu_inference.stream_{name}").set(
+                    counts.pop(name))
         traffic = scorer.spec.state_traffic
         if traffic is not None:
             counts["state_read_bytes"], counts["state_written_bytes"] = (

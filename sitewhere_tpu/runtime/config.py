@@ -349,6 +349,13 @@ TENANT_TEMPLATES: Dict[str, Dict[str, Any]] = {
         "model_config": {},
         "datasets": ["empty"],
     },
+    "sensor-tokens-paged": {
+        # the same readings scored by a stream-state family whose keys
+        # and values live in pages (models/afmoe.py)
+        "model": "afmoe",
+        "model_config": {},
+        "datasets": ["empty"],
+    },
     "forecasting": {
         "model": "deepar",
         "model_config": {"context": 128},

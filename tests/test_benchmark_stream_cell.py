@@ -170,7 +170,7 @@ def test_new_readers_read_the_counters_and_nothing_where_there_is_none():
     cfg = json.loads((run.ROOT / "benchmark/configs/"
                       "nemotron3-nano-30b-1t.json").read_text())
     new = [m["name"] for m in BENCH["per_layer"]
-           if m["workloads"] == [CELL]]
+           if m["workloads"][0] == CELL]
     assert len(new) == 8
     bare = {"window": run.Span({}, {}, 40.0), "traced": None, "trace": None,
             "config": cfg, "device": {"kind": "TPU v5 lite"}}

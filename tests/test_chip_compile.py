@@ -467,3 +467,56 @@ def test_reference_lstm_matches_the_model_in_f32():
     got = chip_smoke.lstm_ad_reference(
         jax.tree_util.tree_map(np.asarray, params), windows)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("one_step, rows", [(True, 32), (True, 128),
+                                            (False, 8)])
+def test_paged_stream_programs_compile_for_v5e_at_published_widths(
+        topo, one_step, rows, monkeypatch):
+    """The paged family's two programs at the published widths, 128
+    streams' window rings and the full layers' pool (shapes only): they
+    fit beside 6.04 GB of weights and 6.84 GB of store, the store is
+    written in place, attention is the paged kernel over pages where
+    they live (no temporary of rows x a stream's pages), and the expert
+    products are kernels."""
+    from sitewhere_tpu.models.common import sketch_edges
+    from sitewhere_tpu.ops import moe, paged_attention
+    from sitewhere_tpu.parallel.streamstate import StreamPrograms
+
+    # the backend here is the CPU; the chip's branches are what compile
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    spec = get_model("afmoe")
+    cfg = make_config("afmoe", {})
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                (1,) + x.shape, x.dtype, sharding=one), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: spec.init(jax.random.PRNGKey(0), cfg)))
+    state = placed(jax.eval_shape(lambda: spec.init_state(cfg, 128)))
+    progs = StreamPrograms(spec, cfg, 1, 128, jnp.float32,
+                           sketch_edges(1.0, 64.0, 64))
+    length = 1 if one_step else cfg.chunk_size
+    packed = jax.ShapeDtypeStruct(
+        (rows, 2 + 2 * length + progs.extra), jnp.int32, sharding=one)
+    compiled = progs.program(one_step, 0).lower(
+        params, state, packed).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(state))
+    assert state_bytes == 128 * 28_311_552 + 3_221_225_472 + 128 * 8196
+    assert mem.alias_size_in_bytes == state_bytes       # written in place
+    # a row's pages are read where they live: no temporary holds a
+    # stream's 128 x 512 KB of full-layer pages for every row
+    assert mem.temp_size_in_bytes < 0.6e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 0.88 * 16 * 2**30)
+    text = compiled.as_text()
+    assert text.count("ragged_paged_attention") >= 8     # 8 layers
+    assert text.count("gmm") >= 12                       # 6 layers x 2
+    assert not re.search(r"bf16\[\d+,256,8,128\]\S* copy\(", text)
