@@ -360,8 +360,9 @@ def clamp_fuse_k(k: int, window: int) -> int:
 # -- device-side score sketches (score-quality observability) --------------
 #
 # Each scoring flush emits a fixed-bin score histogram per stacked tenant
-# slot, accumulated ON DEVICE inside the jitted step (parallel.sharded —
-# one segment_sum over the masked score plane) and ridden home on the
+# slot, accumulated ON DEVICE inside the jitted step (parallel.sharded →
+# ``sketch_histogram``: each score compared with the edges, counts reduced
+# over the lane axis — no gather, no scatter) and ridden home on the
 # existing async d2h reaper path. Bin edges are log-spaced over the
 # family's declared score range (``ModelSpec.score_range``): anomaly
 # scores are sigma-ish units spanning decades, so log bins keep both the
@@ -385,14 +386,35 @@ def sketch_edges(
 ):
     """The ``nbins - 1`` interior bin edges, log-spaced over (lo, hi):
     bin 0 is [0, lo), bin nbins-1 is [hi', inf) — np.histogram semantics
-    (left-closed bins; device binning uses searchsorted side='right' to
-    match exactly). Returns float32 numpy; the jitted step closes over
-    it as a constant."""
+    (left-closed bins; ``sketch_histogram`` matches them exactly).
+    Returns float32 numpy; the jitted step closes over it as a
+    constant."""
     import numpy as np
 
     return np.logspace(
         math.log10(lo), math.log10(hi), nbins - 1, dtype=np.float32
     )
+
+
+def sketch_histogram(scores, valid, edges):
+    """Per-row histogram of ``scores`` [T, B] (any float dtype; binned in
+    f32) where ``valid`` [T, B] and the score is not NaN, over the bins
+    ``[-inf, *edges, inf]`` with np.histogram's left-closed bins:
+    int32 [T, len(edges) + 1].
+
+    A score's bin is the number of edges at or below it, so the count of
+    bins >= k is the count of valid scores >= ``edges[k-1]``: one compare
+    of each score with each edge, summed over the lane axis (the compare
+    fuses into the reduction), and adjacent differences of those counts.
+    No gather, no scatter, no loop."""
+    s = scores.astype(jnp.float32)
+    ok = valid & ~jnp.isnan(s)
+    e = jnp.asarray(edges, jnp.float32)
+    ge = jnp.sum(ok[:, None, :] & (s[:, None, :] >= e[None, :, None]),
+                 axis=-1, dtype=jnp.int32)               # [T, E]
+    n = jnp.sum(ok, axis=-1, dtype=jnp.int32)[:, None]  # [T, 1]
+    at_least = jnp.concatenate([n, ge, jnp.zeros_like(n)], axis=1)
+    return at_least[:, :-1] - at_least[:, 1:]
 
 
 # -- analytic FLOP accounting (device-time / MFU attribution) --------------

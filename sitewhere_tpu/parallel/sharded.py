@@ -39,6 +39,7 @@ from sitewhere_tpu.models.common import (
     clamp_fuse_k,
     quantize_params,
     sketch_edges,
+    sketch_histogram,
 )
 from sitewhere_tpu.ops.windows import (
     WindowState,
@@ -62,7 +63,7 @@ FUSED_STEP_ENABLED = True
 
 # Device-side score sketch kill switch (same pattern): flip to False
 # BEFORE scorer construction to build steps that emit no per-slot score
-# histogram — the rollback knob if the sketch's segment_sum ever shows up
+# histogram — the rollback knob if the sketch's binning ever shows up
 # in a device profile, and the bench's control twin for measuring the
 # sketch's step-time overhead (``scorehealth_pct``).
 SCORE_SKETCH_ENABLED = True
@@ -628,8 +629,9 @@ class ShardedScorer:
 
         Unless ``shadow`` (or the SCORE_SKETCH_ENABLED kill switch is
         off), the step also emits the per-slot score sketch: an
-        ``i32[T, D, NBINS]`` fixed-bin histogram of the masked scores,
-        accumulated with one ``segment_sum`` over the local score plane
+        ``i32[T, D, NBINS]`` fixed-bin histogram of the masked scores
+        (``models.common.sketch_histogram``: each score compared with the
+        edges, counts reduced over the lanes) of the local score plane
         per data shard — zero collectives; the host merges the D partials
         (a 64-int add per slot). NaN scores are excluded on device (the
         resolve path counts them separately).
@@ -639,8 +641,7 @@ class ShardedScorer:
         fused = self.fused and not shadow
         k_steps = self.k_steps if not shadow else 1
         emit_sketch = self.sketch and not shadow
-        nbins = self.nbins
-        edges = jnp.asarray(self.sketch_edges)
+        edges = self.sketch_edges
         score_dtype = (
             {"f32": jnp.float32, "bf16": jnp.bfloat16, "f16": jnp.float16}[
                 self.wire_dtype
@@ -648,23 +649,6 @@ class ShardedScorer:
             if counts_mode
             else jnp.float32
         )
-
-        def sketch_of(s, valid):
-            # s [T_loc, B_loc] scores, valid bool[T_loc, B_loc]: per-slot
-            # histogram via ONE segment_sum over the masked plane. Bin =
-            # searchsorted side='right' (np.histogram's left-closed bins);
-            # invalid/NaN rows map to the dropped overflow segment.
-            t = s.shape[0]
-            sf = s.astype(jnp.float32)
-            b = jnp.searchsorted(edges, sf, side="right").astype(jnp.int32)
-            b = jnp.where(valid & ~jnp.isnan(sf), b, nbins)
-            flat = (
-                jnp.arange(t, dtype=jnp.int32)[:, None] * (nbins + 1) + b
-            ).reshape(-1)
-            hist = jax.ops.segment_sum(
-                jnp.ones_like(flat), flat, num_segments=t * (nbins + 1)
-            )
-            return hist.reshape(t, nbins + 1)[:, :nbins]
 
         # "sw/step" names the step's operations in a profile whatever
         # XLA calls the module (today ``jit_local_step``)
@@ -713,7 +697,7 @@ class ShardedScorer:
                     score_dtype
                 )
             if emit_sketch:
-                hist = sketch_of(s, active[:, None] & m)
+                hist = sketch_histogram(s, active[:, None] & m, edges)
                 return st2, s, hist[:, None, :]
             return st2, s
 

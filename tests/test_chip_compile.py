@@ -278,6 +278,58 @@ def test_step_picks_window_lanes_without_an_element_gather(
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12e9, mem
 
 
+def operand_types(hlo: str, opcode: str) -> list:
+    """(name, result type, first operand's type) of every ``opcode``
+    instruction of the module, fused or not; an operand's type is read
+    from its definition in the same computation."""
+    found = []
+    for body in re.findall(r"^\S[^\n]*\{\n(.*?)^\}", hlo, re.S | re.M):
+        types = dict(re.findall(
+            r"^\s*(?:ROOT )?(%\S+) = (\S+) ", body, re.M))
+        for m in re.finditer(
+                r"^\s*(?:ROOT )?(%\S+) = (\S+) " + opcode + r"\((%[^,)]+)",
+                body, re.M):
+            found.append((m.group(1), m.group(2),
+                          types.get(m.group(3), "")))
+    return found
+
+
+@pytest.mark.parametrize("b_lane", [1024, 16_384])
+def test_step_bins_the_sketch_without_a_gather_a_scatter_or_a_loop(
+        one_chip_scorer, topo, b_lane):
+    """At the benchmark cell's size (32 slots, 1,171,875 streams, W 32)
+    and its smallest and largest buckets: the score sketch compares each
+    score with the 63 edges and counts by a reduction, so no gather of
+    the compiled step reads the f32[63] edges (a binary search, 1.5 ms of
+    the 8.8 ms step on the v5e, PERF.md section 5), no scatter writes
+    anything smaller than the state (the sketch's segment sum into
+    32 x 65 bins), no loop carries a T x B plane, ``pos`` and ``count``
+    still come by their element gathers, and the T x B x 63 comparison
+    lives inside its reduction, never an array of its own."""
+    t, s = one_chip_scorer.n_slots, 1_171_875
+    edges = len(one_chip_scorer.sketch_edges)
+    compiled = lower_step_counts(one_chip_scorer, b_lane, s).compile()
+    hlo = compiled.as_text()
+    assert [g for g in operand_types(hlo, "gather")
+            if g[2].startswith(f"f32[{edges}]")] == []
+    scatters = operand_types(hlo, "scatter")
+    assert scatters and [
+        (name, out) for name, out, _ in scatters
+        if int(np.prod([int(d) for d in re.match(
+            r"\w+\[([\d,]*)\]", out).group(1).split(",") if d])) < t * s
+    ] == []
+    plane = f"s32[{t},{b_lane}]"
+    assert [line for line in hlo.splitlines()
+            if " while(" in line and plane in line] == []
+    assert element_gathers(hlo, t * b_lane)
+    assert whole_state_ops(hlo, t * b_lane * edges, exact=True) == []
+    # the parent's binary search held 302.0 / 2,767.5 MB of temporaries
+    # at these buckets (described-v5e compile; PERF.md section 6)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (302.1e6 if b_lane == 1024
+                                      else 2_767.5e6), mem
+
+
 def test_step_writes_the_counters_in_place_at_the_batch_streams(
         one_chip_scorer, topo):
     """At the benchmark cell's size (32 slots, 1,171,875 streams, W 32,

@@ -15,13 +15,19 @@ import time
 from contextlib import asynccontextmanager
 from pathlib import Path
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import sitewhere_tpu.parallel.sharded as sharded
 from sitewhere_tpu.core.batch import MeasurementBatch
 from sitewhere_tpu.models import get_model, make_config
-from sitewhere_tpu.models.common import SKETCH_NBINS, sketch_edges
+from sitewhere_tpu.models.common import (
+    SKETCH_NBINS,
+    sketch_edges,
+    sketch_histogram,
+)
 from sitewhere_tpu.parallel.mesh import MeshManager
 from sitewhere_tpu.runtime.bus import EventBus
 from sitewhere_tpu.runtime.history import MetricsHistory, Watchdog
@@ -163,6 +169,85 @@ def test_sketch_kill_switch_and_legacy_branch():
     np.testing.assert_array_equal(
         got, _expected_hist(legacy, s_np, counts, 6)
     )
+
+
+def _searchsorted_sketch(scores, valid, edges):
+    """The step's former binning, kept as a reference: a binary search of
+    the edges (side='right') and one segment_sum into [T, NBINS + 1]
+    bins, invalid and NaN rows sent to the dropped last one."""
+    nbins = len(edges) + 1
+    t = scores.shape[0]
+    sf = scores.astype(jnp.float32)
+    b = jnp.searchsorted(jnp.asarray(edges), sf, side="right")
+    b = jnp.where(valid & ~jnp.isnan(sf), b.astype(jnp.int32), nbins)
+    flat = (jnp.arange(t, dtype=jnp.int32)[:, None] * (nbins + 1)
+            + b).reshape(-1)
+    hist = jax.ops.segment_sum(
+        jnp.ones_like(flat), flat, num_segments=t * (nbins + 1))
+    return hist.reshape(t, nbins + 1)[:, :nbins]
+
+
+def _adversarial_plane(case, edges, rng):
+    """(scores [4, 512] in the case's wire dtype, valid bool[4, 512]):
+    slot 0 carries every value of the case once, valid; slots 1 and 2
+    draw from them, 4 lanes in 5 valid (3 in 10 for ``invalid_rows``,
+    every one in slot 2 for ``full_slot``); slot 3 is idle."""
+    on_edges = np.concatenate([
+        edges,
+        np.nextafter(edges, np.float32(-np.inf)),
+        np.nextafter(edges, np.float32(np.inf)),
+    ])
+    specials = np.array([0.0, -0.0, -1e-30, -1.0, -1e30, 1e-30, 1e30,
+                         np.inf, -np.inf, np.nan, -np.nan], np.float32)
+    bulk = rng.lognormal(-1.0, 3.0, 300).astype(np.float32)
+    vals = {
+        "edges": on_edges,
+        "specials": np.concatenate([specials, edges[::7]]),
+        "invalid_rows": bulk,
+        "full_slot": bulk,
+    }.get(case, np.concatenate([on_edges, specials, bulk]))
+    t, b = 4, 512
+    scores = rng.choice(vals, size=(t, b))
+    valid = rng.random((t, b)) < (0.3 if case == "invalid_rows" else 0.8)
+    scores[0, :len(vals)] = vals
+    valid[0, :len(vals)] = True
+    valid[2] |= case == "full_slot"
+    valid[3] = False
+    dtype = {"bf16": jnp.bfloat16, "f16": jnp.float16}.get(case, jnp.float32)
+    with np.errstate(over="ignore"):  # f16 carries ±1e30 as ±inf
+        return scores.astype(dtype), valid
+
+
+@pytest.mark.parametrize("case", ["edges", "specials", "invalid_rows",
+                                  "full_slot", "bf16", "f16"])
+def test_sketch_histogram_is_bit_equal_to_np_histogram_and_the_search(case):
+    """``sketch_histogram`` (each score compared with every edge, counts
+    reduced over the lanes) against np.histogram over [-inf, *edges, inf]
+    and against the former searchsorted + segment_sum form, on planes
+    built to break a binning: scores exactly on each edge and one ulp
+    either side, ±0, negatives, ±inf, NaN, invalid rows that carry finite
+    scores, a slot with every lane valid, an idle slot, and scores on the
+    bf16 / f16 wire."""
+    edges = sketch_edges()
+    rng = np.random.default_rng(sum(map(ord, case)))
+    scores, valid = _adversarial_plane(case, edges, rng)
+    got = np.asarray(jax.jit(sketch_histogram)(scores, valid, edges))
+    ref = np.asarray(jax.jit(_searchsorted_sketch)(scores, valid, edges))
+    s32 = scores.astype(np.float32)
+    bins = np.r_[-np.inf, edges, np.inf]
+    exp = np.stack([
+        np.histogram(row[ok & ~np.isnan(row)], bins=bins)[0]
+        for row, ok in zip(s32, valid)
+    ])
+    assert got.dtype == np.int32 and got.shape == (4, SKETCH_NBINS)
+    np.testing.assert_array_equal(got, exp)
+    np.testing.assert_array_equal(got, ref)
+    assert got.sum() == (valid & ~np.isnan(s32)).sum()
+    assert got[3].sum() == 0
+    if case == "full_slot":
+        assert got[2].sum() == 512
+    if case == "edges":  # both sides of every edge: each bin is hit
+        assert (got[0] > 0).all()
 
 
 # ------------------------------------------------- ScoreHealth statistics
