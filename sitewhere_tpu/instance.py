@@ -58,6 +58,7 @@ from sitewhere_tpu.runtime.lifecycle import (
     LifecycleState,
     cancel_and_wait,
 )
+from sitewhere_tpu.runtime.loopledger import sw
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 from sitewhere_tpu.runtime.tracing import Tracer
 from sitewhere_tpu.services.asset_management import AssetManagement
@@ -748,19 +749,22 @@ class SiteWhereInstance(LifecycleComponent):
             await asyncio.sleep(self.history.resolution_s)
             try:
                 # the tick is the tracing's own cost: the loop ledger's
-                # ``observe`` stage, not ``other``
-                t0 = self.metrics.loop_ledger.clock()
-                # decay idle families' MFU gauges BEFORE sampling so the
-                # ring never records a stale "last busy" value forever
-                self._refresh_mfu()
-                # publish the latency ledgers' rolling p99s / burn rates
-                # as gauges BEFORE sampling so the ring sees this tick's
-                # attribution state, not last tick's
-                self.latency.refresh_gauges()
-                self.history.sample()
-                if self.watchdog is not None:
-                    self.watchdog.evaluate()
-                self.metrics.loop_ledger.observe_from(t0)
+                # ``observe`` stage, not ``other`` (``sw/observe`` under a
+                # capture)
+                ledger = self.metrics.loop_ledger
+                t0 = ledger.clock()
+                with sw("observe"):
+                    # decay idle families' MFU gauges BEFORE sampling so
+                    # the ring never records a stale "last busy" value
+                    self._refresh_mfu()
+                    # publish the latency ledgers' rolling p99s / burn
+                    # rates as gauges BEFORE sampling so the ring sees
+                    # this tick's attribution state, not last tick's
+                    self.latency.refresh_gauges()
+                    self.history.sample()
+                    if self.watchdog is not None:
+                        self.watchdog.evaluate()
+                ledger.observe_from(t0)
             except Exception as exc:  # noqa: BLE001 - a sampling fault
                 # must not kill the blackbox; next tick retries
                 self._record_error("history-tick", exc)

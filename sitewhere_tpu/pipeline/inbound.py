@@ -29,7 +29,6 @@ from sitewhere_tpu.core.events import (
 from sitewhere_tpu.runtime.bus import EventBus, RetryingConsumer
 from sitewhere_tpu.runtime.config import FaultTolerancePolicy
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent, cancel_and_wait
-from sitewhere_tpu.runtime.loopledger import spanned
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 from sitewhere_tpu.services.device_management import DeviceManagement
 
@@ -92,7 +91,6 @@ class InboundProcessor(LifecycleComponent):
             self.poll_batch,
         )
 
-    @spanned("intake")
     async def _handle(self, req) -> None:
         if self.deadline_gate.check(req):
             return  # expired: routed to the expired topic, budget saved

@@ -112,7 +112,7 @@ from sitewhere_tpu.runtime.lifecycle import (
     SupervisedTask,
     cancel_and_wait,
 )
-from sitewhere_tpu.runtime.loopledger import spanned, sw
+from sitewhere_tpu.runtime.loopledger import sw
 from sitewhere_tpu.pipeline.slices import (
     SliceRuntime, _empty_taken, _LaneRing, _PendingFlush,
 )
@@ -1018,7 +1018,6 @@ class TpuInferenceService(MultitenantService):
             self._deliver_pool = None
 
     # -- ingestion → lanes (columnar) ------------------------------------
-    @spanned("lanes")
     async def _enqueue_batch(
         self,
         engine: TpuInferenceEngine,
@@ -1205,21 +1204,20 @@ class TpuInferenceService(MultitenantService):
             done[:k].sort()
             seq_list = done[:k].tolist()
             t_pub = time.perf_counter()
-            with sw("publish"):
-                for i, s in enumerate(seq_list):
-                    try:
-                        await self._publish_batch(
-                            int(s), nowait=publish_nowait, flush_id=flush_id
-                        )
-                    except BaseException:
-                        # cancelled (teardown) or a publish fault
-                        # mid-loop: the remaining completed batches are
-                        # already out of the registry's reach of any
-                        # later resolve — flush them nowait or they
-                        # strand in _batches and their events are lost
-                        for s2 in seq_list[i + 1:]:
-                            await self._publish_batch(int(s2), nowait=True)
-                        raise
+            for i, s in enumerate(seq_list):
+                try:
+                    await self._publish_batch(
+                        int(s), nowait=publish_nowait, flush_id=flush_id
+                    )
+                except BaseException:
+                    # cancelled (teardown) or a publish fault
+                    # mid-loop: the remaining completed batches are
+                    # already out of the registry's reach of any
+                    # later resolve — flush them nowait or they
+                    # strand in _batches and their events are lost
+                    for s2 in seq_list[i + 1:]:
+                        await self._publish_batch(int(s2), nowait=True)
+                    raise
             # the publish loop alone — a child of ``resolve``, which ends
             # where this does
             self.metrics.histogram("tpu_inference.publish", unit="s").record(
@@ -1358,14 +1356,17 @@ class TpuInferenceService(MultitenantService):
         flush_id = self._next_flush_id
         self._next_flush_id += 1
         t_asked = time.perf_counter()
+        # the device's idle before this flush, as the host can split it:
+        # no work due (unfed), or the loop's turn after it fell due
+        idle_unfed, idle_turn = s.idle_split(t_asked)
+        s.due_at = None
         sem = s.permits
         if sem.locked():
             # all of THIS slice's completion slots busy: the flush
             # backpressures here, where depth is the deliver_inflight
             # gauge (check_queues) — other slices' budgets are untouched
             self.metrics.counter("tpu_inference.deliver_backpressure").inc()
-        with sw("permit_wait", flush_id=flush_id):
-            await sem.acquire()
+        await sem.acquire()
         t_got = time.perf_counter()
         self.metrics.histogram("tpu_inference.acquire_wait", unit="s").record(
             t_got - t_asked
@@ -1417,7 +1418,7 @@ class TpuInferenceService(MultitenantService):
             self.metrics.gauge("tpu_inference_lane_rows", family=family).set(
                 depth_left
             )
-            s.first_pending_ts = time.monotonic() if depth_left else None
+            s.first_pending_ts = time.perf_counter() if depth_left else None
             if moved == 0:
                 sem.release()
                 breaker.release_trial()  # allowed, but no call was made
@@ -1548,6 +1549,10 @@ class TpuInferenceService(MultitenantService):
                 ).inc(moved)
             self.metrics.counter("tpu_inference.flushes").inc()
             self.metrics.counter("tpu_inference.flush_rows").inc(moved)
+            self.metrics.histogram(
+                "tpu_inference.idle_unfed", unit="s").record(idle_unfed)
+            self.metrics.histogram(
+                "tpu_inference.idle_turn", unit="s").record(idle_turn)
             # flushes already in flight on this slice as this one joins
             # the device queue (÷ .flushes = the mean depth it waits
             # behind). In flight as the ``inflight`` interval has it:
@@ -1593,6 +1598,8 @@ class TpuInferenceService(MultitenantService):
                 assembly_s=round(assembly_s, 6),
                 h2d_stage_s=round(h2d_stage_s, 6),
                 dispatch_s=round(dispatch_s, 6),
+                idle_unfed_s=round(idle_unfed, 6),
+                idle_turn_s=round(idle_turn, 6),
                 h2d_overlapped=bool(overlapped),
                 compiled=compiling,
                 # kernel variant attribution: which fused-step shape
@@ -1795,15 +1802,17 @@ class TpuInferenceService(MultitenantService):
     def _flush_record(self, family: str, **fields) -> dict:
         """One flush's record: appended to the flight recorder's ring
         (when the service has one) and indexed by ``flush_id``."""
-        t0 = self.metrics.loop_ledger.clock()
-        if self.flightrec is not None:
-            rec = self.flightrec.record("flush", family, **fields)
-        else:
-            rec = fields
-        self.flush_records[fields["flush_id"]] = rec
-        if len(self.flush_records) > self.FLUSH_RECORDS:
-            self.flush_records.popitem(last=False)
-        self.metrics.loop_ledger.observe_from(t0)
+        ledger = self.metrics.loop_ledger
+        t0 = ledger.clock()
+        with sw("observe"):
+            if self.flightrec is not None:
+                rec = self.flightrec.record("flush", family, **fields)
+            else:
+                rec = fields
+            self.flush_records[fields["flush_id"]] = rec
+            if len(self.flush_records) > self.FLUSH_RECORDS:
+                self.flush_records.popitem(last=False)
+        ledger.observe_from(t0)
         return rec
 
     @staticmethod
@@ -3549,12 +3558,11 @@ class TpuInferenceService(MultitenantService):
                 # one future per in-flight FAMILY (a handful), not per row
                 futs.append(h.ensure_host_future(loop, self._deliver_pool))  # hotpath: ok
             try:
-                with sw("reap_wait", flush_id=heads[0].flush_id):
-                    await asyncio.wait(  # supervised: ok(flush-deadline timer races in futs)
-                        [*futs, waiter]
-                        + ([timer] if timer is not None else []),
-                        return_when=asyncio.FIRST_COMPLETED,
-                    )
+                await asyncio.wait(  # supervised: ok(flush-deadline timer races in futs)
+                    [*futs, waiter]
+                    + ([timer] if timer is not None else []),
+                    return_when=asyncio.FIRST_COMPLETED,
+                )
             finally:
                 waiter.cancel()
                 if timer is not None:
@@ -3715,18 +3723,19 @@ class TpuInferenceService(MultitenantService):
                 await self._resolve_rows(seqs, rows, None, family=pf.family)
                 return
             t0 = time.perf_counter()
-            with sw("reap_wait", flush_id=pf.flush_id):
-                scores_np, sketch_np, shadow_np = await asyncio.wait_for(
-                    pf.ensure_host_future(
-                        asyncio.get_running_loop(), self._deliver_pool
-                    ),
-                    timeout=budget,
-                )
+            scores_np, sketch_np, shadow_np = await asyncio.wait_for(
+                pf.ensure_host_future(
+                    asyncio.get_running_loop(), self._deliver_pool
+                ),
+                timeout=budget,
+            )
             # the transfer has landed: the in-flight interval ends and
             # ``resolve`` begins, on one stamp
             now = time.perf_counter()
             service_s = max(0.0, now - max(pf.t_dispatch, s.last_landed))
             s.last_landed = now
+            if pf.t_landed is not None and pf.t_landed > s.landed_at:
+                s.landed_at = pf.t_landed
             # cumulative wait: from the FIRST time the reaper waited on
             # this flush (race rounds included), not just the last await
             waited_s = now - pf.t_wait if pf.t_wait is not None else now - t0
@@ -3743,54 +3752,53 @@ class TpuInferenceService(MultitenantService):
                 # it rode under later compute (raced-on heads never count,
                 # however fast their future resolved afterwards)
                 self.metrics.counter("tpu_inference.d2h_overlapped").inc()
-            with sw("resolve", flush_id=pf.flush_id):
-                t1 = time.perf_counter()
-                # wire dtype (bf16/f16) widens back to f32 at the batch edge
-                if pf.gathered:
-                    picks = scores_np[: pf.moved].astype(np.float32, copy=False)
-                else:
-                    picks = scores_np[_slots, _cols].astype(np.float32, copy=False)
-                # score-quality accounting: per-flush NaN census + the
-                # device sketch folded into the tenant drift windows, all
-                # vectorized (runtime.scorehealth; nan attribution rides the
-                # pack-order slots — one bincount, never a per-row loop)
-                nan_mask = np.isnan(picks)
-                nan_rows = int(nan_mask.sum())
+            t1 = time.perf_counter()
+            # wire dtype (bf16/f16) widens back to f32 at the batch edge
+            if pf.gathered:
+                picks = scores_np[: pf.moved].astype(np.float32, copy=False)
+            else:
+                picks = scores_np[_slots, _cols].astype(np.float32, copy=False)
+            # score-quality accounting: per-flush NaN census + the
+            # device sketch folded into the tenant drift windows, all
+            # vectorized (runtime.scorehealth; nan attribution rides the
+            # pack-order slots — one bincount, never a per-row loop)
+            nan_mask = np.isnan(picks)
+            nan_rows = int(nan_mask.sum())
+            if nan_rows:
+                self.metrics.counter(
+                    "tpu_scores_nan_total", family=pf.family
+                ).inc(nan_rows)
+            if sketch_np is not None:
+                nan_by_slot = None
                 if nan_rows:
-                    self.metrics.counter(
-                        "tpu_scores_nan_total", family=pf.family
-                    ).inc(nan_rows)
-                if sketch_np is not None:
-                    nan_by_slot = None
-                    if nan_rows:
-                        # picks align with the pack-order slots on BOTH the
-                        # gathered and full-plane fallback paths; only the
-                        # single-slot slice zeroed them (override carries it)
-                        if pf.slot_override is not None:
-                            nan_by_slot = np.zeros(
-                                (sketch_np.shape[0],), np.int64
-                            )
-                            nan_by_slot[pf.slot_override] = nan_rows
-                        else:
-                            nan_by_slot = np.bincount(
-                                _slots[nan_mask], minlength=sketch_np.shape[0]
-                            )
-                    self.scorehealth.ingest_sketch(
-                        pf.family, sketch_np.sum(axis=1), nan_by_slot,
-                        mesh_slice=pf.sl,
-                    )
-                if shadow_np is not None:
-                    self._canary_compare(pf, picks, shadow_np)
-                # cancellation past this point observes only INSIDE
-                # _resolve_rows' publish loop (the scatter is await-free), so
-                # scores are written and counts decremented exactly once —
-                # the cancel path below must not resolve a second time
-                scattered = True
-                await self._resolve_rows(
-                    seqs, rows, picks, flush_id=pf.flush_id
+                    # picks align with the pack-order slots on BOTH the
+                    # gathered and full-plane fallback paths; only the
+                    # single-slot slice zeroed them (override carries it)
+                    if pf.slot_override is not None:
+                        nan_by_slot = np.zeros(
+                            (sketch_np.shape[0],), np.int64
+                        )
+                        nan_by_slot[pf.slot_override] = nan_rows
+                    else:
+                        nan_by_slot = np.bincount(
+                            _slots[nan_mask], minlength=sketch_np.shape[0]
+                        )
+                self.scorehealth.ingest_sketch(
+                    pf.family, sketch_np.sum(axis=1), nan_by_slot,
+                    mesh_slice=pf.sl,
                 )
-                t_resolved = time.perf_counter()
-                resolve_s = t_resolved - t1
+            if shadow_np is not None:
+                self._canary_compare(pf, picks, shadow_np)
+            # cancellation past this point observes only INSIDE
+            # _resolve_rows' publish loop (the scatter is await-free), so
+            # scores are written and counts decremented exactly once —
+            # the cancel path below must not resolve a second time
+            scattered = True
+            await self._resolve_rows(
+                seqs, rows, picks, flush_id=pf.flush_id
+            )
+            t_resolved = time.perf_counter()
+            resolve_s = t_resolved - t1
             self.metrics.histogram("tpu_inference.resolve", unit="s").record(
                 resolve_s
             )
@@ -4046,11 +4054,9 @@ class TpuInferenceService(MultitenantService):
 
     # -- main loop -------------------------------------------------------
     async def _scoring_loop(self) -> None:
-        iters = self.metrics.counter("tpu_inference.loop_iters")
         throttled = self.metrics.counter("tpu_inference.fair_throttled")
         held = self.metrics.counter("tpu_inference.flush_held")
         while True:
-            iters.inc()
             moved = 0
             holding = False
             fam_cfgs: Dict[SliceRuntime, Dict[int, TenantEngineConfig]] = {}

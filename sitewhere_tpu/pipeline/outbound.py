@@ -32,7 +32,6 @@ from sitewhere_tpu.runtime.bus import (
 )
 from sitewhere_tpu.runtime.config import FaultTolerancePolicy
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent, cancel_and_wait
-from sitewhere_tpu.runtime.loopledger import sw
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 
 EventFilter = Callable[[DeviceEvent], bool]
@@ -584,46 +583,45 @@ class OutboundDispatcher(LifecycleComponent):
         while True:
             items = await self.bus.consume(src, self.group, self.poll_batch)
             for item in items:
-                with sw("outbound"):
-                    t0 = _time.time() * 1000.0
-                    shed_fanout = False
-                    if isinstance(item, MeasurementBatch):
-                        shed_fanout = self.deadline_gate.check(item) or (
-                            self.overload is not None
-                            and self.overload.degraded(
-                                self.tenant, "pause_fanout"
-                            )
+                t0 = _time.time() * 1000.0
+                shed_fanout = False
+                if isinstance(item, MeasurementBatch):
+                    shed_fanout = self.deadline_gate.check(item) or (
+                        self.overload is not None
+                        and self.overload.degraded(
+                            self.tenant, "pause_fanout"
                         )
-                    if shed_fanout:
-                        # fan-out shed (expired or degraded): no connector
-                        # work, but the TERMINAL span must still seal the
-                        # trace or tail sampling would idle-time-out it
-                        skipped.inc(item.n)
-                        self.stage_timer.observe(
-                            item, t0, _time.time() * 1000.0, n_events=item.n,
-                            delivered=0, shed="overload",
-                        )
-                        continue
-                    if isinstance(item, MeasurementBatch):
-                        results = await asyncio.gather(
-                            *(c.process_batch(item) for c in self.connectors)
-                        )
-                        n_del = sum(results)
-                        delivered.inc(n_del)
-                        n = item.n
-                    else:
-                        results = await asyncio.gather(
-                            *(c.process(item) for c in self.connectors)
-                        )
-                        n_del = sum(bool(r) for r in results)
-                        delivered.inc(n_del)
-                        n = 1
-                    self.stage_timer.observe(
-                        item, t0, _time.time() * 1000.0, n_events=n,
-                        delivered=n_del,
                     )
-                    if isinstance(item, MeasurementBatch) and item.t_scored:
-                        # published on scored-events → the last connector
-                        # is done with the batch: persist and the slowest
-                        # connector lie inside (the rules fork runs beside)
-                        egress.record(_time.perf_counter() - item.t_scored)
+                if shed_fanout:
+                    # fan-out shed (expired or degraded): no connector
+                    # work, but the TERMINAL span must still seal the
+                    # trace or tail sampling would idle-time-out it
+                    skipped.inc(item.n)
+                    self.stage_timer.observe(
+                        item, t0, _time.time() * 1000.0, n_events=item.n,
+                        delivered=0, shed="overload",
+                    )
+                    continue
+                if isinstance(item, MeasurementBatch):
+                    results = await asyncio.gather(
+                        *(c.process_batch(item) for c in self.connectors)
+                    )
+                    n_del = sum(results)
+                    delivered.inc(n_del)
+                    n = item.n
+                else:
+                    results = await asyncio.gather(
+                        *(c.process(item) for c in self.connectors)
+                    )
+                    n_del = sum(bool(r) for r in results)
+                    delivered.inc(n_del)
+                    n = 1
+                self.stage_timer.observe(
+                    item, t0, _time.time() * 1000.0, n_events=n,
+                    delivered=n_del,
+                )
+                if isinstance(item, MeasurementBatch) and item.t_scored:
+                    # published on scored-events → the last connector
+                    # is done with the batch: persist and the slowest
+                    # connector lie inside (the rules fork runs beside)
+                    egress.record(_time.perf_counter() - item.t_scored)

@@ -15,7 +15,6 @@ from sitewhere_tpu.core.batch import MeasurementBatch
 from sitewhere_tpu.runtime.bus import EventBus, RetryingConsumer
 from sitewhere_tpu.runtime.config import FaultTolerancePolicy
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent, cancel_and_wait
-from sitewhere_tpu.runtime.loopledger import spanned
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 from sitewhere_tpu.services.event_store import EventStore
 
@@ -91,7 +90,6 @@ class EventPersistence(LifecycleComponent):
             self.poll_batch,
         )
 
-    @spanned("persist")
     async def _handle(self, item) -> None:
         import time as _time
 

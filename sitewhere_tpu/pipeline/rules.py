@@ -49,7 +49,6 @@ from sitewhere_tpu.core.events import (
 from sitewhere_tpu.runtime.bus import EventBus, RetryingConsumer
 from sitewhere_tpu.runtime.config import FaultTolerancePolicy
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent, cancel_and_wait
-from sitewhere_tpu.runtime.loopledger import spanned
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 
 Predicate = Callable[[DeviceEvent], bool]
@@ -516,7 +515,6 @@ class RuleEngine(LifecycleComponent):
             self.poll_batch,
         )
 
-    @spanned("rules")
     async def _handle(self, item) -> None:
         t0 = time.time() * 1000.0
         if self.deadline_gate.check(item):

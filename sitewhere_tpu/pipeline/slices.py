@@ -219,6 +219,7 @@ class _PendingFlush:
         "poisoned", "flops", "rec", "sketch", "shadow", "slot_override",
         "resolved", "lane", "deadline", "retried", "retry_rows",
         "retry_from", "owns_permit", "flush_id", "stream_stats",
+        "t_landed",
     )
 
     def __init__(
@@ -250,6 +251,11 @@ class _PendingFlush:
         self.plane_nbytes = plane_nbytes
         self.host_future = None
         self.t_wait = None  # when the reaper first started waiting on us
+        # the landing stamp (perf_counter): the worker thread's where
+        # ``_materialize`` returned, or the first ``landed()`` that found
+        # the output ready, whichever came first — the moment the slice's
+        # device went free of this flush, as the host can tell it
+        self.t_landed: Optional[float] = None
         # a flush whose DISPATCH failed (no scores, no transfer): it
         # rides the FIFO so its unscored resolution can't overtake an
         # earlier in-flight flush of the same family
@@ -326,11 +332,14 @@ class _PendingFlush:
     def _materialize(self):
         """Worker-thread materialization of every device output riding
         this flush — one executor hop for scores + sketch + shadow."""
-        return (
+        out = (
             np.asarray(self.scores),
             None if self.sketch is None else np.asarray(self.sketch),
             None if self.shadow is None else np.asarray(self.shadow),
         )
+        if self.t_landed is None:
+            self.t_landed = time.perf_counter()
+        return out
 
     def landed(self) -> bool:
         """Probably-complete signal used to PRIORITIZE heads: a finished
@@ -344,9 +353,12 @@ class _PendingFlush:
         if self.host_future is not None:
             return self.host_future.done()
         try:
-            return bool(self.scores.is_ready())
+            ready = bool(self.scores.is_ready())
         except Exception:  # noqa: BLE001 - non-jax doubles: never "landed"
             return False
+        if ready and self.t_landed is None:
+            self.t_landed = time.perf_counter()  # async: ok(either side's stamp is a landing; one float store)
+        return ready
 
     def ensure_host_future(self, loop, pool):
         """Lazily start (and cache) an executor materialization — used
@@ -400,6 +412,7 @@ class SliceRuntime:
         "family", "sl", "scorer", "breaker", "metrics", "mfu", "lanes",
         "train_lanes", "staging", "staging_slots", "seen_shapes", "permits",
         "reap", "resolving", "first_pending_ts", "last_scores", "last_landed",
+        "landed_at", "due_at",
         "flush_p99", "consec_errors", "quarantine", "probing", "train_ticks",
         "lane_swap", "lane_last_source", "last_train_losses",
     )
@@ -435,7 +448,7 @@ class SliceRuntime:
         self.reap = _ReapQueue()
         # the one resolve task in flight: ≤ 1 keeps the per-tenant FIFO
         self.resolving: Optional[asyncio.Task] = None
-        # monotonic() when the oldest row now on a lane arrived
+        # perf_counter() when the oldest row now on a lane arrived
         self.first_pending_ts: Optional[float] = None
         # last dispatch output, the overlap probe (the next flush's
         # staging "overlapped" ⇔ this is still computing): the GATHERED
@@ -446,6 +459,13 @@ class SliceRuntime:
         # FIFO, so a flush's non-overlapping service time runs from the
         # later of its own dispatch and this
         self.last_landed = 0.0
+        # the host's gap between a landing and the next dispatch, split
+        # (``idle_split``): the newest serve flush's landing stamp
+        # (``_PendingFlush.t_landed``) once it has left the reap queue,
+        # and when a flush first became due since the last dispatch
+        # (None: not due) — both perf_counter()
+        self.landed_at = 0.0
+        self.due_at: Optional[float] = None
         # dispatch→landed history, the flush deadline's source
         self.flush_p99 = RollingQuantile()
         # consecutive scorer errors: failover pacing, chip-local
@@ -484,7 +504,7 @@ class SliceRuntime:
     def mark_pending(self) -> None:
         """Rows landed on a lane: the collect deadline starts with the oldest."""
         if self.first_pending_ts is None:
-            self.first_pending_ts = time.monotonic()
+            self.first_pending_ts = time.perf_counter()
 
     def drain_lanes(self, slot: Optional[int] = None) -> Iterator[tuple]:
         """Pop every lane (of one slot, if given) whole, one at a time —
@@ -512,14 +532,45 @@ class SliceRuntime:
     # -- the flush policy ----------------------------------------------------
     def due(self, mb) -> bool:
         """THE flush policy's first half: a flush is DUE when a lane is
-        full or the oldest pending row has waited the collect deadline."""
-        if any(l.count >= mb.max_batch for l in self.lanes.values()):
-            return True
+        full or the oldest pending row has waited the collect deadline.
+        The first True since the last dispatch stamps ``due_at``: the
+        deadline's own moment, or now for a full lane (the scoring pass
+        that filled it asks at once)."""
         first = self.first_pending_ts
-        return (
-            first is not None
-            and (time.monotonic() - first) * 1000.0 >= mb.deadline_ms
-        )
+        full = any(l.count >= mb.max_batch for l in self.lanes.values())
+        if first is None and not full:
+            self.due_at = None
+            return False
+        now = time.perf_counter()
+        at = now if first is None else first + mb.deadline_ms / 1000.0
+        if full:
+            at = min(at, now)
+        elif at > now:
+            self.due_at = None
+            return False
+        if self.due_at is None:
+            self.due_at = at
+        return True
+
+    def idle_split(self, t_asked: float) -> Tuple[float, float]:
+        """(unfed, turn): the host's side of the device's idle before a
+        serve flush that asked for its permit at ``t_asked`` — unfed from
+        the previous serve landing to the moment a flush first became
+        due (no work to give the chip), turn from the later of the two
+        to ``t_asked`` (the loop's turn). Both 0 while a serve flush of
+        this slice is still in flight, and before its first landing."""
+        landed = self.landed_at
+        for p in self.reap:
+            if p.lane != "serve" or p.poisoned:
+                continue
+            if not p.resolved and not p.landed():
+                return 0.0, 0.0
+            if p.t_landed is not None and p.t_landed > landed:
+                landed = p.t_landed
+        if not landed:
+            return 0.0, 0.0
+        due = t_asked if self.due_at is None else self.due_at
+        return max(0.0, due - landed), max(0.0, t_asked - max(landed, due))
 
     def in_flight(self) -> List[_PendingFlush]:
         """This slice's entries that are dispatched and have not landed:

@@ -44,10 +44,21 @@ method, so the task calls straight into it) and ``observe_from`` is
 never reached; what stays is one wrapper a task.
 
 The steps are timed on ``time.perf_counter()``, the clock the flush
-record is on, and both are mirrored into the profiler's trace by
-``sw(<stage>)`` annotations at the stage entries, so one traced run puts
-the ledger, the flush record and the device's operations on one clock.
-Outside a capture an annotation is a flag test.
+record is on. While a ``jax.profiler`` capture runs, the ledger puts the
+same steps on the profiler's clock, on the loop thread's line: each step
+under ``sw/loop/<stage>`` (``intake|score|egress|other``; argument
+``task``, the counter's label) and the selector's wait under
+``sw/loop/asleep``; each ``observe_from`` stretch runs under
+``sw("observe")`` at its caller. The switch is the duty cycle's own
+tick: it tests the profiler's flag and swaps annotating closures into
+the wrappers' slots and a wrapper into the selector's ``select``, and
+swaps the plain ones back when the capture ends — so a step outside a
+capture runs exactly as it does without the ledger. The program's other
+spans (``sw(...)``) are synchronous and open and close inside one step,
+so on the loop thread's line every one of them nests inside a
+``sw/loop/*`` step; ``spanned`` refuses a coroutine function, whose
+awaits would hold a span open across other tasks' steps and claim their
+time.
 """
 
 from __future__ import annotations
@@ -58,6 +69,7 @@ import contextlib
 import functools
 import gc
 import inspect
+import sys
 import time
 from threading import get_ident
 from time import perf_counter
@@ -91,9 +103,12 @@ _TraceAnnotation = None  # jax.profiler.TraceAnnotation, at first use
 
 def _capturing() -> bool:
     """Is a profiler capture running? ``TraceMe``'s own flag: at the
-    first call this name is rebound to ``TraceAnnotation.is_enabled``
-    (not at import: jax-free consumers import this module)."""
+    first call after JAX is imported this name is rebound to
+    ``TraceAnnotation.is_enabled`` (never imported here: jax-free
+    consumers import this module, and without JAX no capture runs)."""
     global _TraceAnnotation, _capturing
+    if "jax" not in sys.modules:
+        return False
     from jax.profiler import TraceAnnotation as _TraceAnnotation
 
     _capturing = _TraceAnnotation.is_enabled
@@ -103,6 +118,10 @@ def _capturing() -> bool:
 # what ``sw`` hands back while no capture is running
 _NO_SPAN = contextlib.nullcontext()
 
+# the step span of a task with no stage, and the selector's wait
+OTHER_SPAN = "sw/loop/other"
+ASLEEP_SPAN = "sw/loop/asleep"
+
 
 def sw(stage: str, **args: Any):
     """``jax.profiler.TraceAnnotation("sw/<stage>")`` — the program's own
@@ -110,37 +129,30 @@ def sw(stage: str, **args: Any):
     running, whoever started it, the span is in it. The flag a
     ``TraceMe`` tests is tested here first, so that outside a capture no
     annotation object is built (three calls into the extension a span,
-    on a loop that runs thousands a second)."""
+    on a loop that runs thousands a second). Only for a synchronous
+    stretch: opened and closed inside one step of the loop thread."""
     if not _capturing():
         return _NO_SPAN
     return _TraceAnnotation("sw/" + stage, **args)
 
 
-async def _under(stage: str, coro):
-    with _TraceAnnotation("sw/" + stage):
-        return await coro
-
-
 def spanned(stage: str):
-    """Decorator: the whole call — a coroutine function's awaits
-    included — runs under ``sw(stage)``: a handler's entry into its
-    stage, on the profiler's clock. Outside a capture a coroutine
-    function's call returns the function's own coroutine: no frame is
-    added to the handler's every step."""
+    """Decorator: each call of a synchronous function runs under
+    ``sw(stage)``. A coroutine function is refused: a span held across
+    its awaits stands open on the loop thread's line while other tasks'
+    steps run, and claims their time; its task's ``sw/loop/<stage>``
+    steps already say when it runs."""
     def deco(fn):
         if inspect.iscoroutinefunction(fn):
-            @functools.wraps(fn)
-            def wrapped(*a, **kw):
-                if not _capturing():
-                    return fn(*a, **kw)
-                return _under(stage, fn(*a, **kw))
+            raise TypeError(
+                f"spanned({stage!r}) on coroutine function "
+                f"{fn.__qualname__}: a span must not stand across an await"
+            )
 
-            inspect.markcoroutinefunction(wrapped)
-        else:
-            @functools.wraps(fn)
-            def wrapped(*a, **kw):
-                with sw(stage):
-                    return fn(*a, **kw)
+        @functools.wraps(fn)
+        def wrapped(*a, **kw):
+            with sw(stage):
+                return fn(*a, **kw)
         return wrapped
 
     return deco
@@ -185,27 +197,55 @@ def _timed(call, ledger: "LoopLedger", who: list):
     return step
 
 
+def _annotated(call, ledger: "LoopLedger", who: list):
+    """``call`` — a coroutine's ``send`` or ``throw``, or its timed
+    closure — as a step under ``sw/loop/<stage>`` of the counter it is
+    charged to: the step on the profiler's clock."""
+
+    def step(*args):
+        counter = who[0] or ledger.counter_of(asyncio.current_task())
+        with _TraceAnnotation(ledger.span_of.get(counter, OTHER_SPAN)):
+            return call(*args)
+
+    return step
+
+
+def _asleep(select):
+    """The selector's ``select`` under ``sw/loop/asleep``: the loop
+    thread waiting for I/O or its next timer."""
+
+    def wrapped(timeout=None):
+        with _TraceAnnotation(ASLEEP_SPAN):
+            return select(timeout)
+
+    return wrapped
+
+
 class _TimedCoro:
     """A task's coroutine, as the task sees it. ``send`` and ``throw``
-    are slots: outside a sampling slice they hold the wrapped
-    coroutine's own bound methods (``_plain``), so a step costs what it
-    cost without the ledger; inside one they hold ``_timed`` closures,
-    made at the wrapper's first slice and kept — a slice's two edges
-    swap what is there and allocate nothing (objects born at every edge
-    would each count towards the collector's next full collection).
-    ``_who`` is ``[counter the steps are charged to, the task that
-    created this one]``, each None until / once the counter is first
-    asked for."""
+    are slots: outside a sampling slice and a capture they hold the
+    wrapped coroutine's own bound methods (``_plain``), so a step costs
+    what it cost without the ledger; inside a slice they hold ``_timed``
+    closures, during a capture ``_spanned`` ones, during both ``_both``
+    (annotating the timed) — each pair made at its first need and kept:
+    an edge swaps what is there and allocates nothing (objects born at
+    every edge would each count towards the collector's next full
+    collection). ``_who`` is ``[counter the steps are charged to, the
+    task that created this one]``, each None until / once the counter is
+    first asked for."""
 
-    __slots__ = ("_coro", "send", "throw", "_who", "_plain", "_timed")
+    __slots__ = (
+        "_coro", "send", "throw", "_who", "_plain", "_timed", "_spanned",
+        "_both",
+    )
 
     def __init__(self, coro, ledger: "LoopLedger", creator) -> None:
         self._coro = coro
         self._who = [None, creator]
         self._plain = (coro.send, coro.throw)
-        self._timed = None
-        if ledger.sampling:
-            ledger.time(self)
+        self._timed = self._spanned = self._both = None
+        if ledger.sampling or ledger.annotating:
+            ledger.dress(self)
         else:
             self.send, self.throw = self._plain
 
@@ -250,7 +290,9 @@ class LoopLedger:
     # the duty cycle: steps are timed during one slice of ``SLICE_S``
     # seconds in every ``CYCLE`` and each slice's seconds are scaled by
     # the cycle's wall time over its own. ``CYCLE = 1`` times every step
-    # (tests; a diagnosis that wants every step)
+    # (tests; a diagnosis that wants every step). The cycle's tick also
+    # looks for a profiler capture: one is seen up to
+    # ``SLICE_S × (CYCLE − 1)`` late
     SLICE_S = 0.1
     CYCLE = 8
 
@@ -260,6 +302,8 @@ class LoopLedger:
         self.observed = None      # the ``observe`` stage's counter
         self.unlabeled = None     # counter of the unlabeled tasks' steps
         self.sampling = False    # inside a sampling slice
+        self.annotating = False  # a profiler capture seen by the tick
+        self.span_of: dict = {}  # counter → its steps' span name
         self.current = None      # counter of the timed step that is running
         self.moved = 0.0         # seconds ``observe`` took out of that step
         self.paused = 0.0        # seconds the collector has stopped the thread
@@ -273,6 +317,8 @@ class LoopLedger:
         self._cpu_clock = None   # the loop thread's CPU clock id
         self._cpu0 = 0.0
         self._rest_frozen = 0.0  # ``other`` as it stood at uninstall
+        self._select = None      # the annotating wrapper of the selector's
+        #                          ``select``, while it stands there
 
     def rest_seconds(self) -> float:
         if self._loop is None:
@@ -322,15 +368,29 @@ class LoopLedger:
         self.observed._v += dt
         self.moved += dt
 
-    # -- the duty cycle ------------------------------------------------------
-    def time(self, wrapper: _TimedCoro) -> None:
-        if wrapper._timed is None:
-            send, throw = wrapper._plain
-            wrapper._timed = (
-                _timed(send, self, wrapper._who),
-                _timed(throw, self, wrapper._who),
-            )
-        wrapper.send, wrapper.throw = wrapper._timed
+    # -- the duty cycle and the capture ----------------------------------------
+    def dress(self, wrapper: _TimedCoro) -> None:
+        """Put in a wrapper's ``send`` / ``throw`` slots the pair the
+        ledger's state asks for, making it at its first need."""
+        if self.sampling:
+            if wrapper._timed is None:
+                wrapper._timed = tuple(
+                    _timed(c, self, wrapper._who) for c in wrapper._plain)
+            pair = wrapper._timed
+            if self.annotating:
+                if wrapper._both is None:
+                    wrapper._both = tuple(
+                        _annotated(c, self, wrapper._who)
+                        for c in wrapper._timed)
+                pair = wrapper._both
+        elif self.annotating:
+            if wrapper._spanned is None:
+                wrapper._spanned = tuple(
+                    _annotated(c, self, wrapper._who) for c in wrapper._plain)
+            pair = wrapper._spanned
+        else:
+            pair = wrapper._plain
+        wrapper.send, wrapper.throw = pair
 
     def _wrappers(self):
         for task in asyncio.all_tasks(self._loop):
@@ -346,7 +406,7 @@ class LoopLedger:
         self._slice_t0 = perf_counter()
         self._marks = [c._v for c in self._counters()]
         for wrapper in self._wrappers():
-            self.time(wrapper)
+            self.dress(wrapper)
 
     def _close_slice(self) -> None:
         """The slice stands for its whole cycle: what each counter
@@ -355,7 +415,7 @@ class LoopLedger:
         now = perf_counter()
         self.sampling = False
         for wrapper in self._wrappers():
-            wrapper.send, wrapper.throw = wrapper._plain
+            self.dress(wrapper)
         took = now - self._slice_t0
         if took > 0.0:
             scale = (now - self._cycle_t0) / took
@@ -363,11 +423,31 @@ class LoopLedger:
                 counter._v += (counter._v - mark) * (scale - 1.0)
         self._cycle_t0 = now
 
+    def _annotate(self, on: bool) -> None:
+        """A capture began (``on``) or ended: every wrapper's steps and
+        the selector's wait go on the profiler's clock, or come off it."""
+        self.annotating = on
+        for wrapper in self._wrappers():
+            self.dress(wrapper)
+        sel = getattr(self._loop, "_selector", None)
+        if sel is None or not hasattr(sel, "__dict__"):
+            return  # a loop without a selector of its own: no sleep span
+        if on:
+            self._select = sel.select = _asleep(sel.select)
+        elif self._select is not None:
+            if vars(sel).get("select") is self._select:
+                del sel.select  # back to the selector's own method
+            self._select = None
+
     def _tick(self) -> None:
         # a plain callback: no task is inside a step while it runs
         if self._loop is None:
             return
-        if self.sampling:
+        if _capturing() is not self.annotating:
+            self._annotate(not self.annotating)
+        if self.CYCLE == 1:
+            wait = self.SLICE_S  # every step is timed: the slice stays open
+        elif self.sampling:
             self._close_slice()
             wait = self.SLICE_S * (self.CYCLE - 1)
         else:
@@ -395,10 +475,11 @@ class LoopLedger:
             "part of loop_busy_seconds_total{stage=other} that tasks own",
         )
         for prefix, stage in STAGE_OF_TASK:
-            self.by_task[prefix] = m.counter(
-                "loop_busy_seconds_total", stage=stage,
-                task=prefix.rstrip("["),
+            task = prefix.rstrip("[")
+            counter = self.by_task[prefix] = m.counter(
+                "loop_busy_seconds_total", stage=stage, task=task,
             )
+            self.span_of[counter] = f"sw/loop/{stage}#task={task}#"
         self.observed = m.counter(
             "loop_busy_seconds_total", stage="observe", task=""
         )
@@ -419,8 +500,9 @@ class LoopLedger:
         # one-slice cycle never closes it
         self._cycle_t0 = perf_counter()
         self._open_slice()
-        if self.CYCLE > 1:
-            self._timer = loop.call_later(self.SLICE_S, self._tick)
+        if _capturing():
+            self._annotate(True)
+        self._timer = loop.call_later(self.SLICE_S, self._tick)
 
     def uninstall(self) -> None:
         if self._loop is None:
@@ -428,6 +510,8 @@ class LoopLedger:
         if self._timer is not None:
             self._timer.cancel()
             self._timer = None
+        if self.annotating:
+            self._annotate(False)
         if self.sampling:
             self._close_slice()
         self._rest_frozen = self.rest_seconds()

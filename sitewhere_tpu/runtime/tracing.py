@@ -38,6 +38,7 @@ from typing import Any, Dict, List, Optional
 
 from sitewhere_tpu.core.trace import TraceContext, new_span_id, trace_ctx_of
 from sitewhere_tpu.runtime.config import TracingConfig
+from sitewhere_tpu.runtime.loopledger import sw
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 
 # the terminal pipeline stage: its span seals the trace and schedules the
@@ -514,42 +515,46 @@ class StageTimer:
         # called it (nothing is read while no step is being timed)
         ledger = self._ledger
         t_observe = ledger.clock() if ledger.current is not None else 0.0
-        if queue_wait_ms is None:
-            queue_wait_ms = queue_wait_from(item, start_ms)
-        self.service_h.record(max(0.0, end_ms - start_ms) / 1000.0)
-        self.wait_h.record(max(0.0, queue_wait_ms) / 1000.0)
-        self.events_c.inc(n_events)
-        if self.tracer is not None:
-            ctx = trace_ctx_of(item)
-            self.tracer.record_span(
-                ctx, self.stage, start_ms, end_ms,
-                queue_wait_ms=queue_wait_ms, n_events=n_events, error=error,
-                advance=self.stage not in FORK_STAGES,
-                **annotations,
-            )
-            fr = self.tracer.flightrec
-            if fr is not None:
-                self._fr_tick += 1
-                # tail-blindness guard: the stride may skip the exact
-                # batch that breached/retried — any span whose trace the
-                # tail sampler will retain records unconditionally, so
-                # the incident snapshot holds the slow event's own
-                # timings (forced records do not reset the stride; the
-                # steady cadence stays intact around an incident)
-                hot = bool(error) or self.tracer.trace_is_hot(ctx)
-                if hot or self._fr_tick >= self.FLIGHTREC_STRIDE:
-                    if self._fr_tick >= self.FLIGHTREC_STRIDE:
-                        self._fr_tick = 0
-                    rec = fr.record(
-                        "stage", f"{self.tenant}/{self.stage}",
-                        service_ms=round(max(0.0, end_ms - start_ms), 3),
-                        queue_wait_ms=round(max(0.0, queue_wait_ms), 3),
-                        n_events=n_events,
-                    )
-                    if error:
-                        rec["error"] = error
-                    if hot and not error:
-                        rec["forced"] = "tail"
+        # while a profiler capture runs the same stretch is ``sw/observe``
+        # on the loop thread's line
+        with sw("observe"):
+            if queue_wait_ms is None:
+                queue_wait_ms = queue_wait_from(item, start_ms)
+            self.service_h.record(max(0.0, end_ms - start_ms) / 1000.0)
+            self.wait_h.record(max(0.0, queue_wait_ms) / 1000.0)
+            self.events_c.inc(n_events)
+            if self.tracer is not None:
+                ctx = trace_ctx_of(item)
+                self.tracer.record_span(
+                    ctx, self.stage, start_ms, end_ms,
+                    queue_wait_ms=queue_wait_ms, n_events=n_events,
+                    error=error,
+                    advance=self.stage not in FORK_STAGES,
+                    **annotations,
+                )
+                fr = self.tracer.flightrec
+                if fr is not None:
+                    self._fr_tick += 1
+                    # tail-blindness guard: the stride may skip the exact
+                    # batch that breached/retried — any span whose trace
+                    # the tail sampler will retain records unconditionally,
+                    # so the incident snapshot holds the slow event's own
+                    # timings (forced records do not reset the stride; the
+                    # steady cadence stays intact around an incident)
+                    hot = bool(error) or self.tracer.trace_is_hot(ctx)
+                    if hot or self._fr_tick >= self.FLIGHTREC_STRIDE:
+                        if self._fr_tick >= self.FLIGHTREC_STRIDE:
+                            self._fr_tick = 0
+                        rec = fr.record(
+                            "stage", f"{self.tenant}/{self.stage}",
+                            service_ms=round(max(0.0, end_ms - start_ms), 3),
+                            queue_wait_ms=round(max(0.0, queue_wait_ms), 3),
+                            n_events=n_events,
+                        )
+                        if error:
+                            rec["error"] = error
+                        if hot and not error:
+                            rec["forced"] = "tail"
         if t_observe:
             ledger.observe_from(t_observe)
 

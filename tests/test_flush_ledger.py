@@ -12,7 +12,11 @@ to the loop thread's CPU seconds, an awaiting handler is not charged for
 its busy neighbour, and ``observe`` is moved out of the stage it ran
 under; (e) ``runtime_gc_*`` count a forced full collection; (f) with
 ``tracing.enabled=False`` no ``Span`` is allocated and the flush
-histograms still record.
+histograms still record; (g) every serve flush records the host's side
+of the device's idle before it once — ``idle_unfed`` (no work due) and
+``idle_turn`` (the loop's turn after it fell due), both 0 behind a flush
+in flight — and a lane that waits out its collect deadline shows in the
+first, not in the second.
 """
 
 import asyncio
@@ -48,14 +52,14 @@ FLUSH_STAGES = ("lane_wait", "permit_wait", "flush_assembly", "h2d_stage",
                 "dispatch", "inflight", "resolve")
 
 
-async def _instance(tracing_cfg=None):
+async def _instance(tracing_cfg=None, mb=MB):
     inst = SiteWhereInstance(InstanceConfig(
         instance_id="fl",
         mesh=MeshConfig(tenant_axis=1, data_axis=1, slots_per_shard=4),
     ))
     await inst.start()
     await inst.tenant_management.create_tenant(
-        "acme", template="iot-temperature", microbatch=MB,
+        "acme", template="iot-temperature", microbatch=mb,
         model_config={"hidden": 8}, max_streams=64,
         tracing=tracing_cfg or TracingConfig(sample_rate=1.0,
                                              slo_ms=60_000.0),
@@ -538,3 +542,123 @@ async def test_tracing_disabled_allocates_no_span_and_flushes_still_record(
         assert len(inst.inference.flush_records) == flushes
     finally:
         await inst.terminate()
+
+
+# --------------------------- (g) the host's gap between landing and ask
+MB_SLOW = MicroBatchConfig(max_batch=64, deadline_ms=40.0, buckets=(32, 64),
+                           window=8)
+
+
+def _split(rec) -> tuple:
+    return rec["idle_unfed_s"], rec["idle_turn_s"]
+
+
+async def test_idle_split_is_the_loops_turn_when_held_and_unfed_on_a_deadline():
+    inst, toks = await _instance(mb=MB_SLOW)
+    svc = inst.inference
+    m = svc.metrics
+    gates: list = []
+    scorer = svc.scorers["lstm_ad"]
+    orig = scorer.step_counts
+
+    def gated_step(i, v, c):
+        gate = threading.Event()
+        gates.append(gate)
+        return _GatedScores(orig(i, v, c), gate)
+
+    scorer.step_counts = gated_step
+    scored = m.counter("tpu_inference.scored_total")
+    held = m.counter("tpu_inference.flush_held")
+    try:
+        # flush 1: nothing has landed before it
+        await _publish(inst, _batch(inst, toks, 8, 0.0))
+        assert await _wait_for(lambda: len(gates) == 1)
+        # flush 2's rows fall due while flush 1 is in flight: held
+        await _publish(inst, _batch(inst, toks, 8, 100.0))
+        assert await _wait_for(lambda: held.value > 0)
+        await asyncio.sleep(0.1)           # well past their 40 ms deadline
+        gates[0].set()
+        assert await _wait_for(lambda: len(gates) == 2)
+        gates[1].set()
+        assert await _wait_for(lambda: scored.value >= 16)
+        # an idle slice, then a lane that waits out its collect deadline
+        await asyncio.sleep(0.1)
+        await _publish(inst, _batch(inst, toks, 8, 200.0))
+        assert await _wait_for(lambda: len(gates) == 3)
+        gates[2].set()
+        assert await _wait_for(lambda: scored.value >= 24)
+        recs = sorted(svc.flush_records.values(),
+                      key=lambda r: r["flush_id"])
+    finally:
+        for g in gates:
+            g.set()
+        await inst.terminate()
+    assert len(recs) == 3 == m.counter("tpu_inference.flushes").value
+    # once a serve flush, never negative
+    for name in ("idle_unfed", "idle_turn"):
+        assert m.histogram(f"tpu_inference.{name}").count == 3
+    assert all(min(_split(r)) >= 0.0 for r in recs)
+    assert _split(recs[0]) == (0.0, 0.0)
+    # flush 2 was due long before flush 1 landed: its idle is all the
+    # loop's turn, from that landing to the permit asked
+    unfed, turn = _split(recs[1])
+    assert unfed == 0.0
+    assert recs[1]["t_asked"] - recs[0]["t_landed"] - 1e-6 <= turn
+    assert turn <= recs[1]["t_asked"] - recs[0]["t_dispatched"]
+    # flush 3: the idle slice and the 40 ms collect deadline are unfed;
+    # the turn runs from the deadline's own moment to the ask
+    unfed, turn = _split(recs[2])
+    due = recs[2]["t_oldest"] + MB_SLOW.deadline_ms / 1000.0
+    assert unfed >= due - recs[1]["t_landed"] - 1e-6
+    assert unfed >= 0.1 + MB_SLOW.deadline_ms / 1000.0 - 0.01
+    assert turn <= recs[2]["t_asked"] - due + 1e-6
+    assert turn >= recs[2]["t_asked"] - due - 0.005
+
+
+async def test_idle_split_is_zero_behind_a_flush_in_flight():
+    inst, toks = await _instance()
+    svc = inst.inference
+    m = svc.metrics
+    s = svc._slices[("lstm_ad", 0)]
+    gates: list = []
+    scorer = svc.scorers["lstm_ad"]
+    orig = scorer.step_counts
+
+    def gated_step(i, v, c):
+        gate = threading.Event()
+        gates.append(gate)
+        return _GatedScores(orig(i, v, c), gate)
+
+    scorer.step_counts = gated_step
+    scored = m.counter("tpu_inference.scored_total")
+    try:
+        # flush 0 lands and resolves: the slice has a landing behind it
+        await _publish(inst, _batch(inst, toks, 8, 0.0))
+        assert await _wait_for(lambda: len(gates) == 1)
+        gates[0].set()
+        assert await _wait_for(lambda: scored.value >= 8)
+        # flush 1 in flight; flush 2 fills the smallest bucket and joins
+        # the busy device (the flush policy's pipelining exit)
+        await _publish(inst, _batch(inst, toks, 8, 100.0))
+        assert await _wait_for(lambda: len(s.reap) == 1)
+        await _publish(inst, _batch(inst, toks, MB.buckets[0], 200.0))
+        assert await _wait_for(lambda: len(s.reap) == 2)
+        for g in gates:
+            g.set()
+        assert await _wait_for(lambda: scored.value >= 16 + MB.buckets[0])
+        recs = sorted(svc.flush_records.values(),
+                      key=lambda r: r["flush_id"])
+    finally:
+        for g in gates:
+            g.set()
+        await inst.terminate()
+    assert len(recs) == 3
+    assert m.histogram("tpu_inference.idle_turn").count == 3
+    assert m.histogram("tpu_inference.idle_unfed").count == 3
+    # flush 1 followed flush 0's landing on an idle device ...
+    idle = sum(_split(recs[1]))
+    assert recs[1]["t_asked"] - recs[0]["t_landed"] - 1e-6 <= idle
+    assert idle <= recs[1]["t_asked"] - recs[0]["t_dispatched"]
+    # ... flush 2 asked while flush 1 was still in flight: nothing idle
+    assert recs[2]["t_asked"] < recs[1]["t_landed"]
+    assert _split(recs[2]) == (0.0, 0.0)
