@@ -27,7 +27,8 @@ convolution inputs, per ``*`` layer a ring of keys and values, the
 position, and ``y``.
 
 Two programs share ``advance``: the ONE-STEP program (one token a row,
-rows are distinct streams) and the CHUNKED program (a run of up to
+rows are distinct streams; the mixers' state read and written in the
+store for the live rows only) and the CHUNKED program (a run of up to
 ``chunk_size`` tokens a row, the SSD form), which give the same values.
 """
 
@@ -332,19 +333,27 @@ def advance(
         u = _rmsnorm(x, lp["norm"], eps)
         if kind == "M":
             with jax.named_scope("sw/ssm"):
-                ssm_s = ls["ssm"][at]
-                conv = ls["conv"][at].reshape(
-                    n, cfg.conv_kernel - 1, cfg.conv_dim)
                 if one_step:
-                    out, conv, ssm_s = ssd.step(
-                        u[:, 0], lp, conv, ssm_s, cfg.mamba_dims, eps)
+                    # the state-space state (2 MB a stream) of the live
+                    # rows only, in place: none for padding
+                    conv = ls["conv"][at].reshape(
+                        n, cfg.conv_kernel - 1, cfg.conv_dim)
+                    out, conv, ssm = ssd.step_in_store(
+                        u[:, 0], lp, conv, ls["ssm"], at, live,
+                        cfg.mamba_dims, eps)
                     out = out[:, None]
+                    ls = {"conv": ls["conv"].at[to].set(
+                              conv.reshape(n, -1), mode="drop"),
+                          "ssm": ssm}
                 else:
+                    ssm_s = ls["ssm"][at]
+                    conv = ls["conv"][at].reshape(
+                        n, cfg.conv_kernel - 1, cfg.conv_dim)
                     out, conv, ssm_s = ssd.chunk(
                         u, lp, conv, ssm_s, lens, cfg.mamba_dims, eps)
-                ls = {"conv": ls["conv"].at[to].set(
-                          conv.reshape(n, -1), mode="drop"),
-                      "ssm": ls["ssm"].at[to].set(ssm_s, mode="drop")}
+                    ls = {"conv": ls["conv"].at[to].set(
+                              conv.reshape(n, -1), mode="drop"),
+                          "ssm": ls["ssm"].at[to].set(ssm_s, mode="drop")}
         elif kind == "E":
             with jax.named_scope("sw/moe"):
                 flat = u.reshape(n * length, cfg.hidden)

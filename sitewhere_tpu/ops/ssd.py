@@ -11,11 +11,15 @@ Published equations (Mamba-2, Dao & Gu 2024; the Nemotron-H block):
     y_h = S_h C_g + Dskip_h x_h
     y <- RMSNorm_per-group(y * silu(z)) * w ;  out = W_out y
 
-``step`` applies them to one token a row. ``chunk`` applies them to a
-run of up to L tokens a row in the chunked form — inside the run the
-decays are a lower-triangular matrix, the state before the run enters
-through ``exp(cumsum) C . S0`` and the state after it is accumulated in
-one product — which gives the same values as L single steps.
+``step`` applies them to one token a row, the rows' states in hand;
+``step_in_store`` does the same with each live row's state read from and
+written back into the store that holds every stream's — the one-step
+program's form, which moves no state for a padding row. ``chunk``
+applies them to a run of up to L tokens a row in the chunked form —
+inside the run the decays are a lower-triangular matrix, the state
+before the run enters through ``exp(cumsum) C . S0`` and the state after
+it is accumulated in one product — which gives the same values as L
+single steps.
 
 Precision: the two projections are bf16 products with f32 accumulation;
 the step size, the decay, the convolution, the state update and every
@@ -77,6 +81,29 @@ def _gate_norm_out(y, z, p, groups: int, eps: float):
     )
 
 
+def _step_inputs(u, p, conv, dims):
+    """What one token a row brings to the state, batched over the rows:
+    (z, conv', x [N, heads, P], b, c [N, heads, state], delta [N, heads])."""
+    z, xbc, dt = _project_in(u, p, dims)
+    hist = jnp.concatenate([conv, xbc[:, None, :]], axis=1)
+    xbc = jax.nn.silu(jnp.sum(hist * p["conv_w"][None], axis=1) + p["conv_b"])
+    x, b, c = _split_xbc(xbc, dims)
+    delta = jax.nn.softplus(dt + p["dt_bias"])               # [N, heads]
+    return z, hist[:, 1:], x, b, c, delta
+
+
+def _recur(ssm, x, b, c, delta, p):
+    """The state update and its read-out for rows ``[n, heads, ...]``:
+    (y [n, heads, P], ssm')."""
+    decay = jnp.exp(-delta * jnp.exp(p["a_log"]))
+    ssm = (
+        decay[:, :, None, None] * ssm
+        + (delta[:, :, None] * x)[..., None] * b[:, :, None, :]
+    )
+    y = jnp.sum(ssm * c[:, :, None, :], axis=-1) + p["d"][None, :, None] * x
+    return y, ssm
+
+
 def step(
     u: jnp.ndarray,        # [N, H] normed hidden, one token a row
     p: dict,
@@ -85,21 +112,52 @@ def step(
     dims: Tuple[int, int, int, int],
     eps: float,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One token a row: (out f32[N, H], conv', ssm')."""
+    """One token a row, every row's state in hand: (out f32[N, H], conv',
+    ssm'). The plain batched form that ``step_in_store`` is held to."""
     heads, head_dim, groups, _ = dims
-    z, xbc, dt = _project_in(u, p, dims)
-    hist = jnp.concatenate([conv, xbc[:, None, :]], axis=1)
-    xbc = jax.nn.silu(jnp.sum(hist * p["conv_w"][None], axis=1) + p["conv_b"])
-    x, b, c = _split_xbc(xbc, dims)
-    delta = jax.nn.softplus(dt + p["dt_bias"])               # [N, heads]
-    decay = jnp.exp(-delta * jnp.exp(p["a_log"]))
-    ssm = (
-        decay[:, :, None, None] * ssm
-        + (delta[:, :, None] * x)[..., None] * b[:, :, None, :]
-    )
-    y = jnp.sum(ssm * c[:, :, None, :], axis=-1) + p["d"][None, :, None] * x
+    z, conv, x, b, c, delta = _step_inputs(u, p, conv, dims)
+    y, ssm = _recur(ssm, x, b, c, delta, p)
     y = y.reshape(y.shape[0], heads * head_dim)
-    return _gate_norm_out(y, z, p, groups, eps), hist[:, 1:], ssm
+    return _gate_norm_out(y, z, p, groups, eps), conv, ssm
+
+
+def step_in_store(
+    u: jnp.ndarray,        # [N, H] normed hidden, one token a row
+    p: dict,
+    conv: jnp.ndarray,     # f32[N, taps-1, conv_dim]
+    store: jnp.ndarray,    # f32[S, heads, head_dim, state], every stream's
+    rows: jnp.ndarray,     # i32[N] each row's stream in ``store``
+    live: jnp.ndarray,     # bool[N] rows that carry a token
+    dims: Tuple[int, int, int, int],
+    eps: float,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``step`` with the state read from and written back into the whole
+    store, one live row a turn: (out f32[N, H], conv', store'). Nothing
+    of the state is moved for a padding row — its ``out`` is 0 — and the
+    store is updated in place where the caller donates it; the rows'
+    inputs (KB a row) are computed batched, as in ``step``."""
+    heads, head_dim, groups, _ = dims
+    z, conv, x, b, c, delta = _step_inputs(u, p, conv, dims)
+    # the live rows first, turn k takes the k-th (a sort: a compaction by
+    # ``flatnonzero`` is a scatter)
+    order = jnp.argsort(~live, stable=True)
+    one = (1,) + store.shape[1:]
+
+    def turn(k, carry):
+        store, y = carry
+        i = order[k]
+        at = (rows[i], 0, 0, 0)
+        y_i, s = _recur(
+            jax.lax.dynamic_slice(store, at, one),
+            *(jax.lax.dynamic_slice_in_dim(v, i, 1) for v in (x, b, c, delta)),
+            p)
+        return (jax.lax.dynamic_update_slice(store, s, at),
+                jax.lax.dynamic_update_slice_in_dim(y, y_i, i, 0))
+
+    store, y = jax.lax.fori_loop(
+        0, live.sum(), turn, (store, jnp.zeros_like(x)))
+    y = y.reshape(y.shape[0], heads * head_dim)
+    return _gate_norm_out(y, z, p, groups, eps), conv, store
 
 
 def chunk(

@@ -62,7 +62,8 @@ SHORT_RUN = 8
 # call of the flush), the rest from the host's plan
 DEVICE_STATS = ("pairs_routed", "pairs_held", "experts_hit")
 HOST_STATS = ("rows_one_step", "rows_chunked", "rows_same_stream",
-              "calls_one_step", "calls_chunked", "streams_advanced")
+              "calls_one_step", "calls_chunked", "streams_advanced",
+              "rows_padding")
 # a paged family's counters, from its ``PagePool`` (the last a level)
 PAGE_STATS = ("kv_pages_granted", "kv_pages_freed", "kv_pages_recycled",
               "positions_swa", "positions_full", "state_read_bytes",
@@ -192,6 +193,7 @@ def plan(
                 call.cols[:k, 0] = base + take
                 calls.append(call)
                 stats["rows_one_step"] += k
+                stats["rows_padding"] += r - k
                 stats["streams_advanced"] += k
                 stats["calls_one_step"] += 1
                 a += k

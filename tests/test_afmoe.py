@@ -449,8 +449,10 @@ def test_published_widths_weigh_what_the_configuration_says():
 # ------------------------------------------ the other family, unchanged
 # sha256 of the jaxprs of Nemotron-H's two programs at the tiny size of
 # tests/test_nemotron_h.py, as the commit before the page pool made them
+# (the one-step program's since its mixers read and write the live rows'
+# state in the store, ``ssd.step_in_store``)
 NEMOTRON_JAXPRS = {
-    True: "8730f0d2adfd2888027fb1e48082f15b1e564d07e55e4de86f801c52b6643daf",
+    True: "681d5a5d2db61fed5e0f39364d8afeff99c6485991304bce39d1abe2cbac897c",
     False: "370d30f209f3430131c466452773aabb4169392ea22f653ad1982b2224c2fd98",
 }
 

@@ -444,15 +444,10 @@ async def test_smoke_chips4_phase_tiny_on_virtual_devices():
     assert line["serving_collectives"] == 0 and line["train_all_reduces"] >= 1
 
 
-@pytest.mark.parametrize("one_step, rows", [(True, 32), (False, 8)])
-def test_stream_state_programs_compile_for_v5e_at_published_widths(
-        topo, one_step, rows, monkeypatch):
-    """The stateful family's two programs at the published widths and the
-    provisioned store (shapes only): they fit beside 6.4 GB of weights
-    and 5.5 GB of state, the store is updated in place, the grouped
-    expert product is a kernel, and nothing as large as a layer's expert
-    weights is copied (an ``up`` matrix whose minor dimension is not
-    whole lane tiles was, every step, until it was stored padded)."""
+def _stream_program(topo, one_step, rows, monkeypatch):
+    """The stateful family's program at the published widths and the
+    provisioned store of 512 streams, compiled for the described chip
+    (shapes only): (compiled, the store's bytes)."""
     from sitewhere_tpu.models.common import sketch_edges
     from sitewhere_tpu.ops import moe
     from sitewhere_tpu.parallel.streamstate import StreamPrograms
@@ -474,16 +469,27 @@ def test_stream_state_programs_compile_for_v5e_at_published_widths(
     progs = StreamPrograms(spec, cfg, 1, 512, jnp.float32,
                            sketch_edges(1.0, 64.0, 64))
     length = 1 if one_step else cfg.chunk_size
-
-    def arg(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
-
+    packed = jax.ShapeDtypeStruct((rows, 2 + 2 * length), jnp.int32,
+                                  sharding=one)
     compiled = progs.program(one_step, 0).lower(
-        params, state, arg(rows, 2 + 2 * length)).compile()
-    mem = compiled.memory_analysis()
+        params, state, packed).compile()
     state_bytes = sum(
         int(np.prod(x.shape)) * x.dtype.itemsize
         for x in jax.tree_util.tree_leaves(state))
+    return compiled, state_bytes
+
+
+@pytest.mark.parametrize("one_step, rows", [(True, 32), (False, 8)])
+def test_stream_state_programs_compile_for_v5e_at_published_widths(
+        topo, one_step, rows, monkeypatch):
+    """The stateful family's two programs at the published widths and the
+    provisioned store (shapes only): they fit beside 6.4 GB of weights
+    and 5.5 GB of state, the store is updated in place, the grouped
+    expert product is a kernel, and nothing as large as a layer's expert
+    weights is copied (an ``up`` matrix whose minor dimension is not
+    whole lane tiles was, every step, until it was stored padded)."""
+    compiled, state_bytes = _stream_program(topo, one_step, rows, monkeypatch)
+    mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == state_bytes       # donated, in place
     assert mem.temp_size_in_bytes < 1.2e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -493,6 +499,25 @@ def test_stream_state_programs_compile_for_v5e_at_published_widths(
         "gmm") >= 8                                      # 4 layers x up, down
     assert "ragged-dot" not in text
     assert not re.search(r"bf16\[1,64,\d+,\d+\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("rows", [32, 128])
+def test_one_step_program_moves_the_live_rows_mixer_state_only(
+        topo, rows, monkeypatch):
+    """The one-step program reads and writes a mixer layer's state (2 MB
+    a stream) for its live rows only, in place in the store: no
+    instruction makes the rows' states ``f32[rows,64,64,128]`` (a gather
+    of every row, padding too, then its copy and scatter back), the
+    store is still donated whole, and a loop carries each layer's
+    store."""
+    compiled, state_bytes = _stream_program(topo, True, rows, monkeypatch)
+    assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
+    text = compiled.as_text()
+    assert f"f32[{rows},64,64,128]" not in text
+    loops = [line for line in text.splitlines()
+             if re.search(r"\) while\(", line)
+             and "f32[512,64,64,128]" in line]
+    assert len(loops) == 4                               # one a mixer layer
 
 
 def test_smoke_script_refuses_a_cpu():

@@ -11,8 +11,8 @@ import pytest
 
 from benchmark.builders.nemotron_h_stream import install_weights, model_config
 from benchmark.reference import nemotron_h as ref
-from sitewhere_tpu.models import get_model, make_config
-from sitewhere_tpu.ops import moe
+from sitewhere_tpu.models import get_model, make_config, nemotron_h
+from sitewhere_tpu.ops import moe, ssd
 from sitewhere_tpu.parallel.mesh import MeshManager
 from sitewhere_tpu.parallel.sharded import ShardedScorer
 from sitewhere_tpu.parallel.streamstate import (
@@ -134,7 +134,8 @@ def test_two_events_of_one_stream_in_one_flush_apply_in_order(fresh):
     # three passes of the one-step program: 3 streams, then stream 1 twice
     assert host == {"rows_one_step": 5, "rows_chunked": 0,
                     "rows_same_stream": 3, "calls_one_step": 3,
-                    "calls_chunked": 0, "streams_advanced": 5}
+                    "calls_chunked": 0, "streams_advanced": 5,
+                    "rows_padding": 3 * ONE_STEP_ROWS[0] - 5}
     want = [ref.surprisal(weights, d, s) for s in series]
     assert abs(got[0] - want[0][0]) < TOL and abs(got[2] - want[2][0]) < TOL
     assert np.abs(got[[1, 3, 4]] - want[1][:3]).max() < TOL
@@ -172,6 +173,76 @@ def test_a_second_slot_keeps_its_own_streams():
     got1 = [flush(sc, [(1, v)], slot=1)[0] for v in series[1]]
     assert np.abs(got0 - ref.surprisal(weights, d, series[0])).max() < TOL
     assert np.abs(got1 - ref.surprisal(weights, d, series[1])).max() < TOL
+
+
+# ---------------------------------------- the one-step mixer, in the store
+def _random_state(cfg, cap, seed):
+    """A store in which every stream holds something of its own."""
+    rng = np.random.default_rng(seed)
+
+    def fill(x):
+        if x.dtype == jnp.int32:
+            return jnp.asarray(rng.integers(0, 300, x.shape), jnp.int32)
+        v = (0.5 * rng.standard_normal(x.shape)).astype(np.float32)
+        return jnp.asarray(v.view(np.uint32) if x.dtype == jnp.uint32 else v)
+
+    return jax.tree_util.tree_map(
+        fill, jax.eval_shape(lambda: nemotron_h.init_state(cfg, cap)))
+
+
+def _gathered(u, p, conv, store, rows, live, dims, eps):
+    """The batched form: every row's state gathered (a padding row's
+    from the clamped stream), stepped, scattered back for live rows."""
+    out, conv, ssm = ssd.step(u, p, conv, store[rows], dims, eps)
+    to = jnp.where(live, rows, store.shape[0])
+    return out, conv, store.at[to].set(ssm, mode="drop")
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("live_rows", [
+    [], [0], list(range(7)), list(range(8)),
+    [1, 4, 6],                              # live rows not in front
+], ids=["k0", "k1", "kR-1", "kR", "apart"])
+def test_one_step_call_moves_the_live_rows_state_only(live_rows, monkeypatch):
+    """A one-step call of R = 8 rows with k live: the same scores and the
+    same state for the live streams as the batched form of the same
+    equations, and every other stream's state bit for bit as it was —
+    stream cap - 1 too, which a padding row's clamped gather reads."""
+    cfg = make_config("nemotron_h", model_config(TINY))
+    cap, r = 12, 8
+    params = nemotron_h.init(jax.random.PRNGKey(4), cfg)
+    state = _random_state(cfg, cap, seed=len(live_rows))
+    rng = np.random.default_rng(7)
+    ids = np.full(r, cap, np.int32)                 # padding, as ``plan``
+    streams = rng.permutation(cap - 1)[:len(live_rows)]
+    ids[live_rows] = streams
+    lens = (ids < cap).astype(np.int32)
+    toks = (rng.integers(0, 256, (r, 1)) * lens[:, None]).astype(np.int32)
+
+    def run():
+        return jax.jit(lambda st, i, t, n: nemotron_h.advance(
+            params, cfg, st, i, t, n, one_step=True))(state, ids, toks, lens)
+
+    got, got_scores, got_stats = run()
+    with monkeypatch.context() as m:
+        m.setattr(ssd, "step_in_store", _gathered)
+        want, want_scores, want_stats = run()
+    assert np.abs(np.asarray(got_scores) - np.asarray(want_scores)).max() < TOL
+    assert not np.asarray(got_scores)[lens == 0].any()
+    assert np.array_equal(got_stats, want_stats)
+    others = np.setdiff1d(np.arange(cap), streams)
+    assert cap - 1 in others
+    for g, w, before in zip(*(jax.tree_util.tree_leaves(t)
+                              for t in (got, want, state))):
+        g, w, before = np.asarray(g), np.asarray(w), np.asarray(before)
+        if g.dtype == np.uint32:                     # rings: f32 bits here
+            g, w = g.view(np.float32), w.view(np.float32)
+        assert np.allclose(g[streams], w[streams], rtol=1e-5, atol=TOL)
+        assert np.array_equal(_bits(g)[others], _bits(before)[others])
 
 
 # ------------------------------------------------------- the expert layer
@@ -284,6 +355,30 @@ def test_plan_sends_each_row_through_one_program_in_order(
                     (c.ids == s) & (c.lens > 0))]
         seen = np.concatenate(mine)
         assert seen.tolist() == [i for i, x in enumerate(ids) if x == s]
+
+
+def test_plan_counts_the_one_step_rows_that_carry_no_event():
+    """``rows_padding``: the one-step calls' capacity less their live
+    rows, over a flush of slots that take every kind of call."""
+    flushes = [
+        [3, 1, 2],                                  # 32-row call, 3 live
+        list(range(200)),                           # 128 + 72 of 128
+        [5] * 130 + [6] * 2 + [7],                  # 6, 7; then 6; 5 chunked
+        [],                                         # an empty slot
+    ]
+    b = 512
+    ids = np.zeros((len(flushes), b), np.int32)
+    counts = np.zeros(len(flushes), np.int32)
+    for slot, rows in enumerate(flushes):
+        ids[slot, :len(rows)] = rows
+        counts[slot] = len(rows)
+    calls, stats = plan(ids, ids * 0 + 7, counts, 128, 512)
+    one_step = [c for c in calls if c.one_step]
+    assert stats["rows_padding"] == sum(
+        len(c.ids) - int((c.lens > 0).sum()) for c in one_step)
+    assert stats["rows_padding"] == (32 - 3) + (128 - 72) + (32 - 2) + (32 - 1)
+    assert stats["rows_padding"] + stats["rows_one_step"] == sum(
+        len(c.ids) for c in one_step)
 
 
 # ------------------------------------------------------------- bf16, fp8
