@@ -21,6 +21,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 from sitewhere_tpu.models import (
     afmoe,
     deepar,
+    lfm2_moe,
     lstm_ad,
     nemotron_h,
     transformer,
@@ -47,6 +48,7 @@ __all__ = [
     "vit",
     "nemotron_h",
     "afmoe",
+    "lfm2_moe",
 ]
 
 
@@ -156,6 +158,16 @@ MODEL_REGISTRY: Dict[str, ModelSpec] = {
         page_pool=afmoe.page_pool,
         flops_per_row=afmoe.flops_per_row,
         score_range=afmoe.SCORE_RANGE,
+    ),
+    "lfm2_moe": ModelSpec(
+        name="lfm2_moe",
+        config_cls=lfm2_moe.Lfm2MoeConfig,
+        init=lfm2_moe.init,
+        init_state=lfm2_moe.init_state,
+        advance=lfm2_moe.advance,
+        page_pool=lfm2_moe.page_pool,
+        flops_per_row=lfm2_moe.flops_per_row,
+        score_range=lfm2_moe.SCORE_RANGE,
     ),
     "vit_b16": ModelSpec(
         name="vit_b16",

@@ -188,11 +188,11 @@ def page_pool(cfg: AfmoeConfig) -> PageLayout:
 
 
 # ----------------------------------------------------------------- forward
-def _rmsnorm(x, w, eps):
+def rmsnorm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
 
 
-def _rope(x, pos, theta: float):
+def rope(x, pos, theta: float):
     """Rotate-half RoPE over the last axis of x [N, L, heads, D] at the
     absolute positions ``pos`` i32[N, L] (f32)."""
     d = x.shape[-1]
@@ -218,11 +218,11 @@ def _attention(u, lp, store, where, table, kv_len, lens, t_abs, full: bool,
     q = jnp.dot(ub, lp["wq"], preferred_element_type=jnp.float32)
     k = jnp.dot(ub, lp["wk"], preferred_element_type=jnp.float32)
     v = jnp.dot(ub, lp["wv"], preferred_element_type=jnp.float32)
-    q = _rmsnorm(q.reshape(n, length, cfg.heads, d), lp["q_norm"], cfg.eps)
-    k = _rmsnorm(k.reshape(n, length, g, d), lp["k_norm"], cfg.eps)
+    q = rmsnorm(q.reshape(n, length, cfg.heads, d), lp["q_norm"], cfg.eps)
+    k = rmsnorm(k.reshape(n, length, g, d), lp["k_norm"], cfg.eps)
     if not full:
-        q = _rope(q, t_abs, cfg.rope_theta)
-        k = _rope(k, t_abs, cfg.rope_theta)
+        q = rope(q, t_abs, cfg.rope_theta)
+        k = rope(k, t_abs, cfg.rope_theta)
     kv = jnp.stack([k, v.reshape(n, length, g, d)], axis=3).reshape(
         n, length, 2 * g, d).astype(store.dtype)
     page, off = where
@@ -287,7 +287,7 @@ def advance(
     rings, pools = list(state["window"]), list(state["full"])
     n_w = n_f = 0
     for a, m, lp in zip(cfg.attention, cfg.mlp, params["layers"]):
-        u = _rmsnorm(x, lp["attn_norm"], eps)
+        u = rmsnorm(x, lp["attn_norm"], eps)
         if a == "W":
             with jax.named_scope("sw/swa"):
                 flat = rings[n_w].reshape((-1,) + rings[n_w].shape[2:])
@@ -302,8 +302,8 @@ def advance(
                     u, lp, pools[n_f], (write_f, off), table_f, kv_len_f,
                     lens, t_abs, True, cfg)
                 n_f += 1
-        x = x + _rmsnorm(out, lp["attn_post_norm"], eps)
-        flat = _rmsnorm(x, lp["mlp_norm"], eps).reshape(n * length, -1)
+        x = x + rmsnorm(out, lp["attn_post_norm"], eps)
+        flat = rmsnorm(x, lp["mlp_norm"], eps).reshape(n * length, -1)
         if m == "D":
             with jax.named_scope("sw/dense_mlp"):
                 out = moe.dense_swiglu(flat, lp["w_gu"], lp["w_down"])
@@ -318,9 +318,9 @@ def advance(
                                                lp["s_down"])
                 stats = stats + st
         out = out.reshape(n, length, cfg.hidden)
-        x = x + _rmsnorm(out, lp["mlp_post_norm"], eps)
+        x = x + rmsnorm(out, lp["mlp_post_norm"], eps)
     with jax.named_scope("sw/head"):
-        y = _rmsnorm(x, params["norm_f"], eps)
+        y = rmsnorm(x, params["norm_f"], eps)
         before = jnp.concatenate([y_before[:, None], y[:, :-1]], axis=1)
         logits = jnp.dot(before.astype(dt), params["head"],
                          preferred_element_type=jnp.float32)

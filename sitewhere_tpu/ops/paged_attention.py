@@ -20,6 +20,17 @@ online softmax, one page of every row a pass — what tier-1 holds to the
 plain reference. Neither makes a temporary of rows x table: the loop's
 is rows x one page. Scores and the softmax are f32, the products take
 the store's dtype with f32 accumulation.
+
+Heads of 64 lanes (``attend_paired``). A TPU lays the store's minor axis
+over 128 lanes, so a ``[pages, page, 2 G, 64]`` store would hold as many
+bytes of padding as of keys and values, and the kernel would fetch them.
+The paired store puts kv heads 2 i and 2 i + 1 side by side in one row of
+128 lanes — ``[pages, page, G, 128]``, combined head 2 i the keys of the
+pair, 2 i + 1 its values — and a query of 64 lanes meets it with zeros in
+the other half: its product with the pair's keys is its product with its
+own head's keys, to the bit, and of the 128 lanes of output the half of
+its own head is kept. The kernel computes twice the scores' products a
+position and reads no byte more than the keys and values themselves.
 """
 
 from __future__ import annotations
@@ -134,3 +145,36 @@ def _gather(q, pages, table, kv_len, lens, scale, window):
          jnp.zeros(shape, jnp.float32), jnp.zeros(shape + (d,), jnp.float32)))
     out = acc / jnp.where(l > 0, l, 1.0)[..., None]
     return out.reshape(n, length, hq, d)
+
+
+def paired(k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """Keys and values [..., G, D] of every kv head as a position of the
+    paired store: [..., G, 2 D], row 2 i the keys of heads 2 i and 2 i + 1
+    side by side, row 2 i + 1 their values."""
+    lead, (g, d) = k.shape[:-2], k.shape[-2:]
+    pair = lead + (g // 2, 2 * d)
+    return jnp.stack([k.reshape(pair), v.reshape(pair)],
+                     axis=-2).reshape(lead + (g, 2 * d))
+
+
+def attend_paired(
+    q: jnp.ndarray,        # [N, L, Hq, D] queries of D lanes
+    pages: jnp.ndarray,    # [P, page, G, 2 D] the paired store
+    table: jnp.ndarray,
+    kv_len: jnp.ndarray,
+    lens: jnp.ndarray,
+    scale: float,
+    window: Optional[int] = None,
+) -> jnp.ndarray:
+    """``attend`` over a store whose kv heads are paired into the lanes
+    (``paired``): each query widened to 2 D with zeros in the half its
+    head's pair does not hold, and the output's other half dropped.
+    Returns [N, L, Hq, D]."""
+    d, hq = q.shape[-1], q.shape[2]
+    per = hq // pages.shape[2]          # query heads a kv head
+    second = ((jnp.arange(hq) // per) % 2 == 1)[:, None]
+    zero = jnp.zeros_like(q)
+    wide = jnp.where(second, jnp.concatenate([zero, q], axis=-1),
+                     jnp.concatenate([q, zero], axis=-1))
+    out = attend(wide, pages, table, kv_len, lens, scale, window)
+    return jnp.where(second, out[..., d:], out[..., :d])

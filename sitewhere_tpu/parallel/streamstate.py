@@ -82,7 +82,9 @@ class PageLayout:
     n_window: int        # layers that keep a ring of pages a stream
     n_full: int          # layers that keep their positions in the pool
     position_bytes: int  # K and V of one position of one layer
-    row_bytes: int       # the rest of a stream's state (position, hidden)
+    row_bytes: int       # the rest of a stream's state (position, hidden,
+    #                      and the convolution tails)
+    tail_bytes: int = 0  # a stream's convolution tails (in row_bytes too)
 
 
 @dataclass
@@ -359,7 +361,9 @@ class PagePool:
     table is full — the stream's OWN oldest page (counted as recycled;
     the stream then attends the positions it still holds). ``release``
     returns a slot's pages. ``grant`` also counts what the call's
-    attention reads and writes (``PAGE_STATS``).
+    attention reads and writes (``PAGE_STATS``) and, for a family that
+    keeps convolution tails, the tails its rows read and write
+    (``conv_tail_bytes``).
 
     A grant is all or nothing: where a row finds no page
     (``PoolExhausted``) the pool is put back as it was before the grant,
@@ -466,6 +470,10 @@ class PagePool:
                 ) + lay.row_bytes
                 stats["state_written_bytes"] += (
                     pb * n * (lay.n_window + lay.n_full) + lay.row_bytes)
+                if lay.tail_bytes:
+                    # a row's tails, read and written whole a call
+                    stats["conv_tail_bytes"] = stats.get(
+                        "conv_tail_bytes", 0) + 2 * lay.tail_bytes
         for key, total in self.totals.items():
             stats[f"kv_pages_{key}"] = total - self._reported[key]
         self._reported = dict(self.totals)

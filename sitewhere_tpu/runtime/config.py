@@ -356,6 +356,13 @@ TENANT_TEMPLATES: Dict[str, Dict[str, Any]] = {
         "model_config": {},
         "datasets": ["empty"],
     },
+    "sensor-tokens-lfm2": {
+        # ... and by one that keeps convolution tails beside its pages
+        # (models/lfm2_moe.py)
+        "model": "lfm2_moe",
+        "model_config": {},
+        "datasets": ["empty"],
+    },
     "forecasting": {
         "model": "deepar",
         "model_config": {"context": 128},

@@ -178,7 +178,8 @@ def test_new_readers_read_the_counters_and_nothing_where_there_is_none():
     gives them — and the pool's reader reads the gauge."""
     cfg = json.loads((run.ROOT / "benchmark/configs/"
                       "trinity-mini-1t.json").read_text())
-    new = [m["name"] for m in BENCH["per_layer"] if m["workloads"] == [CELL]]
+    # the six this cell brought list it first (a later cell may follow)
+    new = [m["name"] for m in BENCH["per_layer"] if m["workloads"][0] == CELL]
     assert len(new) == 6
     bare = {"window": run.Span({}, {}, 40.0), "traced": None, "trace": None,
             "config": cfg, "device": {"kind": "TPU v5 lite"}}

@@ -597,3 +597,135 @@ def test_paged_stream_programs_compile_for_v5e_at_published_widths(
     assert text.count("ragged_paged_attention") >= 8     # 8 layers
     assert text.count("gmm") >= 12                       # 6 layers x 2
     assert not re.search(r"bf16\[\d+,256,8,128\]\S* copy\(", text)
+
+
+_LFM2_COMPILED: dict = {}
+
+
+def _lfm2_program(topo, one_step, rows, monkeypatch):
+    """The LFM2-MoE family's program at the published widths, 512
+    streams' tails and both attention layers' pools, compiled for the
+    described chip (shapes only, once a shape): (compiled, the store)."""
+    from sitewhere_tpu.models.common import sketch_edges
+    from sitewhere_tpu.ops import moe, paged_attention
+    from sitewhere_tpu.parallel.streamstate import StreamPrograms
+
+    # the backend here is the CPU; the chip's branches are what compile
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    spec = get_model("lfm2_moe")
+    cfg = make_config("lfm2_moe", {})
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                (1,) + x.shape, x.dtype, sharding=one), tree)
+
+    state = placed(jax.eval_shape(lambda: spec.init_state(cfg, 512)))
+    if (one_step, rows) not in _LFM2_COMPILED:
+        params = placed(jax.eval_shape(
+            lambda: spec.init(jax.random.PRNGKey(0), cfg)))
+        progs = StreamPrograms(spec, cfg, 1, 512, jnp.float32,
+                               sketch_edges(1.0, 64.0, 64))
+        length = 1 if one_step else cfg.chunk_size
+        packed = jax.ShapeDtypeStruct(
+            (rows, 2 + 2 * length + progs.extra), jnp.int32, sharding=one)
+        _LFM2_COMPILED[(one_step, rows)] = progs.program(
+            one_step, 0).lower(params, state, packed).compile()
+    return _LFM2_COMPILED[(one_step, rows)], state
+
+
+@pytest.mark.parametrize("one_step, rows", [(True, 32), (True, 128),
+                                            (False, 8)])
+def test_lfm2_stream_programs_compile_for_v5e_at_published_widths(
+        topo, one_step, rows, monkeypatch):
+    """The LFM2-MoE family's two programs at the published widths: they
+    fit beside 10.54 GB of weights and 3.56 GB of store (84% of the
+    chip), the store is written in place, attention is the paged kernel
+    (heads paired into 128 lanes), the expert products are kernels, and
+    neither the pool nor the tied head's embedding is copied."""
+    compiled, state = _lfm2_program(topo, one_step, rows, monkeypatch)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(state))
+    assert state_bytes == (2 * 3328 * 256 * 2048 + 8 * 512 * 2 * 2048 * 4
+                           + 512 * (2048 + 1) * 4)
+    assert mem.alias_size_in_bytes == state_bytes       # written in place
+    assert mem.temp_size_in_bytes < 0.4e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 0.86 * 16 * 2**30)
+    text = compiled.as_text()
+    assert text.count("ragged_paged_attention") >= 2     # 2 layers
+    assert text.count("gmm") >= 16                       # 8 layers x 2
+    assert not re.search(r"bf16\[\d+,256,8,128\]\S* copy\(", text)
+    assert not re.search(r"bf16\[\S*65536,2048\]\S* (copy|transpose)\(",
+                         text)
+
+
+def test_lfm2_pool_takes_2kb_a_position_a_layer_with_no_lane_padding(
+        topo, monkeypatch):
+    """Keys and values at head_dim 64 with kv heads paired into the lanes:
+    the compiled program takes each attention layer's pool as
+    ``bf16[1,3328,256,8,128]`` in the row-major (8, 128) tiling — 128
+    values of a position's pair on the lanes, 8 rows on the sublanes, no
+    padding — so its buffer is 3328 x 256 x 2,048 bytes, not twice that
+    (``[..., 16, 64]`` row-major would pad every row to 128 lanes)."""
+    compiled, state = _lfm2_program(topo, True, 32, monkeypatch)
+    text = compiled.as_text()
+    layouts = set(re.findall(r"bf16\[1,3328,256,8,128\]\{[^}]*\}", text))
+    assert layouts == {"bf16[1,3328,256,8,128]{4,3,2,1,0:T(8,128)(2,1)}"}
+    pool = [x for x in jax.tree_util.tree_leaves(state)
+            if x.shape == (1, 3328, 256, 8, 128)]
+    assert len(pool) == 2
+    assert all(x.size * x.dtype.itemsize == 3328 * 256 * 2048 for x in pool)
+    # the arguments are the weights, the store and the call: nothing padded
+    weights = 10_536_463_360
+    assert compiled.memory_analysis().argument_size_in_bytes < (
+        weights + sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(state)) + 2**20)
+
+
+# sha256 of the jaxprs of the other paged / stream-state families' two
+# programs at their published widths (the TPU branches), as the commit
+# before the LFM2-MoE family made them: the shared ``ops/moe.py``,
+# ``ops/paged_attention.py`` and ``parallel/streamstate.py`` lower them
+# as they did
+OTHER_FAMILIES_JAXPRS = {
+    ("afmoe", True): "2784a1ac0cb1f30da174206db7568c3ffaae5ffd25e74701129e433654599ac9",
+    ("afmoe", False): "971ad3d34300d66969cb4b0f7f580e3ca48ba5730360d873abd13c083a495611",
+    ("nemotron_h", True): "5ef621bb736e8800bc25dcc59e56f171264d22bd90ae5eea370381feeb36cbf5",
+    ("nemotron_h", False): "0880d78320916768b1e01e2281f3b391d2e6888276cb46ce63c4fe383b47c662",
+}
+
+
+@pytest.mark.parametrize("family, one_step", sorted(OTHER_FAMILIES_JAXPRS))
+def test_afmoe_and_nemotron_programs_lower_as_they_did(
+        family, one_step, monkeypatch):
+    import hashlib
+
+    from sitewhere_tpu.models.common import sketch_edges
+    from sitewhere_tpu.ops import moe, paged_attention
+    from sitewhere_tpu.parallel.streamstate import StreamPrograms
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    streams = {"afmoe": 128, "nemotron_h": 512}[family]
+    spec, cfg = get_model(family), make_config(family, {})
+
+    def stacked(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((1,) + x.shape, x.dtype), tree)
+
+    params = stacked(jax.eval_shape(
+        lambda: spec.init(jax.random.PRNGKey(0), cfg)))
+    state = stacked(jax.eval_shape(lambda: spec.init_state(cfg, streams)))
+    progs = StreamPrograms(spec, cfg, 1, streams, jnp.float32,
+                           sketch_edges(1.0, 64.0, 64))
+    rows, length = (32, 1) if one_step else (8, cfg.chunk_size)
+    packed = jax.ShapeDtypeStruct((rows, 2 + 2 * length + progs.extra),
+                                  jnp.int32)
+    text = str(jax.make_jaxpr(progs.program(one_step, 0))(
+        params, state, packed))
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        OTHER_FAMILIES_JAXPRS[(family, one_step)])
