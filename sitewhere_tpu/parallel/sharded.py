@@ -483,6 +483,28 @@ class ShardedScorer:
         """Host→device bytes one staged flush moves (feed observability)."""
         return int(sum(a.nbytes for a in staged))
 
+    # -- planes ----------------------------------------------------------
+    # lanes a (slot, data shard) of the narrow plane: the chip's lane
+    # tile, so a narrower [T, B] array would fill the same tiles
+    NARROW_LANES = 128
+
+    def planes(self, buckets) -> tuple:
+        """The lane widths a flush's plane is picked from, ascending, for
+        the flush policy's ``buckets``. A window family's step does its
+        per-row work — the rings' scatter, the counters' and windows'
+        gathers, the model over ``[T, B, W]`` — over every lane of the
+        plane, so it adds one plane of ``NARROW_LANES`` below the
+        smallest bucket: a flush whose fullest lane fits it pays for
+        ``T × NARROW_LANES`` lanes, not ``T × buckets[0]``. The buckets
+        themselves stay the flush policy's hold threshold
+        (``SliceRuntime.held``). A stateful family's one-step program
+        takes ``ONE_STEP_ROWS`` rows whatever the plane, so its planes
+        are its buckets."""
+        buckets = tuple(buckets)
+        if self.stateful or not buckets or buckets[0] <= self.NARROW_LANES:
+            return buckets
+        return (self.NARROW_LANES,) + buckets
+
     # -- device-time / MFU attribution -----------------------------------
     def flops_per_row(self, b_lane: int = 0) -> float:
         """Analytic matmul FLOPs the device executes per lane row of one
@@ -730,8 +752,9 @@ class ShardedScorer:
         return jax.jit(smapped, donate_argnums=(1,))
 
     def prewarm(self, lane_sizes) -> None:
-        """Compile every bucketed batch shape up front (counts wire — the
-        service hot path). A first-use compile inside the scoring loop
+        """Compile every plane in ``lane_sizes`` up front (counts wire —
+        the service hot path; the service passes ``planes`` of each
+        tenant's buckets). A first-use compile inside the scoring loop
         blocks the event loop for seconds (tens of seconds on TPU) and
         torpedoes p99 — pay it at startup instead. Zero-count lanes leave
         window state untouched (scatter mode=drop)."""

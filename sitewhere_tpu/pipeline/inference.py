@@ -1371,11 +1371,15 @@ class TpuInferenceService(MultitenantService):
         self.metrics.histogram("tpu_inference.acquire_wait", unit="s").record(
             t_got - t_asked
         )
-        # pick the bucket AFTER the (possibly long) acquire wait: rows that
+        # pick the plane AFTER the (possibly long) acquire wait: rows that
         # accumulated while every slot was busy should ride out in ONE
-        # bigger flush, not drain at the stale pre-wait size
+        # bigger flush, not drain at the stale pre-wait size. The planes
+        # are the scorer's (a window family's narrow one below the
+        # buckets); the hold above stays at the smallest bucket
         pending_max = max((l.count for l in lanes.values()), default=0)
-        b_lane = s.pick_bucket(pending_max, tuple(mb.buckets), mb.max_batch)
+        b_lane = s.pick_bucket(
+            pending_max, scorer.planes(mb.buckets), mb.max_batch
+        )
         # wire-thin stacked batch: compact id/value dtypes + one count per
         # (slot, data-shard) lane instead of a bool mask — rows fill each
         # lane from the front, so validity is derivable on device (see
@@ -1549,6 +1553,12 @@ class TpuInferenceService(MultitenantService):
                 ).inc(moved)
             self.metrics.counter("tpu_inference.flushes").inc()
             self.metrics.counter("tpu_inference.flush_rows").inc(moved)
+            # the plane's lanes (occupancy = flush_rows ÷ plane_lanes),
+            # and whether it was one below the smallest bucket
+            plane = counts.size * b_lane
+            self.metrics.counter("tpu_inference.plane_lanes").inc(plane)
+            if b_lane < min(mb.buckets[0], mb.max_batch):
+                self.metrics.counter("tpu_inference.flush_narrow").inc()
             self.metrics.histogram(
                 "tpu_inference.idle_unfed", unit="s").record(idle_unfed)
             self.metrics.histogram(
@@ -1594,7 +1604,7 @@ class TpuInferenceService(MultitenantService):
                 t_assembled=t_assembled, t_staged=t_staged,
                 t_dispatched=t_dispatched,
                 lane="serve",
-                rows=moved, bucket=b_lane,
+                rows=moved, bucket=b_lane, plane=plane,
                 assembly_s=round(assembly_s, 6),
                 h2d_stage_s=round(h2d_stage_s, 6),
                 dispatch_s=round(dispatch_s, 6),
@@ -2016,7 +2026,8 @@ class TpuInferenceService(MultitenantService):
                 dsh, minlength=self.mm.n_data_shards
             )
             b_lane = s2.pick_bucket(
-                int(lane_counts.max()), tuple(mb.buckets), mb.max_batch
+                int(lane_counts.max()), scorer.planes(mb.buckets),
+                mb.max_batch,
             )
             t, d = scorer.n_slots, self.mm.n_data_shards
             ids_st = np.zeros((t, d * b_lane), scorer.ids_np_dtype)
@@ -4232,16 +4243,26 @@ class TpuInferenceService(MultitenantService):
         latency-sensitive traffic."""
         # union of every resident engine's bucket sizes per (family,
         # slice): tenants sharing a slice may configure different
-        # buckets, and a missed size is a mid-scoring-loop XLA compile
+        # buckets, and a missed size is a mid-scoring-loop XLA compile.
+        # The scoring step compiles the scorer's planes of them (a
+        # window family's narrow plane too), the train lane the buckets
         wanted: Dict[Tuple[str, int], set] = {}
+        planes: Dict[Tuple[str, int], set] = {}
         for tenant, engine in self.engines.items():
             assert isinstance(engine, TpuInferenceEngine)
             if engine.placement is None:
                 continue
             key = (engine.config.model, engine.placement.shard)
+            s = self._slices.get(key)
+            if s is None:
+                continue
             mb = engine.config.microbatch
             wanted.setdefault(key, set()).update(
                 [min(b, mb.max_batch) for b in mb.buckets] + [mb.max_batch]
+            )
+            planes.setdefault(key, set()).update(
+                [min(b, mb.max_batch) for b in s.scorer.planes(mb.buckets)]
+                + [mb.max_batch]
             )
         lane_keys: set = set()
         for tenant, engine in self.engines.items():
@@ -4254,28 +4275,25 @@ class TpuInferenceService(MultitenantService):
                     (engine.config.model, engine.placement.shard)
                 )
         for key, sizes in wanted.items():
-            s = self._slices.get(key)
-            if s is not None:
-                scorer = s.scorer
-                scorer.prewarm(sorted(sizes))
-                # every bucket's step is compiled now: its first real
-                # flush must not report a (false) compile either — same
-                # rule as the train lane below
-                s.seen_shapes.update(sizes)
-                if key in lane_keys and getattr(
-                    scorer, "train_lane", False
-                ):
-                    # the train lane's first step/ingest must not pay a
-                    # mid-traffic XLA compile either — same rule as the
-                    # scoring shapes above
-                    if getattr(scorer, "_train_fused", None) is None:
-                        scorer.init_optimizer()
-                    scorer.prewarm_train_lane(sorted(sizes))
-                    # the lane's executables are compiled now: the first
-                    # real dispatch must not report a (false) compile —
-                    # that would fire the steady_state_recompile
-                    # watchdog the moment a replay train job starts
-                    s.seen_shapes.add("train")
+            s = self._slices[key]
+            scorer = s.scorer
+            scorer.prewarm(sorted(planes[key]))
+            # every plane's step is compiled now: its first real flush
+            # must not report a (false) compile either — same rule as
+            # the train lane below
+            s.seen_shapes.update(planes[key])
+            if key in lane_keys and getattr(scorer, "train_lane", False):
+                # the train lane's first step/ingest must not pay a
+                # mid-traffic XLA compile either — same rule as the
+                # scoring shapes above
+                if getattr(scorer, "_train_fused", None) is None:
+                    scorer.init_optimizer()
+                scorer.prewarm_train_lane(sorted(sizes))
+                # the lane's executables are compiled now: the first
+                # real dispatch must not report a (false) compile —
+                # that would fire the steady_state_recompile watchdog
+                # the moment a replay train job starts
+                s.seen_shapes.add("train")
 
     def params_source(self, tenant: str):
         """A zero-arg callable yielding the tenant's CURRENT slot params

@@ -591,12 +591,13 @@ class SliceRuntime:
         (a train-lane step in flight holds nothing: serving has the
         right of way, and the lane only ever enters an empty window).
 
-        Below the smallest bucket a bigger flush runs the SAME program in
-        the same device time, so holding costs no throughput and saves a
-        whole step of device queue: the rows stay on the lanes (counted
-        by the lane watermark as ever) and ride out together when the
-        in-flight flush lands. From the smallest bucket up, coalescing
-        further would move to a larger program, and pipelining up to
+        A held flush never runs a program larger than the smallest
+        bucket's (a window family's narrow plane while every lane fits
+        it, ``ShardedScorer.planes``, else that bucket's), and holding
+        saves a whole step of device queue: the rows stay on the lanes
+        (counted by the lane watermark as ever) and ride out together
+        when the in-flight flush lands. From the smallest bucket up,
+        coalescing further would move to a larger program, and pipelining up to
         ``max_inflight`` deep — which hides h2d and assembly under
         compute for full flushes — applies unchanged.
 

@@ -176,8 +176,9 @@ def one_chip_scorer(described):
     return described_scorer(described.devices[:1], 1, 1)
 
 
-def test_step_counts_compiles_for_v5e(one_chip_scorer, topo):
-    rep = compile_report(lower_step_counts(one_chip_scorer, 2048))
+@pytest.mark.parametrize("b_lane", [2048, ShardedScorer.NARROW_LANES])
+def test_step_counts_compiles_for_v5e(one_chip_scorer, topo, b_lane):
+    rep = compile_report(lower_step_counts(one_chip_scorer, b_lane))
     assert rep["collectives"] == {}, rep
     assert rep["temp_mb"] < 8_000, rep  # fits a 16 GB chip with room
 
@@ -214,17 +215,19 @@ def whole_state_ops(hlo: str, n_elems: int, name: str = "ENTRY",
     return found
 
 
+@pytest.mark.parametrize("b_lane", [1024, ShardedScorer.NARROW_LANES])
 @pytest.mark.parametrize("max_streams", [524_288, 1_048_576])
 def test_step_touches_the_window_state_only_where_it_scatters(
-        one_chip_scorer, topo, max_streams):
+        one_chip_scorer, topo, max_streams, b_lane):
     """At the benchmark cell's size (32 slots, 524,288 streams, W 32,
-    bucket 1,024) and at the published fleet's power of two, which the
-    [T, S, W] store could not compile ("Used 16.00G of 15.75G"): the
-    compiled step holds no temporary of the state's order and one
-    operation that yields the whole state — the in-place scatter."""
+    bucket 1,024, and the narrow plane of 128 lanes below it) and at the
+    published fleet's power of two, which the [T, S, W] store could not
+    compile ("Used 16.00G of 15.75G"): the compiled step holds no
+    temporary of the state's order and one operation that yields the
+    whole state — the in-place scatter."""
     t, w = one_chip_scorer.n_slots, one_chip_scorer.window
     compiled = lower_step_counts(
-        one_chip_scorer, 1024, max_streams).compile()
+        one_chip_scorer, b_lane, max_streams).compile()
     state_bytes = t * max_streams * w * 4
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < state_bytes // 10, mem
@@ -330,12 +333,14 @@ def test_step_bins_the_sketch_without_a_gather_a_scatter_or_a_loop(
                                       else 2_767.5e6), mem
 
 
+@pytest.mark.parametrize("b_lane", [1024, ShardedScorer.NARROW_LANES])
 def test_step_writes_the_counters_in_place_at_the_batch_streams(
-        one_chip_scorer, topo):
+        one_chip_scorer, topo, b_lane):
     """At the benchmark cell's size (32 slots, 1,171,875 streams, W 32,
-    bucket 1,024): ``pos`` and ``count`` are read at the batch's ids and
-    written back there. The TPU's scatter writes only a linear array, and
-    the [32, 1171875] counters are tiled (8, 128): every scatter into
+    bucket 1,024 and the narrow plane of 128 lanes below it): ``pos``
+    and ``count`` are read at the batch's ids and written back there.
+    The TPU's scatter writes only a linear array, and the [32, 1171875]
+    counters are tiled (8, 128): every scatter into
     them went through a copy to a flat s32[37500000] and back, in a
     ``while`` (5.1 of the step's 16.4 ms on the v5e, PERF.md section 5).
     Outside one conditional nothing of the step yields an array of the
@@ -346,8 +351,8 @@ def test_step_writes_the_counters_in_place_at_the_batch_streams(
     from sitewhere_tpu.ops.windows import COUNTERS_PER_WRITE
 
     t, w, s = one_chip_scorer.n_slots, one_chip_scorer.window, 1_171_875
-    assert 0 < t * s // COUNTERS_PER_WRITE < t * 1024  # a conditional
-    compiled = lower_step_counts(one_chip_scorer, 1024, s).compile()
+    assert 0 < t * s // COUNTERS_PER_WRITE < t * b_lane  # a conditional
+    compiled = lower_step_counts(one_chip_scorer, b_lane, s).compile()
     hlo = compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= t * s * (w + 2) * 4, mem

@@ -81,7 +81,7 @@ async def _wait_for(cond, timeout_s=20.0, interval=0.01) -> bool:
 
 
 async def _instance(tenants=("acme",), tenant_axis=1, slots_per_shard=4,
-                    max_inflight=None, **tenant_kw):
+                    max_inflight=None, mb=MB, **tenant_kw):
     kw = {} if max_inflight is None else {"inference_max_inflight": max_inflight}
     inst = SiteWhereInstance(InstanceConfig(
         instance_id="fp",
@@ -92,7 +92,7 @@ async def _instance(tenants=("acme",), tenant_axis=1, slots_per_shard=4,
     await inst.start()
     for tok in tenants:
         await inst.tenant_management.create_tenant(
-            tok, template="iot-temperature", microbatch=MB,
+            tok, template="iot-temperature", microbatch=mb,
             model_config={"hidden": 8}, max_streams=64, **tenant_kw,
         )
     await inst.drain_tenant_updates()
@@ -431,6 +431,90 @@ async def test_per_tenant_order_is_preserved_across_a_held_flush():
         assert _count(inst, "flushes") == 2
     finally:
         _open(gates)
+        await inst.terminate()
+
+
+# ------------------------------------------- the plane a flush takes
+# a smallest bucket above the scorer's narrow plane of 128 lanes
+WIDE = MicroBatchConfig(max_batch=1024, deadline_ms=1.0, buckets=(1024,),
+                        window=8)
+NARROW = 128
+
+
+async def test_a_flush_takes_the_narrow_plane_while_every_lane_fits_it():
+    """The plane follows the fullest lane: up to 128 rows the narrow
+    plane, from 129 the smallest bucket's — every plane compiled by the
+    prewarm, so no flush compiles."""
+    inst, fleets = await _instance(mb=WIDE)
+    svc, toks = inst.inference, fleets["acme"]
+    t = svc.scorers["lstm_ad"].n_slots
+    try:
+        scored = 0
+        for n, lanes in ((SMALL, NARROW), (NARROW, NARROW), (NARROW + 1, 1024),
+                         (200, 1024)):
+            flushes = _count(inst, "flushes")
+            await _publish(inst, "acme", toks, n, 100.0)
+            scored += n
+            assert await _wait_for(
+                lambda: _count(inst, "scored_total") >= scored)
+            assert _count(inst, "flushes") == flushes + 1
+            rec = list(svc.flush_records.values())[-1]
+            assert (rec["rows"], rec["bucket"], rec["plane"]) == (
+                n, lanes, t * lanes)
+        assert _count(inst, "flush_narrow") == 2
+        assert _count(inst, "plane_lanes") == t * (2 * NARROW + 2 * 1024)
+        assert _count(inst, "flush_rows") == scored
+        assert _count(inst, "compiles") == 0
+    finally:
+        await inst.terminate()
+
+
+async def test_a_lane_over_the_narrow_plane_is_still_held_under_the_bucket():
+    """The hold reads the smallest bucket, not the plane: 200 rows on a
+    lane under a 1,024 bucket wait for the flush in flight, then leave
+    together at the bucket's plane."""
+    inst, fleets = await _instance(mb=WIDE)
+    svc, toks = inst.inference, fleets["acme"]
+    gates = _gate_each(svc.scorers["lstm_ad"])
+    try:
+        await _publish(inst, "acme", toks, SMALL, 100.0)
+        assert await _wait_for(lambda: _reap_len(svc) == 1)
+        await _publish(inst, "acme", toks, 200, 200.0)
+        assert await _wait_for(lambda: _count(inst, "flush_held") > 0)
+        await asyncio.sleep(0.1)
+        assert _count(inst, "flushes") == 1 and _lane_rows(svc) == 200
+        gates[0].set()
+        assert await _wait_for(lambda: _count(inst, "flushes") == 2)
+        rec = list(svc.flush_records.values())[-1]
+        assert (rec["rows"], rec["bucket"]) == (200, 1024)
+        gates[1].set()
+        assert await _wait_for(
+            lambda: _count(inst, "scored_total") >= SMALL + 200)
+        assert _count(inst, "flush_narrow") == 1
+        assert _count(inst, "flush_pipelined") == 0
+    finally:
+        _open(gates)
+        await inst.terminate()
+
+
+async def test_a_narrow_flush_after_the_prewarm_compiles_nothing():
+    """``prewarm()`` compiled the narrow plane's step, gather rungs and
+    single-slot slice: a live narrow flush adds no compile."""
+    inst, fleets = await _instance(mb=WIDE)
+    svc, toks = inst.inference, fleets["acme"]
+    s = svc._slices[KEY]
+    assert {NARROW, 1024} <= s.seen_shapes
+    try:
+        for r in range(3):
+            await _publish(inst, "acme", toks, SMALL, 10.0 * r)
+            assert await _wait_for(
+                lambda: _count(inst, "scored_total") >= SMALL * (r + 1))
+        assert _count(inst, "flush_narrow") == _count(inst, "flushes") > 0
+        assert _count(inst, "compiles") == 0
+        assert inst.metrics.counter(
+            "tpu_inference_compiles", family="lstm_ad",
+            bucket=str(NARROW)).value == 0
+    finally:
         await inst.terminate()
 
 

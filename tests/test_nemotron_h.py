@@ -410,6 +410,15 @@ def test_the_store_is_provisioned_once_and_counted(tiny):
     assert written == 3 * (per_stream - rings) + 3 * 2 * 2 * 16 * 4
 
 
+def test_a_stateful_scorers_planes_are_its_buckets(tiny):
+    """The one-step program's rows follow ``ONE_STEP_ROWS``, not the
+    plane: no narrow plane is added below a wide smallest bucket."""
+    sc, _w, _d = tiny
+    assert sc.stateful
+    for buckets in ((1024, 4096, 16384), (32, 256)):
+        assert sc.planes(buckets) == buckets
+
+
 def test_published_widths_weigh_what_the_configuration_says():
     """Bytes of the real configuration from shapes alone (nothing is
     allocated): weights 6.33 GB as published + 0.18 GB of zero padding
